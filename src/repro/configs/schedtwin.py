@@ -44,7 +44,8 @@ class TwinConfig:
     # oracle, "pallas" = the TPU kernel, "auto" = reference on CPU /
     # pallas on TPU — interpret-mode pallas is ~2.3x slower than
     # reference on CPU, BENCH_overhead.json) and Pallas interpret
-    # override (None auto-detects: interpret on CPU, compiled on TPU).
+    # override (None resolves from the platform: the compiled kernel
+    # on TPU, interpret mode on CPU).
     backend: str = "auto"
     interpret: Optional[bool] = None
 

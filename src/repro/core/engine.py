@@ -26,8 +26,8 @@ Backends (registered in ``PASS_BACKENDS``):
     The semantic oracle: bit-identical to the scalar DES.
   * ``pallas``    — ``kernels.policy_eval.policy_eval_pass_batched``,
     the TPU kernel with the fork axis on the grid and the queue in
-    VMEM.  Interpret-mode on CPU (this container), compiled on TPU
-    (``interpret=None`` auto-detects).
+    VMEM.  Compiled on a TPU, interpret mode elsewhere (the CPU test
+    runs); ``interpret=None`` resolves from the platform.
 
 Every consumer routes through here: ``whatif.decide`` /
 ``decide_ensemble`` (ensemble members ride the same batch axis —
@@ -87,6 +87,7 @@ def _quiet_donation(jitted):
             warnings.filterwarnings(
                 "ignore", message="Some donated buffers were not usable")
             return jitted(*args, **kwargs)
+    call.lower = jitted.lower
     return call
 
 #: What the engine accepts as a pool: a parametric ``PolicySpec`` with
@@ -429,8 +430,9 @@ class DrainEngine:
         "reference" on CPU/GPU (interpret-mode pallas is ~2.3x slower
         than reference at k=32 on CPU, see BENCH_overhead.json; the
         kernel only pays off compiled).  The resolved choice is logged.
-    interpret : Pallas interpret-mode override.  ``None`` auto-detects:
-        interpret on CPU (this container), compiled on TPU.
+    interpret : Pallas interpret-mode override.  ``None`` resolves from
+        the platform (``policy_eval.default_interpret``): compiled on
+        TPU, interpreted on CPU.
     dynamic_bounds : truncate the pass's sequential rank loops at the
         deepest live queued rank each event (``des.pass_rank_limit``) —
         bit-exact; collapses the O(J)-rank loops to the queue depth.
@@ -465,7 +467,7 @@ class DrainEngine:
     def resolved_interpret(self) -> bool:
         if self.interpret is not None:
             return self.interpret
-        return jax.default_backend() != "tpu"
+        return _pe.default_interpret()
 
     def pass_fn(self) -> PassFn:
         return PASS_BACKENDS[self.backend](self)
@@ -561,15 +563,29 @@ class DrainEngine:
         device computation.  Fork f = s·P + p; outcome axes (S, P).
         ``objective`` selects per scenario: ``best[s]`` is the pool
         index the goal picks for scenario s (costs over the P axis)."""
+        args = self._grid_args(scenarios, pool, objective, weights)
+        S, P = int(scenarios.total_nodes.shape[0]), args[-1]
+        res, metrics, costs, best = _replay(*args)
+        return _shape_outcome(res, metrics, (S, P), costs, best)
+
+    def lower_replay_grid(self, scenarios, pool,
+                          objective: ObjectiveLike = None
+                          ) -> jax.stages.Lowered:
+        """The jitted computation ``replay_grid`` runs, lowered for the
+        default device: ``.compile().as_text()`` shows what the
+        compiler made of it (e.g. a ``tpu_custom_call`` for the
+        compiled Pallas pass)."""
+        return _replay.lower(*self._grid_args(scenarios, pool, objective))
+
+    def _grid_args(self, scenarios, pool, objective, weights=None):
+        """``_replay``'s arguments for the S×P grid (fork f = s·P + p)."""
         goal = resolve_goal(objective, weights)
         pool = as_pool(pool)
         S = int(scenarios.total_nodes.shape[0])
         P = pool_size(pool)
-        inputs = replay_inputs(scenarios, pool)
-        plan = self.plan(pool)                 # fork f = s·P + p
-        res, metrics, costs, best = _replay(
-            self, *inputs, plan * S if plan is not None else None, goal, P)
-        return _shape_outcome(res, metrics, (S, P), costs, best)
+        plan = self.plan(pool)
+        return (self, *replay_inputs(scenarios, pool),
+                plan * S if plan is not None else None, goal, P)
 
     def fan_grid(self, scenarios, pool, fan,
                  objective: ObjectiveLike = None, *,

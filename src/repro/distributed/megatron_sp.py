@@ -28,7 +28,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.distributed.sharding import ShardingRules
@@ -102,8 +101,8 @@ def out_project_rs(h: jax.Array, w: jax.Array, *, rules: ShardingRules,
                                     scatter_dimension=1, tiled=True)
 
     out_spec = P(dp_spec, "model", None)
-    return shard_map(body, mesh=mesh, in_specs=(h_spec, w_spec),
-                     out_specs=out_spec, check_rep=False)(h, w)
+    return jax.shard_map(body, mesh=mesh, in_specs=(h_spec, w_spec),
+                         out_specs=out_spec, check_vma=False)(h, w)
 
 
 def in_project_ag(x: jax.Array, weights, *, rules: ShardingRules,
@@ -149,10 +148,10 @@ def in_project_ag(x: jax.Array, weights, *, rules: ShardingRules,
         P(dp_spec, None, s[1]) if kind == "df"
         else P(dp_spec, None, s[1], None)
         for s, kind in zip(w_specs, kinds))
-    return shard_map(body, mesh=mesh,
-                     in_specs=(P(dp_spec, "model", None), *w_specs),
-                     out_specs=out_specs,
-                     check_rep=False)(x, *weights)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=(P(dp_spec, "model", None), *w_specs),
+                         out_specs=out_specs,
+                         check_vma=False)(x, *weights)
 
 
 def gather_seq(x: jax.Array, *, rules: ShardingRules,
@@ -173,7 +172,7 @@ def gather_seq(x: jax.Array, *, rules: ShardingRules,
     def body(x_loc):
         return jax.lax.all_gather(x_loc, "model", axis=1, tiled=True)
 
-    return shard_map(body, mesh=mesh,
-                     in_specs=P(dp_spec, "model", None),
-                     out_specs=P(dp_spec, None, None),
-                     check_rep=False)(x)
+    return jax.shard_map(body, mesh=mesh,
+                         in_specs=P(dp_spec, "model", None),
+                         out_specs=P(dp_spec, None, None),
+                         check_vma=False)(x)

@@ -18,7 +18,8 @@ VMEM — comfortably under the ~16 MB/core budget with double buffering.
 MXU dims (BLOCK x D) are multiples of 128.
 
 Oracle: ``repro.models.attention.full_attention`` (ref.py re-exports).
-Validated in interpret mode; on TPU pass ``interpret=False``.
+Validated in interpret mode; ``interpret`` has no default (``ops``
+resolves it from the platform).
 """
 from __future__ import annotations
 
@@ -92,7 +93,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
                     scale: float | None = None,
-                    interpret: bool = True) -> jax.Array:
+                    interpret: bool) -> jax.Array:
     """q: (B, Hq, Sq, D); k, v: (B, Hkv, Sk, D), Hq % Hkv == 0.
     Returns (B, Hq, Sq, D) in q.dtype."""
     b, hq, sq, d = q.shape

@@ -1,9 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-``INTERPRET`` defaults to True (this container is CPU-only; interpret
-mode executes kernel bodies in Python for correctness).  On real TPU
-set ``repro.kernels.ops.INTERPRET = False`` (or pass interpret=False)
-to run the compiled kernels.
+``interpret=None`` (every wrapper's default) resolves from the
+platform through ``policy_eval.default_interpret``: the compiled
+kernels on a TPU, interpret mode (kernel bodies evaluated as plain
+JAX, for correctness) everywhere else.  Pass a bool to override.
 
 ``twin_schedule_pass`` is the drop-in replacement for the pure-jnp
 ``core.backfill.schedule_pass`` inside the what-if engine: it takes a
@@ -11,10 +11,9 @@ SimState + policy pool and returns the per-policy started masks.
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 
 from repro.core.state import SimState
 from repro.kernels import flash_attention as _fa
@@ -23,7 +22,9 @@ from repro.kernels import rglru as _rg
 from repro.kernels import wkv6 as _wkv
 from repro.kernels.ref import kernel_inputs_from_state
 
-INTERPRET = True
+
+def _interpret(interpret: Optional[bool]) -> bool:
+    return _pe.default_interpret() if interpret is None else interpret
 
 
 def twin_schedule_pass(state: SimState, pool: jax.Array,
@@ -36,7 +37,7 @@ def twin_schedule_pass(state: SimState, pool: jax.Array,
     return _pe.policy_eval_pass(
         inp["order"], inp["queued"], inp["nodes"], inp["est"],
         inp["run_end"], inp["run_nodes"], inp["free0"], inp["now"],
-        interpret=INTERPRET if interpret is None else interpret)
+        interpret=_interpret(interpret))
 
 
 def flash_attention(q, k, v, *, causal=True, block_q=None, block_k=None,
@@ -48,15 +49,14 @@ def flash_attention(q, k, v, *, causal=True, block_q=None, block_k=None,
         kwargs["block_k"] = block_k
     return _fa.flash_attention(
         q, k, v, causal=causal, scale=scale,
-        interpret=INTERPRET if interpret is None else interpret, **kwargs)
+        interpret=_interpret(interpret), **kwargs)
 
 
 def wkv6(r, k, v, w, u, *, block_t=None, interpret=None):
     kwargs = {}
     if block_t is not None:
         kwargs["block_t"] = block_t
-    return _wkv.wkv6(r, k, v, w, u,
-                     interpret=INTERPRET if interpret is None else interpret,
+    return _wkv.wkv6(r, k, v, w, u, interpret=_interpret(interpret),
                      **kwargs)
 
 
@@ -66,6 +66,4 @@ def rglru(a, x, h0, *, block_t=None, block_w=None, interpret=None):
         kwargs["block_t"] = block_t
     if block_w is not None:
         kwargs["block_w"] = block_w
-    return _rg.rglru(a, x, h0,
-                     interpret=INTERPRET if interpret is None else interpret,
-                     **kwargs)
+    return _rg.rglru(a, x, h0, interpret=_interpret(interpret), **kwargs)

@@ -5,26 +5,40 @@ Every SchedTwin cycle runs k drain simulations; each simulation runs a
 every event.  The paper parallelizes this with k CQSim processes on 48
 CPU cores; the TPU-native adaptation is a **policy-batched kernel**:
 
-  * grid = the policy/ensemble axis (one program per candidate policy),
-  * the queue state (<= max_jobs jobs x 6 f32 fields, ~6 KB at J=256)
-    is VMEM-resident for the whole pass,
+  * grid = the policy/ensemble axis in tiles of ``TILE`` = 8 forks (one
+    sublane tile), so every (8, J) block obeys Mosaic's (8, 128) block
+    rule and one vector op advances all eight forks at once,
+  * the queue state (<= max_jobs jobs x 6 f32 fields, ~6 KB per fork at
+    J=256) is VMEM-resident for the whole pass,
   * the inherently sequential greedy/backfill dependence is an
-    in-kernel ``fori_loop`` over priority ranks,
+    in-kernel ``fori_loop`` over priority ranks.  Mosaic has no scalar
+    gather from a vector value, so each rank step reads its job with a
+    masked lane reduction over an iota (``_pick``: the slot at rank i,
+    then that slot's fields) and records a start as a masked vector
+    select — exact, since exactly one lane is selected,
   * the EASY "shadow time" is computed WITHOUT the CPU algorithm's
     sort: for every candidate end time t_j we evaluate
     ``free_at(t_j) = free + sum(nodes_r * (end_r <= t_j))`` — an O(J^2)
-    SIMD broadcast that replaces an O(J log J) sort-scan, which is the
-    right trade on the VPU (J^2 = 64K lanes of work, zero data
-    movement).  See ``DESIGN.md`` §2 (hardware adaptation) at the repo
-    root for the full derivation and the tie-handling caveat.
+    SIMD broadcast-reduce (one (J, J) f32 block per fork, 256 KiB at
+    J=256, reduced over sublanes on the VPU) that replaces an
+    O(J log J) sort-scan.  Node counts are integers, so the sum is
+    exact in any order.  See ``DESIGN.md`` §2 (hardware adaptation) at
+    the repo root for the full derivation and the tie-handling caveat.
 
 Two entry points:
   * ``policy_eval_pass`` — shared snapshot, per-policy ``order`` only
     (the first pass of a decision cycle, where all forks still share
-    one queue state);
+    one queue state): a broadcast onto the batched entry;
   * ``policy_eval_pass_batched`` — every input carries the fork axis
     (mid-drain, after fork states have diverged).  This is the
     ``pallas`` backend of ``repro.core.engine.DrainEngine``.
+
+The wrappers pad the fork axis to a multiple of 8 and the job axis to
+a multiple of 128 with inert rows and slots (never queued, never
+running), then slice the padding off — bit-exact.
+
+``interpret`` has no default: callers resolve it from the platform
+(``default_interpret``: compiled on TPU, interpreted elsewhere).
 
 The priority *keys* are computed (and argsorted) outside the kernel —
 they are embarrassingly parallel and XLA already fuses them; the kernel
@@ -37,18 +51,17 @@ Inputs (policy axis k leading where applicable):
   est       (J,)   f32   — user walltime estimate
   run_end   (J,)   f32   — predicted end for RUNNING jobs else +inf
   run_nodes (J,)   f32   — nodes held by RUNNING jobs else 0
-  free0     (1, 1) f32   — free nodes now
-  now       (1, 1) f32   — current time
-  limit     (1, 1) i32   — rank bound for both sequential loops: ranks
+  free0     ()     f32   — free nodes now
+  now       ()     f32   — current time
+  limit     ()     i32   — rank bound for both sequential loops: ranks
                            in [limit, J) hold no queued slot, so the
                            greedy and backfill ``fori_loop``s stop there
-                           (a dynamic trip count — supported by Mosaic;
-                           bit-exact, see DESIGN.md §7).  Callers pass
-                           J to disable.
+                           (a dynamic trip count read from SMEM;
+                           bit-exact, see DESIGN.md §7).  None = J.
 
 Outputs:
   started (k, J) i32 — jobs started by this pass under each policy
-  free    (k, 1) f32 — free nodes after the pass
+  free    (k,)   f32 — free nodes after the pass
 """
 from __future__ import annotations
 
@@ -57,92 +70,133 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-NEG = -1.0
 BIG = 3.0e38  # ~f32 inf stand-in (pallas-friendly)
+TILE = 8      # forks per grid program: one f32/i32 sublane tile
+LANES = 128   # the job axis is padded to whole lane tiles
 
 
-def _pass_kernel(order_ref, queued_ref, nodes_ref, est_ref,
+def default_interpret() -> bool:
+    """Pallas interpret mode off the TPU, the compiled kernel on it."""
+    return jax.default_backend() != "tpu"
+
+
+def _pick(mask: jax.Array, x: jax.Array) -> jax.Array:
+    """Per row, ``x`` at the single lane where ``mask`` holds -> (T, 1).
+    A masked sum over one selected lane: exact for ints and floats."""
+    return jnp.sum(jnp.where(mask, x, jnp.zeros_like(x)), axis=1,
+                   keepdims=True)
+
+
+def _free_at(end_eff: jax.Array, nodes_eff: jax.Array) -> jax.Array:
+    """``out[r, i] = sum_j nodes_eff[r, j] * (end_eff[r, j] <= end_eff[r, i])``
+    for every fork row r of the tile: one (J, J) compare per row, with
+    j on sublanes so the reduction is a VPU sum over vregs."""
+    end_t = end_eff.T                                   # (J, T)
+    nodes_t = nodes_eff.T
+    sub = jax.lax.broadcasted_iota(jnp.int32, end_eff.shape, 0)
+    out = jnp.zeros_like(end_eff)
+    for r in range(end_eff.shape[0]):
+        le = end_t[:, r:r + 1] <= end_eff[r:r + 1, :]  # (J, J): [j, i]
+        row = jnp.sum(jnp.where(le, nodes_t[:, r:r + 1], 0.0), axis=0,
+                      keepdims=True)                    # (1, J)
+        out = jnp.where(sub == r, row, out)
+    return out
+
+
+def _pass_kernel(limit_ref, order_ref, queued_ref, nodes_ref, est_ref,
                  run_end_ref, run_nodes_ref, free_ref, now_ref,
-                 limit_ref, started_ref, free_out_ref):
-    """One scheduling pass for ONE policy (grid dim 0 = policy)."""
-    order = order_ref[0, :]          # (J,) i32 priority-ranked job ids
-    queued = queued_ref[0, :]        # (J,) i32
-    nodes = nodes_ref[0, :]          # (J,) f32
-    est = est_ref[0, :]
-    run_end = run_end_ref[0, :]
-    run_nodes = run_nodes_ref[0, :]
-    free0 = free_ref[0, 0]
-    now = now_ref[0, 0]
-    j_cap = order.shape[0]
+                 started_ref, free_out_ref):
+    """One scheduling pass for a tile of TILE forks (grid dim 0)."""
+    order = order_ref[...]           # (T, J) i32 priority-ranked job ids
+    queued = queued_ref[...]         # (T, J) i32
+    nodes = nodes_ref[...]           # (T, J) f32
+    est = est_ref[...]
+    run_end = run_end_ref[...]
+    run_nodes = run_nodes_ref[...]
+    free0 = free_ref[...]            # (T, 1) f32
+    now = now_ref[...]               # (T, 1) f32
+    j_cap = order.shape[1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, order.shape, 1)
     # rank bound: ranks >= limit hold no queued slot -> provable no-ops
     # in both sequential loops below (truncation is bit-exact)
     limit = jnp.minimum(limit_ref[0, 0], j_cap)
 
-    q_nodes = jnp.where(queued > 0, nodes, BIG)  # invalid jobs never fit
+    def at_rank(i):
+        """(slot mask (T, J), is_queued (T, 1), nodes (T, 1)) of the
+        job each fork ranks at ``i``."""
+        slot = lane == _pick(lane == i, order)
+        return slot, _pick(slot, queued) > 0, _pick(slot, nodes)
 
     # ---- pass 1: greedy in priority order (sequential) ---------------
     def greedy(i, carry):
         free, head_rank, started = carry
-        j = order[i]
-        fits = q_nodes[j] <= free
+        slot, is_queued, need = at_rank(i)
+        fits = jnp.where(is_queued, need, BIG) <= free  # invalid never fit
         no_head = head_rank < 0
-        can_start = fits & no_head
-        is_queued = queued[j] > 0
-        free = jnp.where(can_start & is_queued, free - nodes[j], free)
-        started = jnp.where(can_start & is_queued,
-                            started.at[j].set(1), started)
+        start = fits & no_head & is_queued
+        free = jnp.where(start, free - need, free)
+        started = jnp.where(slot & start, 1, started)
         blocked = is_queued & (~fits) & no_head
         head_rank = jnp.where(blocked, i, head_rank)
         return free, head_rank, started
 
-    started0 = jnp.zeros((j_cap,), dtype=jnp.int32)
     free1, head_rank, started1 = jax.lax.fori_loop(
-        0, limit, greedy, (free0, jnp.int32(-1), started0))
+        0, limit, greedy,
+        (free0, jnp.full(free0.shape, -1, jnp.int32),
+         jnp.zeros(order.shape, jnp.int32)))
 
-    head = order[jnp.maximum(head_rank, 0)]
     has_head = head_rank >= 0
-    head_nodes = jnp.where(has_head, nodes[head], 0.0)
+    head = _pick(lane == jnp.maximum(head_rank, 0), order)
+    head_nodes = jnp.where(has_head, _pick(lane == head, nodes), 0.0)
 
     # ---- shadow time without a sort (O(J^2) SIMD) ---------------------
     # running set = RUNNING jobs + jobs started in pass 1 (their end is
     # now + estimate; the twin never sees true runtimes).
-    end_eff = jnp.where(started1 > 0, now + est, run_end)       # (J,)
-    nodes_eff = jnp.where(started1 > 0, nodes, run_nodes)       # (J,)
-    # free_at[i] = free1 + sum_j nodes_eff[j] * (end_eff[j] <= end_eff[i])
-    le = (end_eff[None, :] <= end_eff[:, None]).astype(jnp.float32)
-    free_at = free1 + le @ nodes_eff                            # (J,)
+    end_eff = jnp.where(started1 > 0, now + est, run_end)       # (T, J)
+    nodes_eff = jnp.where(started1 > 0, nodes, run_nodes)       # (T, J)
+    free_at = free1 + _free_at(end_eff, nodes_eff)              # (T, J)
     feasible = (free_at >= head_nodes) & (end_eff < BIG)
     t_cand = jnp.where(feasible, end_eff, BIG)
-    shadow = jnp.where(has_head, jnp.min(t_cand), BIG)
+    shadow = jnp.where(has_head, jnp.min(t_cand, axis=1, keepdims=True),
+                       BIG)
     at_shadow = feasible & (end_eff <= shadow)
-    extra_raw = jnp.max(jnp.where(at_shadow, free_at, -BIG)) - head_nodes
-    extra = jnp.where(has_head,
-                      jnp.where(jnp.any(at_shadow), extra_raw, 0.0),
-                      BIG)
+    any_at = jnp.max(at_shadow.astype(jnp.int32), axis=1,
+                     keepdims=True) > 0
+    extra_raw = jnp.max(jnp.where(at_shadow, free_at, -BIG), axis=1,
+                        keepdims=True) - head_nodes
+    extra = jnp.where(has_head, jnp.where(any_at, extra_raw, 0.0), BIG)
 
     # ---- pass 2: EASY backfill (sequential) ---------------------------
     def backfill(i, carry):
         free, extra, started = carry
-        j = order[i]
-        cand = (queued[j] > 0) & (started[j] == 0) & (i != head_rank)
-        fits_now = nodes[j] <= free
-        cond_a = (now + est[j]) <= shadow
-        cond_b = nodes[j] <= extra
+        slot, is_queued, need = at_rank(i)
+        cand = is_queued & (_pick(slot, started) == 0) & (i != head_rank)
+        fits_now = need <= free
+        cond_a = (now + _pick(slot, est)) <= shadow
+        cond_b = need <= extra
         start = cand & fits_now & (cond_a | cond_b)
-        free = jnp.where(start, free - nodes[j], free)
-        extra = jnp.where(start & (~cond_a), extra - nodes[j], extra)
-        started = jnp.where(start, started.at[j].set(1), started)
+        free = jnp.where(start, free - need, free)
+        extra = jnp.where(start & (~cond_a), extra - need, extra)
+        started = jnp.where(slot & start, 1, started)
         return free, extra, started
 
     # ranks <= head_rank cannot backfill (started in pass 1, or the head
-    # itself); no head -> nothing left to backfill at all
-    back_lo = jnp.where(head_rank >= 0, head_rank + 1, limit)
+    # itself); no head -> nothing left to backfill at all.  The tile
+    # starts at its shallowest fork's bound: the forks whose bound lies
+    # deeper find no candidate at the ranks in between, so their carry
+    # is unchanged there.
+    back_lo = jnp.min(jnp.where(has_head, head_rank + 1, limit))
     free2, _, started = jax.lax.fori_loop(
         back_lo, limit, backfill, (free1, extra, started1))
 
-    started_ref[0, :] = started
-    free_out_ref[0, 0] = free2
+    started_ref[...] = started
+    free_out_ref[...] = free2
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
 def _limit_arr(limit, j_cap: int) -> jax.Array:
@@ -153,60 +207,16 @@ def _limit_arr(limit, j_cap: int) -> jax.Array:
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def policy_eval_pass(order: jax.Array, queued: jax.Array,
-                     nodes: jax.Array, est: jax.Array,
-                     run_end: jax.Array, run_nodes: jax.Array,
-                     free0: jax.Array, now: jax.Array,
-                     limit: jax.Array | None = None,
-                     *, interpret: bool = True):
-    """Batched scheduling pass: ``order`` is (k, J); the rest (J,).
-
-    Returns (started (k, J) i32, free (k,) f32).  ``interpret=True``
-    runs the kernel body on CPU (this container); on TPU pass False.
-    ``limit`` (i32 scalar, shared by all programs) truncates the
-    sequential rank loops; None scans all J ranks.
-    """
-    k, j_cap = order.shape
-    f32 = jnp.float32
-
-    shared = lambda: pl.BlockSpec((1, j_cap), lambda p: (0, 0))  # noqa: E731
-    per_policy = lambda: pl.BlockSpec((1, j_cap), lambda p: (p, 0))  # noqa: E731
-    scalar = lambda: pl.BlockSpec((1, 1), lambda p: (0, 0))  # noqa: E731
-
-    started, free = pl.pallas_call(
-        _pass_kernel,
-        grid=(k,),
-        in_specs=[per_policy(), shared(), shared(), shared(), shared(),
-                  shared(), scalar(), scalar(), scalar()],
-        out_specs=[per_policy(), pl.BlockSpec((1, 1), lambda p: (p, 0))],
-        out_shape=[
-            jax.ShapeDtypeStruct((k, j_cap), jnp.int32),
-            jax.ShapeDtypeStruct((k, 1), f32),
-        ],
-        interpret=interpret,
-    )(order,
-      queued.reshape(1, j_cap).astype(jnp.int32),
-      nodes.reshape(1, j_cap).astype(f32),
-      est.reshape(1, j_cap).astype(f32),
-      run_end.reshape(1, j_cap).astype(f32),
-      run_nodes.reshape(1, j_cap).astype(f32),
-      free0.reshape(1, 1).astype(f32),
-      now.reshape(1, 1).astype(f32),
-      _limit_arr(limit, j_cap))
-    return started, free[:, 0]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
 def policy_eval_pass_batched(order: jax.Array, queued: jax.Array,
                              nodes: jax.Array, est: jax.Array,
                              run_end: jax.Array, run_nodes: jax.Array,
                              free0: jax.Array, now: jax.Array,
                              limit: jax.Array | None = None,
-                             *, interpret: bool = True):
+                             *, interpret: bool):
     """Fully policy-batched scheduling pass: ALL inputs are (k, J)
-    (``free0``/``now`` are (k,)) — one grid program per fork, each
-    reading its own row.  Used inside the batched drain, where fork
-    states have diverged (different jobs running, different clocks,
+    (``free0``/``now`` are (k,)) — one row per fork, TILE forks per
+    grid program.  Used inside the batched drain, where fork states
+    have diverged (different jobs running, different clocks,
     ensemble-perturbed estimates).
 
     Returns (started (k, J) i32, free (k,) f32).  ``limit`` (i32
@@ -214,30 +224,66 @@ def policy_eval_pass_batched(order: jax.Array, queued: jax.Array,
     truncates the sequential rank loops; None scans all J ranks.
     """
     k, j_cap = order.shape
+    kp, jp = _round_up(k, TILE), _round_up(j_cap, LANES)
     f32 = jnp.float32
 
-    per_policy = lambda: pl.BlockSpec((1, j_cap), lambda p: (p, 0))  # noqa: E731
-    per_scalar = lambda: pl.BlockSpec((1, 1), lambda p: (p, 0))  # noqa: E731
-    shared_scalar = lambda: pl.BlockSpec((1, 1), lambda p: (0, 0))  # noqa: E731
+    def rows(x, dtype, fill):                 # (k, J) -> (kp, jp)
+        return jnp.pad(x.astype(dtype), ((0, kp - k), (0, jp - j_cap)),
+                       constant_values=fill)
+
+    def col(x):                               # (k,) -> (kp, 1)
+        return jnp.pad(x.astype(f32).reshape(k, 1), ((0, kp - k), (0, 0)))
+
+    # padded ranks hold the padded (never queued) slots
+    order_p = jnp.concatenate(
+        [order.astype(jnp.int32),
+         jnp.broadcast_to(jnp.arange(j_cap, jp, dtype=jnp.int32),
+                          (k, jp - j_cap))], axis=1)
+    order_p = jnp.pad(order_p, ((0, kp - k), (0, 0)))
+
+    row_spec = pl.BlockSpec((TILE, jp), lambda p: (p, 0))
+    col_spec = pl.BlockSpec((TILE, 1), lambda p: (p, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
 
     started, free = pl.pallas_call(
         _pass_kernel,
-        grid=(k,),
-        in_specs=[per_policy()] * 6 + [per_scalar(), per_scalar(),
-                                       shared_scalar()],
-        out_specs=[per_policy(), per_scalar()],
+        grid=(kp // TILE,),
+        in_specs=[smem] + [row_spec] * 6 + [col_spec, col_spec],
+        out_specs=[row_spec, col_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((k, j_cap), jnp.int32),
-            jax.ShapeDtypeStruct((k, 1), f32),
+            jax.ShapeDtypeStruct((kp, jp), jnp.int32),
+            jax.ShapeDtypeStruct((kp, 1), f32),
         ],
         interpret=interpret,
-    )(order.astype(jnp.int32),
-      queued.astype(jnp.int32),
-      nodes.astype(f32),
-      est.astype(f32),
-      run_end.astype(f32),
-      run_nodes.astype(f32),
-      free0.reshape(k, 1).astype(f32),
-      now.reshape(k, 1).astype(f32),
-      _limit_arr(limit, j_cap))
-    return started, free[:, 0]
+    )(_limit_arr(limit, j_cap),
+      order_p,
+      rows(queued, jnp.int32, 0),
+      rows(nodes, f32, 0.0),
+      rows(est, f32, 0.0),
+      rows(run_end, f32, jnp.inf),
+      rows(run_nodes, f32, 0.0),
+      col(free0),
+      col(now))
+    return started[:k, :j_cap], free[:k, 0]
+
+
+def policy_eval_pass(order: jax.Array, queued: jax.Array,
+                     nodes: jax.Array, est: jax.Array,
+                     run_end: jax.Array, run_nodes: jax.Array,
+                     free0: jax.Array, now: jax.Array,
+                     limit: jax.Array | None = None,
+                     *, interpret: bool):
+    """Shared-snapshot scheduling pass: ``order`` is (k, J), the rest
+    (J,) / scalars, broadcast onto ``policy_eval_pass_batched``.
+
+    Returns (started (k, J) i32, free (k,) f32).  ``limit`` (i32
+    scalar) truncates the sequential rank loops; None scans all J
+    ranks.
+    """
+    k, j_cap = order.shape
+    rows = lambda x: jnp.broadcast_to(x, (k, j_cap))  # noqa: E731
+    fork = lambda x: jnp.broadcast_to(jnp.reshape(x, ()), (k,))  # noqa: E731
+    return policy_eval_pass_batched(
+        order, rows(queued), rows(nodes), rows(est), rows(run_end),
+        rows(run_nodes), fork(free0), fork(now), limit,
+        interpret=interpret)

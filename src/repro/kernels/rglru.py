@@ -49,7 +49,7 @@ def _rglru_kernel(a_ref, x_ref, h0_ref, y_ref, h_ref, *, block_t: int):
 def rglru(a: jax.Array, x: jax.Array, h0: jax.Array, *,
           block_t: int = DEFAULT_BLOCK_T,
           block_w: int = DEFAULT_BLOCK_W,
-          interpret: bool = True):
+          interpret: bool):
     """a, x: (B, S, W) f32; h0: (B, W) f32.
     Returns (h_all (B, S, W) f32, h_final (B, W) f32)."""
     b, s, w = a.shape

@@ -60,7 +60,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, state_ref, *,
                    static_argnames=("block_t", "interpret"))
 def wkv6(r: jax.Array, k: jax.Array, v: jax.Array, w: jax.Array,
          u: jax.Array, *, block_t: int = DEFAULT_BLOCK_T,
-         interpret: bool = True):
+         interpret: bool):
     """r/k/v/w: (B, S, H, N) f32; u: (H, N) f32.
     Returns (y (B, S, H, N) f32, final state (B, H, N, N) f32)."""
     b, s, h, n = r.shape

@@ -14,37 +14,23 @@ from typing import Optional, Tuple
 
 import jax
 import numpy as np
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit/auto axis types
-    from jax.sharding import AxisType
-except ImportError:  # jax 0.4.x — meshes are implicitly "auto"
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """Version-portable ``shard_map`` (the fleet engine's per-device
-    SPMD primitive): top-level ``jax.shard_map`` on new jax, the
-    ``jax.experimental`` spelling on 0.4.x.  Replication checking is
-    disabled where the knob exists — every fleet output is explicitly
-    sharded or reduced by the caller, and the checker predates
-    while-loop-heavy bodies like the drain."""
-    try:
-        smap = jax.shard_map                      # jax >= 0.6
-    except AttributeError:
-        from jax.experimental.shard_map import shard_map as smap
-    try:
-        return smap(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_rep=False)
-    except TypeError:                             # knob renamed/removed
-        return smap(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    """``jax.shard_map`` with the varying-manual-axes check off (the
+    fleet engine's per-device SPMD primitive).  Every fleet output is
+    explicitly sharded or reduced by the caller, and the check rejects
+    the drain's ``while_loop`` carries, the replay's pass-elision
+    ``lax.cond`` and the ``pallas_call`` outputs, whose types it cannot
+    infer as varying."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _make_mesh(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -322,8 +322,9 @@ def main() -> None:
     ap.add_argument("--no-compile-cache", action="store_true",
                     help="skip the persistent XLA compilation cache "
                          "(default: cache compiled engines under "
-                         "~/.cache/repro-jax-cache so the ~1.5 s replay "
-                         "compile is paid once per machine)")
+                         "$JAX_COMPILATION_CACHE_DIR, else <repo>/"
+                         ".jax_cache, so compiles are paid once per "
+                         "machine)")
     ap.add_argument("--jobs", type=int, default=150)
     ap.add_argument("--nodes", type=int, default=32)
     ap.add_argument("--pool", default="paper",
@@ -649,11 +650,19 @@ def main() -> None:
           f"reordered={res['reordered']} gaps={res['gaps']} "
           f"lost={res['lost']} resyncs={res['resyncs']} "
           f"read_retries={res['read_retries']}")
-    print(f"bus health: {bus.health()}"
+    health = bus.health()
+    print(f"bus health: {health}"
           + (f", chaos injected: {view.stats}" if args.chaos else ""))
     if twin.dead_letters:
         print(f"dead letters: {len(twin.dead_letters)} quarantined "
               f"(first: {twin.dead_letters[0].reason})")
+    # Quarantine keeps the twin serving, but outside --chaos (where
+    # faults are injected on purpose) a quarantined event or a failed
+    # bus subscriber is a fault of the run: exit nonzero.
+    if not args.chaos and (twin.dead_letters or health["callback_failures"]):
+        raise SystemExit(
+            f"twin_loop: {len(twin.dead_letters)} dead letter(s), "
+            f"{health['callback_failures']} bus callback failure(s)")
 
 
 if __name__ == "__main__":

@@ -209,7 +209,6 @@ def _moe_apply_ep(cfg: ModelConfig, rules, params, x: jax.Array
     admit — same expected drop rate, simpler = faster; on a 1-shard
     mesh it equals the global-capacity reference exactly (tested).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m = cfg.moe
@@ -316,13 +315,13 @@ def _moe_apply_ep(cfg: ModelConfig, rules, params, x: jax.Array
         }
         return y.reshape(bl, sl, d), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(dp_spec, "model", None), router_spec,
                   w_specs["moe.w_gate"], w_specs["moe.w_up"],
                   w_specs["moe.w_down"]),
         out_specs=(P(dp_spec, "model", None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params["moe.router"], params["moe.w_gate"],
       params["moe.w_up"], params["moe.w_down"])
 

@@ -17,6 +17,7 @@ The contract under test:
 """
 import warnings
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -311,8 +312,12 @@ def test_replay_grid_default_objective_is_score():
     scen = _grid(S=2)
     pool = parse_pool("paper")
     out = REF.replay_grid(scen, pool.spec)
-    costs = policy_cost(out.metrics, PAPER_WEIGHTS)
-    costs = jnp.where(out.deadlocked, jnp.inf, costs)
+    # expected costs under jit, like the replay's own selection: eager
+    # op-by-op arithmetic misses XLA's FMA contraction (DESIGN.md §9)
+    # and lands 1 ulp away
+    costs = jax.jit(lambda m, dead: jnp.where(
+        dead, jnp.inf, policy_cost(m, PAPER_WEIGHTS)))(
+            out.metrics, out.deadlocked)
     np.testing.assert_array_equal(np.asarray(out.costs),
                                   np.asarray(costs))
     np.testing.assert_array_equal(np.asarray(out.best),
