@@ -119,7 +119,8 @@ def twin_phase(trace, nodes: int, dev) -> None:
                       twin.telemetry.policy_start_distribution())
         print(f"twin {name}: {report.n_jobs} jobs, {lat['n']} cycles, "
               f"wall {time.perf_counter() - t0:.3f} s, cycle p50 "
-              f"{lat['p50_s'] * 1e3:.3f} ms, peak_bytes_in_use "
+              f"{lat['p50_s'] * 1e3:.3f} ms, p95 "
+              f"{lat['p95_s'] * 1e3:.3f} ms, peak_bytes_in_use "
               f"{peak_bytes(dev)}", flush=True)
         print(f"  metrics {runs[name][0]}", flush=True)
         print(f"  policy mix {runs[name][1]}", flush=True)

@@ -49,5 +49,6 @@ print(f"avg slowdown {report.avg_slowdown:5.2f}   utilization "
 print("policy mix:", {k: f"{v:.0f}%" for k, v in
                       twin.telemetry.policy_start_distribution().items()})
 lat = twin.telemetry.cycle_latency_stats()
-print(f"decision latency p50 {lat['p50_s'] * 1e3:.1f} ms over "
-      f"{lat['n']} cycles (paper: 'a few seconds')")
+print(f"decision latency p50 {lat['p50_s'] * 1e3:.1f} ms, p95 "
+      f"{lat['p95_s'] * 1e3:.1f} ms over {lat['n']} cycles "
+      f"(paper: 'a few seconds')")

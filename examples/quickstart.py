@@ -180,4 +180,7 @@ for name, m in per_policy.items():
           f"{m['max_wait']:9.1f} {m['utilization']:6.3f}")
 print("\npolicy mix (Table 1):",
       twin.telemetry.policy_start_distribution())
-print("cycle latency:", twin.telemetry.cycle_latency_stats())
+lat = twin.telemetry.cycle_latency_stats()
+print(f"cycle latency: p50 {lat['p50_s'] * 1e3:.1f} ms, p95 "
+      f"{lat['p95_s'] * 1e3:.1f} ms over {lat['n']} cycles; stage p50 ms",
+      {name: round(s * 1e3, 3) for name, s in lat["stage_p50_s"].items()})

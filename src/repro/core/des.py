@@ -39,6 +39,12 @@ running job.
   grid is ONE device computation (``engine.replay_grid``) instead of
   S·P Python event loops, bit-identical to the host emulator's static
   mode (tests/test_replay.py).
+
+The batched loops name their stages with ``jax.named_scope`` —
+``keys`` (priority keys and argsort), ``pass`` (the scheduling pass and
+its starts) and ``advance`` (the next event and the clock) — and the
+engine names its selection ``select``, so a profile's op metadata
+attributes device time to each stage.
 """
 from __future__ import annotations
 
@@ -212,30 +218,34 @@ def simulate_to_drain_batched(states: SimState, order_fn: Callable[[SimState], j
         active = active_mask(st, dead)                      # (k,)
 
         # ---- schedule pass on the whole batch ------------------------
-        order = order_fn(st)                                # (k, J)
-        limit = (pass_rank_limit(st, active)
-                 if dynamic_bounds else None)
-        started = pass_fn(st, order, limit) & active[:, None]  # (k, J)
-        st = apply_starts(st, started)
-        first = jnp.where(it == 0, started, first)
+        with jax.named_scope("keys"):
+            order = order_fn(st)                            # (k, J)
+        with jax.named_scope("pass"):
+            limit = (pass_rank_limit(st, active)
+                     if dynamic_bounds else None)
+            started = pass_fn(st, order, limit) & active[:, None]
+            st = apply_starts(st, started)
+            first = jnp.where(it == 0, started, first)
 
         # ---- advance each fork to its next predicted completion ------
-        jobs = st.jobs
-        running = jobs.state == RUNNING
-        has_queued = jnp.any(jobs.state == QUEUED, axis=1)  # (k,)
-        ends = jnp.where(running, jobs.end_t, jnp.inf)
-        t_next = jnp.maximum(jnp.min(ends, axis=1), st.now)  # (k,)
-        can_advance = active & has_queued & jnp.isfinite(t_next)
-        dead = dead | (active & has_queued & ~jnp.isfinite(t_next))
+        with jax.named_scope("advance"):
+            jobs = st.jobs
+            running = jobs.state == RUNNING
+            has_queued = jnp.any(jobs.state == QUEUED, axis=1)  # (k,)
+            ends = jnp.where(running, jobs.end_t, jnp.inf)
+            t_next = jnp.maximum(jnp.min(ends, axis=1), st.now)  # (k,)
+            can_advance = active & has_queued & jnp.isfinite(t_next)
+            dead = dead | (active & has_queued & ~jnp.isfinite(t_next))
 
-        ending = running & (jobs.end_t <= t_next[:, None]) & can_advance[:, None]
-        freed = jnp.sum(jnp.where(ending, jobs.nodes, 0), axis=1)
-        jobs = jobs._replace(state=jnp.where(ending, DONE, jobs.state))
-        st = st._replace(
-            jobs=jobs,
-            free_nodes=st.free_nodes + freed,
-            now=jnp.where(can_advance, t_next, st.now),
-        )
+            ending = (running & (jobs.end_t <= t_next[:, None])
+                      & can_advance[:, None])
+            freed = jnp.sum(jnp.where(ending, jobs.nodes, 0), axis=1)
+            jobs = jobs._replace(state=jnp.where(ending, DONE, jobs.state))
+            st = st._replace(
+                jobs=jobs,
+                free_nodes=st.free_nodes + freed,
+                now=jnp.where(can_advance, t_next, st.now),
+            )
         return st, first, it + 1, dead, iters + active.astype(jnp.int32)
 
     init = (states,
@@ -327,41 +337,44 @@ def simulate_replay_batched(states: SimState, arrival_t: jax.Array,
 
     def body(carry):
         st, cursor, true_end, start_ord, it, dead, events, passes = carry
-        jobs = st.jobs
+        with jax.named_scope("advance"):
+            jobs = st.jobs
 
-        # ---- pick each fork's next event -----------------------------
-        next_arr, cur = next_arrival(cursor)
-        running = jobs.state == RUNNING
-        te = jnp.where(running, true_end, jnp.inf)
-        next_end = jnp.min(te, axis=1)                       # (k,)
-        # among simultaneous actual ends, retire the earliest-started
-        # (the host heap pops end events in push == start order)
-        at_min = running & (te <= next_end[:, None])
-        j_end = jnp.argmin(jnp.where(at_min, start_ord, ord_none), axis=1)
+            # ---- pick each fork's next event -------------------------
+            next_arr, cur = next_arrival(cursor)
+            running = jobs.state == RUNNING
+            te = jnp.where(running, true_end, jnp.inf)
+            next_end = jnp.min(te, axis=1)                   # (k,)
+            # among simultaneous actual ends, retire the earliest-started
+            # (the host heap pops end events in push == start order)
+            at_min = running & (te <= next_end[:, None])
+            j_end = jnp.argmin(jnp.where(at_min, start_ord, ord_none),
+                               axis=1)
 
-        is_arr = next_arr <= next_end        # equal times: arrival first
-        t_ev = jnp.minimum(next_arr, next_end)
-        has_event = jnp.isfinite(t_ev)
-        dead = dead | (~has_event & jnp.any(jobs.state == QUEUED, axis=1))
-        live = has_event & ~dead                             # (k,)
+            is_arr = next_arr <= next_end        # equal times: arrival first
+            t_ev = jnp.minimum(next_arr, next_end)
+            has_event = jnp.isfinite(t_ev)
+            dead = dead | (~has_event
+                           & jnp.any(jobs.state == QUEUED, axis=1))
+            live = has_event & ~dead                         # (k,)
 
-        # ---- inject the arrival (slot = cursor) ----------------------
-        arr = live & is_arr
-        hit_arr = (slots[None, :] == cur[:, None]) & arr[:, None]
-        jstate = jnp.where(hit_arr, QUEUED, jobs.state)
-        cursor = cursor + arr.astype(jnp.int32)
+            # ---- inject the arrival (slot = cursor) ------------------
+            arr = live & is_arr
+            hit_arr = (slots[None, :] == cur[:, None]) & arr[:, None]
+            jstate = jnp.where(hit_arr, QUEUED, jobs.state)
+            cursor = cursor + arr.astype(jnp.int32)
 
-        # ---- retire the completion at its TRUE end time --------------
-        fin = live & ~is_arr
-        hit_end = (slots[None, :] == j_end[:, None]) & fin[:, None]
-        jstate = jnp.where(hit_end, DONE, jstate)
-        end_t = jnp.where(hit_end, true_end, jobs.end_t)
-        freed = jnp.sum(jnp.where(hit_end, jobs.nodes, 0), axis=1)
-        st = st._replace(
-            jobs=jobs._replace(state=jstate, end_t=end_t),
-            free_nodes=st.free_nodes + freed,
-            now=jnp.where(live, t_ev, st.now),
-        )
+            # ---- retire the completion at its TRUE end time ----------
+            fin = live & ~is_arr
+            hit_end = (slots[None, :] == j_end[:, None]) & fin[:, None]
+            jstate = jnp.where(hit_end, DONE, jstate)
+            end_t = jnp.where(hit_end, true_end, jobs.end_t)
+            freed = jnp.sum(jnp.where(hit_end, jobs.nodes, 0), axis=1)
+            st = st._replace(
+                jobs=jobs._replace(state=jstate, end_t=end_t),
+                free_nodes=st.free_nodes + freed,
+                now=jnp.where(live, t_ev, st.now),
+            )
 
         # ---- one scheduling pass on the whole batch ------------------
         # Only live forks' starts survive the mask below, so the pass
@@ -373,16 +386,18 @@ def simulate_replay_batched(states: SimState, arrival_t: jax.Array,
 
         def run_pass(op):
             st, true_end, start_ord, passes = op
-            order = order_fn(st)
-            started = pass_fn(st, order,
-                              limit if dynamic_bounds else None)
-            started = started & live[:, None]
-            st = apply_starts(st, started)
-            true_end = jnp.where(started, st.now[:, None] + true_rt,
-                                 true_end)
-            start_ord = jnp.where(started,
-                                  it * (max_jobs + 1) + slots[None, :],
-                                  start_ord)
+            with jax.named_scope("keys"):
+                order = order_fn(st)
+            with jax.named_scope("pass"):
+                started = pass_fn(st, order,
+                                  limit if dynamic_bounds else None)
+                started = started & live[:, None]
+                st = apply_starts(st, started)
+                true_end = jnp.where(started, st.now[:, None] + true_rt,
+                                     true_end)
+                start_ord = jnp.where(started,
+                                      it * (max_jobs + 1) + slots[None, :],
+                                      start_ord)
             return st, true_end, start_ord, passes + 1
 
         op = (st, true_end, start_ord, passes)

@@ -358,8 +358,9 @@ def hoisted_orders(states0: SimState, pool: EnginePool, plan: HoistPlan,
     plan_arr = np.asarray(plan, dtype=bool)
     ti_idx = jnp.asarray(np.nonzero(plan_arr)[0], dtype=jnp.int32)
     states_ti = jax.tree.map(lambda x: x[ti_idx], states0)
-    return jax.vmap(static_priority_order)(
-        states_ti, _index_pool(pool, ti_idx), ever_queued[ti_idx])
+    with jax.named_scope("keys"):
+        return jax.vmap(static_priority_order)(
+            states_ti, _index_pool(pool, ti_idx), ever_queued[ti_idx])
 
 
 def make_order_fn(states0: SimState, pool: EnginePool, plan: HoistPlan,
@@ -743,18 +744,19 @@ def _decide_impl(engine: DrainEngine, state: SimState, pool: EnginePool,
     k = pool_size(pool)
     eval_mask = state.jobs.state == QUEUED
     res = _drain_impl(engine, broadcast_state(state, k), pool, plan)
-    metrics = jax.vmap(drain_metrics, in_axes=(0, None))(res, eval_mask)
-    costs = objective.costs(metrics)
-    costs = jnp.where(res.deadlocked, jnp.inf, costs)
-    best = scoring.select_policy(costs)
-    return Decision(
-        policy_index=best,
-        costs=costs,
-        run_mask=res.first_started[best],
-        metrics=metrics,
-        deadlocked=res.deadlocked,
-        cost_terms=objective.cost_terms(metrics),
-    )
+    with jax.named_scope("select"):
+        metrics = jax.vmap(drain_metrics, in_axes=(0, None))(res, eval_mask)
+        costs = objective.costs(metrics)
+        costs = jnp.where(res.deadlocked, jnp.inf, costs)
+        best = scoring.select_policy(costs)
+        return Decision(
+            policy_index=best,
+            costs=costs,
+            run_mask=res.first_started[best],
+            metrics=metrics,
+            deadlocked=res.deadlocked,
+            cost_terms=objective.cost_terms(metrics),
+        )
 
 
 @functools.partial(jax.jit, static_argnames=("engine", "objective", "plan"))
@@ -1000,9 +1002,10 @@ def grid_select(objective: Objective, metrics: DrainMetrics,
     dispatch loses XLA's fused-multiply-add contraction of the score
     arithmetic, breaking cost bitwise-parity with the local path)."""
     grid = jax.tree.map(lambda x: x.reshape((-1, P) + x.shape[1:]), metrics)
-    costs = objective.costs(grid)                              # (S, P)
-    costs = jnp.where(deadlocked.reshape(-1, P), jnp.inf, costs)
-    return costs, jnp.argmin(costs, axis=-1)
+    with jax.named_scope("select"):
+        costs = objective.costs(grid)                          # (S, P)
+        costs = jnp.where(deadlocked.reshape(-1, P), jnp.inf, costs)
+        return costs, jnp.argmin(costs, axis=-1)
 
 
 @functools.partial(jax.jit, static_argnames=("objective", "P"))
@@ -1041,12 +1044,13 @@ def fan_select(objective: ObjectiveLike, metrics: DrainMetrics,
     dist = as_distributional(objective)
     grid = jax.tree.map(
         lambda x: x.reshape((-1, F, P) + x.shape[1:]), metrics)
-    member = dist.member_costs(grid)                       # (S, F, P)
-    member = jnp.where(deadlocked.reshape(-1, F, P), jnp.inf, member)
-    costs = dist.reduce_fan(member)                        # (S, P)
-    best = jnp.argmin(costs, axis=-1)
-    ci, width = member_uncertainty(member, axis=-2)
-    return member, costs, best, ci, width
+    with jax.named_scope("select"):
+        member = dist.member_costs(grid)                   # (S, F, P)
+        member = jnp.where(deadlocked.reshape(-1, F, P), jnp.inf, member)
+        costs = dist.reduce_fan(member)                    # (S, P)
+        best = jnp.argmin(costs, axis=-1)
+        ci, width = member_uncertainty(member, axis=-2)
+        return member, costs, best, ci, width
 
 
 @functools.partial(jax.jit, static_argnames=("objective", "F", "P"))
@@ -1069,7 +1073,8 @@ def _replay_impl(engine: DrainEngine, states: SimState,
         states, arrival_t, true_rt, order_fn, engine.pass_fn(),
         dynamic_bounds=engine.dynamic_bounds,
         elide_empty=engine.elide_empty)
-    metrics = jax.vmap(state_metrics)(res.state, valid, true_rt)
+    with jax.named_scope("select"):
+        metrics = jax.vmap(state_metrics)(res.state, valid, true_rt)
     return res, metrics
 
 

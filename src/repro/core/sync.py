@@ -41,6 +41,7 @@ from repro.core.events import Event, EventKind
 from repro.core.state import (DONE, INVALID, QUEUED, RUNNING, TIME_NONE,
                               JobTable, SimState, add_job, end_job,
                               requeue_job, resize_cluster, start_job)
+from repro.core.telemetry import fetch
 
 
 def apply_event(state: SimState, ev: Event,
@@ -92,7 +93,7 @@ def _apply_job_event_idempotent(state: SimState,
     a late straggler can only FILL IN what it knows (never re-run a
     resource effect).  One host-side state read per event — the same
     host-driven granularity as the normal path."""
-    cur = int(state.jobs.state[ev.job_id])
+    cur = int(fetch(state.jobs.state[ev.job_id]))
 
     if ev.kind == EventKind.QUEUEJOB:
         if cur != INVALID:          # already known (duplicate / late)

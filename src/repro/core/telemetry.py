@@ -1,9 +1,66 @@
-"""Twin telemetry: per-cycle latency, decisions, policy mix (Table 1)."""
+"""Twin telemetry: per-cycle latency, decisions, policy mix (Table 1),
+and the host path of each pump by stage.
+
+Each pump of ``SchedTwin`` runs its stages (``STAGES``) under
+``StageMeter.span``: a ``time.perf_counter`` span whose seconds add to
+the pump's stage totals, and a ``jax.profiler.TraceAnnotation`` of the
+same name, so a profile shows the stage on the host plane on the device
+ops' clock.  Beside the stage totals the meter counts the pump's
+events, its blocking device-to-host reads (``fetch``) and its backend
+compiles; a pump that records a decision cycle stamps all of them on
+that ``CycleRecord``.
+"""
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import math
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, Iterator, List, Optional, Sequence
+
+import jax
+import numpy as np
+
+#: The twin's host stages, in the order a pump runs them.
+STAGES = ("twin.read", "twin.ingest", "twin.resync", "twin.decide",
+          "twin.fetch", "twin.unpack", "twin.qrun")
+
+#: JAX's monitoring event for one backend compile.
+BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+class _ProcessCounts:
+    """Monotone counts over the whole process; a meter reads deltas."""
+    host_reads = 0
+    compiles = 0
+    listening = False
+
+
+def _on_duration(event: str, duration: float, **kwargs) -> None:
+    if event == BACKEND_COMPILE:
+        _ProcessCounts.compiles += 1
+
+
+def _listen_for_compiles() -> None:
+    """One compile listener per process, however many twins it builds."""
+    if not _ProcessCounts.listening:
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _ProcessCounts.listening = True
+
+
+def fetch(x) -> np.ndarray:
+    """``x`` as a host array.  A device array costs one blocking
+    device-to-host read, counted for the pump's ``host_reads``."""
+    if isinstance(x, jax.Array):
+        _ProcessCounts.host_reads += 1
+    return np.asarray(x)
+
+
+def _nearest_rank(values: Sequence[float], q: float) -> float:
+    """The smallest sample with at least a share ``q`` of the samples
+    at or below it."""
+    xs = sorted(values)
+    return xs[min(len(xs) - 1, max(0, math.ceil(q * len(xs)) - 1))]
 
 
 @dataclasses.dataclass
@@ -50,6 +107,14 @@ class CycleRecord:
     deadline_s: float = 0.0
     margin_s: float = 0.0
     deadline_miss: bool = False
+    # the pump that ran this cycle (``StageMeter``): host seconds per
+    # stage (every name in ``STAGES``, 0.0 where it did not run), its
+    # blocking device-to-host reads, the events it consumed and the
+    # backend compiles inside it.
+    stages: Dict[str, float] = dataclasses.field(default_factory=dict)
+    host_reads: int = 0
+    events: int = 0
+    compiles: int = 0
 
 
 @dataclasses.dataclass
@@ -202,16 +267,26 @@ class Telemetry:
         return out
 
     # ---- overhead (paper: "a few seconds per scheduling cycle") -------
-    def cycle_latency_stats(self) -> Dict[str, float]:
-        if not self.cycles:
-            return {"mean_s": 0.0, "max_s": 0.0, "p50_s": 0.0, "n": 0}
-        ws = sorted(c.wall_seconds for c in self.cycles)
-        n = len(ws)
+    def cycle_latency_stats(self) -> Dict[str, object]:
+        """The decision span (``wall_seconds``) by nearest rank, the
+        median of each host stage, and the mean blocking reads per
+        cycle."""
+        n = len(self.cycles)
+        if not n:
+            return {"n": 0, "mean_s": 0.0, "p50_s": 0.0, "p95_s": 0.0,
+                    "max_s": 0.0, "host_reads": 0.0,
+                    "stage_p50_s": dict.fromkeys(STAGES, 0.0)}
+        ws = [c.wall_seconds for c in self.cycles]
         return {
-            "mean_s": sum(ws) / n,
-            "max_s": ws[-1],
-            "p50_s": ws[n // 2],
             "n": n,
+            "mean_s": sum(ws) / n,
+            "p50_s": _nearest_rank(ws, 0.5),
+            "p95_s": _nearest_rank(ws, 0.95),
+            "max_s": max(ws),
+            "host_reads": sum(c.host_reads for c in self.cycles) / n,
+            "stage_p50_s": {
+                s: _nearest_rank([c.stages.get(s, 0.0) for c in self.cycles],
+                                0.5) for s in STAGES},
         }
 
 
@@ -230,3 +305,40 @@ class StopWatch:
     def __exit__(self, *exc) -> Optional[bool]:
         self.seconds = self._clock() - self._t0
         return None
+
+
+class StageMeter:
+    """One pump's host stages and counters (module docstring)."""
+
+    def __init__(self):
+        _listen_for_compiles()
+        self.reset()
+
+    def reset(self) -> None:
+        self.stages = dict.fromkeys(STAGES, 0.0)
+        self.events = 0
+        self._reads0 = _ProcessCounts.host_reads
+        self._compiles0 = _ProcessCounts.compiles
+
+    @contextlib.contextmanager
+    def span(self, name: str) -> Iterator[None]:
+        with jax.profiler.TraceAnnotation(name):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self.stages[name] += time.perf_counter() - t0
+
+    @contextlib.contextmanager
+    def pump(self, cycles: List[CycleRecord]) -> Iterator[None]:
+        """Meter one pump: totals start at zero, and a cycle the pump
+        records gets the pump's stages and counts when it returns."""
+        self.reset()
+        n0 = len(cycles)
+        yield
+        if len(cycles) > n0:
+            rec = cycles[-1]
+            rec.stages = dict(self.stages)
+            rec.host_reads = _ProcessCounts.host_reads - self._reads0
+            rec.compiles = _ProcessCounts.compiles - self._compiles0
+            rec.events = self.events

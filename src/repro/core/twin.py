@@ -27,6 +27,7 @@ make the whole twin crash-safe through ``checkpoint.manager``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -47,6 +48,7 @@ from repro.core.policies import (PAPER_POOL, PolicyPool, PolicySpec,
 from repro.core.race import RaceSpec, normalize_race
 from repro.core.scoring import ScoreWeights
 from repro.core.state import QUEUED, SimState, empty_state
+from repro.core.telemetry import fetch
 
 
 def _jsonable(x):
@@ -59,7 +61,7 @@ def _jsonable(x):
         return [_jsonable(v) for v in x]
     if isinstance(x, (bool, int, float, str)) or x is None:
         return x
-    arr = np.asarray(x)
+    arr = fetch(x)
     return arr.item() if arr.ndim == 0 else arr.tolist()
 
 
@@ -69,6 +71,16 @@ def _fork_pool(pool: PolicyPool, p: int) -> PolicyPool:
         spec=PolicySpec(pool.spec.family[p:p + 1],
                         pool.spec.theta[p:p + 1]),
         names=(pool.names[p],))
+
+
+def _metered(entry):
+    """An ingestion entry point run under the twin's ``StageMeter``:
+    stage totals start at zero, and the cycle it records carries them."""
+    @functools.wraps(entry)
+    def run(self, *args):
+        with self._meter.pump(self.telemetry.cycles):
+            return entry(self, *args)
+    return run
 
 
 class SchedTwin:
@@ -181,6 +193,7 @@ class SchedTwin:
         self.max_jobs = max_jobs
         self.state: SimState = empty_state(max_jobs, total_nodes)
         self.telemetry = telemetry.Telemetry()
+        self._meter = telemetry.StageMeter()
         self.free_nodes_probe = free_nodes_probe
         self.ensemble = ensemble
         self.ensemble_noise = ensemble_noise
@@ -210,6 +223,7 @@ class SchedTwin:
         self._incumbent: Optional[Tuple[str, int]] = None
 
     # ------------------------------------------------------------------
+    @_metered
     def pump(self) -> int:
         """③ consume pending events; run a decision cycle if any event
         opened a scheduling opportunity.  Returns #events consumed.
@@ -220,50 +234,61 @@ class SchedTwin:
         through ``_ingest`` (quarantine / dedup / reorder / gap
         classification) and losses trigger a probe resync."""
         ing = self.telemetry.ingest
+        span = self._meter.span
 
         def _count_retry(attempt: int, exc: Exception) -> None:
             ing.read_retries += 1
 
         try:
-            events = read_with_retry(
-                self.bus, self.CONSUMER, retries=self.read_retries,
-                backoff_s=self.read_backoff_s, sleep=self._sleep,
-                on_retry=_count_retry)
+            with span("twin.read"):
+                events = read_with_retry(
+                    self.bus, self.CONSUMER, retries=self.read_retries,
+                    backoff_s=self.read_backoff_s, sleep=self._sleep,
+                    on_retry=_count_retry)
         except BusReadError:
             ing.read_failures += 1
             return 0
+        self._meter.events = len(events)
         needs_cycle = False
         lost_any = False
-        t_latest = float(self.state.now)
-        for ev in events:
-            applied, cycle, gap, lost = self._ingest(ev)
-            needs_cycle |= cycle
-            lost_any |= lost
-            if gap or lost:
-                needs_cycle = True   # something is missing — resync+look
-            if applied:
-                t_latest = max(t_latest, float(ev.time))
+        with span("twin.ingest"):
+            t_latest = float(fetch(self.state.now))
+            for ev in events:
+                applied, cycle, gap, lost = self._ingest(ev)
+                needs_cycle |= cycle
+                lost_any |= lost
+                if gap or lost:
+                    needs_cycle = True   # something is missing — resync+look
+                if applied:
+                    t_latest = max(t_latest, float(ev.time))
         if lost_any and self.jobs_probe is not None:
             # events are gone for good (aged past the reorder window):
             # rebuild the job table from the authoritative probe — the
             # only heal for a dropped QUEUEJOB.
-            self.state = sync.resync_jobs(self.state, self.jobs_probe())
-            ing.resyncs += 1
-            t_latest = max(t_latest, float(self.state.now))
+            with span("twin.resync"):
+                self.state = sync.resync_jobs(self.state, self.jobs_probe())
+                ing.resyncs += 1
+                t_latest = max(t_latest, float(fetch(self.state.now)))
         if needs_cycle:
             self._decision_cycle(t_latest)
         return len(events)
 
+    @_metered
     def on_event(self, ev: Event) -> None:
         """Push-mode entry point (bus.subscribe)."""
-        self.bus.read(self.CONSUMER)  # keep offset in step with pushes
-        applied, needs_cycle, gap, lost = self._ingest(ev)
+        span = self._meter.span
+        with span("twin.read"):
+            self.bus.read(self.CONSUMER)  # keep offset in step with pushes
+        self._meter.events = 1
+        with span("twin.ingest"):
+            applied, needs_cycle, gap, lost = self._ingest(ev)
         if lost and self.jobs_probe is not None:
-            self.state = sync.resync_jobs(self.state, self.jobs_probe())
-            self.telemetry.ingest.resyncs += 1
+            with span("twin.resync"):
+                self.state = sync.resync_jobs(self.state, self.jobs_probe())
+                self.telemetry.ingest.resyncs += 1
         if needs_cycle or gap or lost:
             self._decision_cycle(float(ev.time) if applied
-                                 else float(self.state.now))
+                                 else float(fetch(self.state.now)))
 
     def _ingest(self, ev: Event) -> Tuple[bool, bool, bool, bool]:
         """Sanitize + apply ONE event.  Returns ``(applied, needs_cycle,
@@ -309,10 +334,10 @@ class SchedTwin:
         ``FanSpec.from_history`` fits its lognormal σ to these pairs."""
         if ev.kind != EventKind.JOBOBIT or ev.job_id < 0:
             return
-        start = float(self.state.jobs.start_t[ev.job_id])
+        start = float(fetch(self.state.jobs.start_t[ev.job_id]))
         if start < 0.0:  # never started in the mirror — no ground truth
             return
-        est = float(self.state.jobs.est_runtime[ev.job_id])
+        est = float(fetch(self.state.jobs.est_runtime[ev.job_id]))
         self.telemetry.record_residual(est, ev.time - start)
 
     # ------------------------------------------------------------------
@@ -393,17 +418,35 @@ class SchedTwin:
     def _decision_cycle(self, t: float) -> None:
         """④→⑦ : sync, simulate, select, feed back — under the deadline
         guard's ladder when one is configured."""
+        span = self._meter.span
         if self.free_nodes_probe is not None:
-            self.state = sync.resync_free_nodes(
-                self.state, self.free_nodes_probe())
+            with span("twin.resync"):
+                self.state = sync.resync_free_nodes(
+                    self.state, self.free_nodes_probe())
 
         level = self.guard.plan() if self.guard is not None else 0
         with telemetry.StopWatch(self._clock) as sw:
-            decision, race_out, names, source = self._decide_at_level(level)
-            run_mask = np.asarray(decision.run_mask)  # blocks for timing
+            with span("twin.decide"):
+                decision, race_out, names, source = \
+                    self._decide_at_level(level)
+            with span("twin.fetch"):
+                run_mask = fetch(decision.run_mask)  # blocks for timing
+        with span("twin.unpack"):
+            job_ids = self._record_cycle(t, level, sw.seconds, decision,
+                                         race_out, names, source, run_mask)
+        if job_ids:
+            # ⑦ qrun — the physical system will emit RUNJOB events that
+            # flow back through the bus and insert predicted-end events.
+            with span("twin.qrun"):
+                self.qrun(job_ids, t)
+
+    def _record_cycle(self, t: float, level: int, seconds: float, decision,
+                      race_out, names, source, run_mask) -> List[int]:
+        """The decision's results to the host, recorded as one
+        ``CycleRecord``; returns the jobs to ``qrun``."""
         guard_fields = {}
         if self.guard is not None:
-            missed, margin = self.guard.observe(level, sw.seconds)
+            missed, margin = self.guard.observe(level, seconds)
             guard_fields = dict(
                 guard_level=level,
                 deadline_s=self.guard.spec.budget_s,
@@ -412,17 +455,17 @@ class SchedTwin:
         job_ids = [int(j) for j in np.nonzero(run_mask)[0]]
         # decisions are reported by family name + θ ("WFP",
         # "wfp[a=2,tau=600]", ...); pool position stays the tie-break.
-        win_idx = int(decision.policy_index)
+        win_idx = int(fetch(decision.policy_index))
         winner = names[win_idx]
         src, idx = source
         self._incumbent = (src, win_idx) if idx is None else (src, idx)
         costs = {name: float(c)
-                 for name, c in zip(names, np.asarray(decision.costs))}
+                 for name, c in zip(names, fetch(decision.costs))}
         # the goal's per-term device-computed breakdown for ALL k forks
         # (policy -> term -> cost): downstream reports (radar areas,
         # summarize-style tables) consume this instead of recomputing
         # costs on the host from raw metrics.
-        term_arrays = {term: np.asarray(v)
+        term_arrays = {term: fetch(v)
                        for term, v in (decision.cost_terms or {}).items()}
         term_costs = {name: {term: float(v[i])
                              for term, v in term_arrays.items()}
@@ -433,12 +476,11 @@ class SchedTwin:
         fan_width = {}
         if decision.cost_ci is not None:
             cost_ci = {name: float(c)
-                       for name, c in zip(names,
-                                          np.asarray(decision.cost_ci))}
+                       for name, c in zip(names, fetch(decision.cost_ci))}
         if decision.fan_width is not None:
             fan_width = {name: float(w)
                          for name, w in zip(names,
-                                            np.asarray(decision.fan_width))}
+                                            fetch(decision.fan_width))}
         race_fields = {}
         if race_out is not None:
             race_fields = dict(
@@ -447,17 +489,14 @@ class SchedTwin:
                 race_separation=float(np.min(race_out.separation)),
                 race_stopped=race_out.stopped)
         self.telemetry.record(telemetry.CycleRecord(
-            time=t, wall_seconds=sw.seconds, policy=winner,
+            time=t, wall_seconds=seconds, policy=winner,
             costs=costs, n_started=len(job_ids), started_jobs=job_ids,
             objective=str(self.objective), term_costs=term_costs,
             cost_ci=cost_ci, fan_width=fan_width,
             fan_size=decision.fan_size, **race_fields, **guard_fields))
+        return job_ids
 
-        if job_ids:
-            # ⑦ qrun — the physical system will emit RUNJOB events that
-            # flow back through the bus and insert predicted-end events.
-            self.qrun(job_ids, t)
-
+    @_metered
     def flush(self) -> bool:
         """End-of-stream reconcile (the emulator's ``on_quiesce`` hook):
         when the producer has quiesced but jobs look unfinished, any
@@ -471,11 +510,14 @@ class SchedTwin:
         newly = self._tracker.flush()
         if newly:
             ing.lost += newly
-        if self.jobs_probe is not None:
-            self.state = sync.resync_jobs(self.state, self.jobs_probe())
-            ing.resyncs += 1
-        if bool((np.asarray(self.state.jobs.state) == QUEUED).any()):
-            self._decision_cycle(float(self.state.now))
+        with self._meter.span("twin.resync"):
+            if self.jobs_probe is not None:
+                self.state = sync.resync_jobs(self.state, self.jobs_probe())
+                ing.resyncs += 1
+            queued = bool((fetch(self.state.jobs.state) == QUEUED).any())
+            now = float(fetch(self.state.now)) if queued else 0.0
+        if queued:
+            self._decision_cycle(now)
             return True
         return False
 
@@ -483,7 +525,7 @@ class SchedTwin:
     def recover(self) -> None:
         """Rebuild the mirror from a full bus replay (twin restart)."""
         self.state = empty_state(self.state.jobs.capacity,
-                                 int(self.state.total_nodes))
+                                 int(fetch(self.state.total_nodes)))
         for ev in self.bus.replay():
             self.state, _ = sync.apply_event(self.state, ev)
 

@@ -639,8 +639,14 @@ def main() -> None:
                   f"reduction), mean {memb / len(cs):.1f}/cycle, "
                   f"stops {stops}")
     lat = twin.telemetry.cycle_latency_stats()
-    print(f"cycle latency: mean {lat['mean_s'] * 1e3:.1f} ms, "
-          f"p50 {lat['p50_s'] * 1e3:.1f} ms over {lat['n']} cycles")
+    print(f"cycle latency (decide + run-mask read): p50 "
+          f"{lat['p50_s'] * 1e3:.2f} ms, p95 {lat['p95_s'] * 1e3:.2f} ms, "
+          f"mean {lat['mean_s'] * 1e3:.2f} ms, max "
+          f"{lat['max_s'] * 1e3:.2f} ms over {lat['n']} cycles")
+    print("cycle stages, p50 ms: " + ", ".join(
+        f"{name.split('.')[-1]} {s * 1e3:.3f}"
+        for name, s in lat["stage_p50_s"].items())
+        + f"; host reads {lat['host_reads']:.1f}/cycle")
     res = twin.telemetry.resilience_stats()
     print(f"resilience: miss_rate={res['miss_rate']:.3f} "
           f"(misses={res['deadline_misses']}/{res['cycles']}, "
