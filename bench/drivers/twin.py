@@ -6,6 +6,7 @@ Every pump that records a decision cycle is timed on the host clock
 from the emulator's call to its return."""
 from __future__ import annotations
 
+import dataclasses
 import statistics
 import time
 from typing import Dict, List, Optional
@@ -40,6 +41,11 @@ class Driver:
         self.traces = gen.scenario_traces(cell.family, self.config,
                                           int(self.traffic["episodes"]), seed)
         self._steps = 0
+        # whether the program stamps each cycle with its pump's stages
+        # and counts: then every recorded cycle carries them
+        from repro.core.telemetry import CycleRecord
+        self.staged = "stages" in {
+            f.name for f in dataclasses.fields(CycleRecord)}
         self.episodes: List[tuple] = []      # (trace, [check.Cycle])
         self.failed = 0
 
@@ -75,14 +81,15 @@ class Driver:
                 record.add("pump_s", dt)
                 record.add("decide_s", cycles[-1].wall_seconds)
                 record.add("traced", float(traced))
-                # the pump's stages and blocking reads, where the
-                # program records them: one sample each per cycle
-                stamped = getattr(cycles[-1], "stages", None)
-                if stamped:
+                # the pump's stages and counts, where the program
+                # records them: one sample each per cycle
+                if self.staged:
+                    rec = cycles[-1]
                     for name in self.stages:
-                        record.add(name, stamped[name])
-                    record.add("host_reads", cycles[-1].host_reads)
-                    record.add("compiles", cycles[-1].compiles)
+                        record.add(name, rec.stages[name])
+                    record.add("host_reads", rec.host_reads)
+                    record.add("events", rec.events)
+                    record.add("compiles", rec.compiles)
 
         with span("emulator", traced):
             em.run(on_event=pump, on_quiesce=twin.flush)
@@ -155,10 +162,12 @@ class Driver:
                 for name, xs in zip(self.stages, times)))
             rest = [p - sum(parts) for p, parts in zip(window, zip(*times))]
             reads = statistics.mean(untraced(record, "host_reads"))
+            events = statistics.mean(untraced(record, "events"))
             lines.append(f"unattributed p50 {1e3 * quantile(rest, 0.5):.4f}"
                          f" ms of pump p50 "
                          f"{1e3 * quantile(window, 0.5):.4f} ms; "
-                         f"host reads per cycle {reads:.3f}")
+                         f"host reads per cycle {reads:.3f}, events per "
+                         f"cycle {events:.3f}")
             every = sum(record.samples["compiles"])
             calm = sum(untraced(record, "compiles"))
             lines.append(f"program compiles in the pumps: window "

@@ -1,6 +1,8 @@
 """Decision engine: median over cycles of the program's ``twin.decide``
 span (goal resolution, plan and dispatch of the decide, before its run
-mask is read)."""
+mask is read).  On the plain ``decide`` path, which the twin cells run,
+the span holds no blocking read; a raced or pruned decide reads its
+rungs inside it, and ``host_reads`` counts those reads."""
 from bench.readings import quantile, untraced
 
 

@@ -207,7 +207,8 @@ def main(seed: int = 0, pool_sizes: Sequence[int] = POOL_SIZES,
             f"overhead,live_cycle,mean_s={stats['mean_s']:.4f},"
             f"p50_s={stats['p50_s']:.4f},p95_s={stats['p95_s']:.4f},"
             f"max_s={stats['max_s']:.4f},n={stats['n']},"
-            f"host_reads={stats['host_reads']:.1f},paper=a few seconds")
+            f"host_reads={stats['host_reads']:.1f},"
+            f"events={stats['events']:.2f},paper=a few seconds")
         extra["live_cycle"] = stats
 
     state = snapshot_state(seed)

@@ -87,6 +87,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.telemetry import fetch
+
 __all__ = [
     "FanSpec", "PruneInfo", "perturb_block", "perturb_rows",
     "perturb_window", "materialize_fan", "dominance_keep",
@@ -450,7 +452,7 @@ def pruned_fan_grid(scenarios, pool, fan, objective=None, *,
     pool = _eng.as_pool(pool)
     pre = dataclasses.replace(spec, n=min(pre_n, spec.n))
     pre_out = eng.fan_grid(scenarios, pool, pre, goal)
-    pre_members = np.asarray(pre_out.member_costs)
+    pre_members = fetch(pre_out.member_costs)
     pointwise = as_distributional(goal).reduction == "regret"
     keep = dominance_keep(pre_members, pointwise=pointwise)
     keep_idx = np.nonzero(keep)[0]
@@ -490,7 +492,7 @@ def pruned_fan_grid(scenarios, pool, fan, objective=None, *,
         cost_ci=ci, fan_width=width)
     info = PruneInfo(
         keep=keep_idx,
-        best=keep_idx[np.asarray(out.best)],
+        best=keep_idx[fetch(out.best)],
         rate=1.0 - Pk / P,
         pre_members=pre_members,
         members=S * (pre.n * P + (spec.n - pre.n) * Pk),

@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.state import JobTable
+from repro.core.telemetry import fetch
 
 # Canonical ids — tie-break order is numeric order (paper §4.2).
 WFP = 0    # ALCF utility: run job maximizing (wait/est)^3 * nodes
@@ -263,13 +264,13 @@ def time_invariant_mask(pool) -> np.ndarray:
     if hit is not None:
         return hit
     if isinstance(pool, PolicySpec):
-        fam = np.asarray(pool.family).reshape(-1)
-        th = np.asarray(pool.theta).reshape(fam.shape[0], -1)
+        fam = fetch(pool.family).reshape(-1)
+        th = fetch(pool.theta).reshape(fam.shape[0], -1)
         mask = ((fam == FAM_LIN)
                 & (th[:, _WAIT_COL] == 0.0)
                 & (th[:, _XF_COL] == 0.0))
     else:
-        ids = np.asarray(pool).reshape(-1)
+        ids = fetch(pool).reshape(-1)
         mask = np.isin(ids, sorted(STATIC_KEY_IDS))
     mask.setflags(write=False)
     try:

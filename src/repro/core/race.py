@@ -51,6 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.fan import FanSpec, normalize_fan
+from repro.core.telemetry import fetch
 
 __all__ = [
     "RaceSpec", "RungRecord", "RaceOutcome", "normalize_race",
@@ -229,12 +230,12 @@ def run_race(spec: RaceSpec, S: int, P: int, objective,
             raise RuntimeError(
                 f"racing window [{lo}, {hi}) would replay an already-"
                 f"evaluated member")
-        mc = np.asarray(eval_window(active, lo, hi), np.float32)
+        mc = np.asarray(fetch(eval_window(active, lo, hi)), np.float32)
         buf[:, lo:hi, active] = mc
         spent += S * w * len(active)
         f_done = hi
         cur = buf[:, :hi, :][:, :, active]           # (S, hi, Pa)
-        costs, ci, width = (np.asarray(x) for x in
+        costs, ci, width = (fetch(x) for x in
                             rung_stats(objective, cur, spec.z))
         if on_rung is not None:
             on_rung(active, costs, ci, width)
@@ -366,17 +367,16 @@ def decide_race(state, pool, race, objective=None, *, engine=None):
             eng, state, sub, spec.fan, goal, eng.plan(sub),
             lo, hi - lo)
         if lo == 0:
-            first0["mask"] = np.asarray(f0)      # (k, J): rung 0 = full pool
-        dead[active] |= np.asarray(md).any(axis=0)
-        sums = jax.tree.map(lambda x: np.asarray(x).sum(axis=0,
-                                                        dtype=np.float64),
+            first0["mask"] = fetch(f0)           # (k, J): rung 0 = full pool
+        dead[active] |= fetch(md).any(axis=0)
+        sums = jax.tree.map(lambda x: fetch(x).sum(axis=0, dtype=np.float64),
                             mm)
         if msum is None:
             msum = jax.tree.map(lambda s: np.zeros(k, np.float64), sums)
         msum = jax.tree.map(
             lambda acc, s: _scatter_add(acc, active, s), msum, sums)
         mcount[active] += hi - lo
-        return np.asarray(mc)[None]              # (S=1, W, Pa)
+        return fetch(mc)[None]                   # (S=1, W, Pa)
 
     def on_rung(active, costs, ci, width):
         full["costs"][active] = costs[0]

@@ -269,12 +269,12 @@ class Telemetry:
     # ---- overhead (paper: "a few seconds per scheduling cycle") -------
     def cycle_latency_stats(self) -> Dict[str, object]:
         """The decision span (``wall_seconds``) by nearest rank, the
-        median of each host stage, and the mean blocking reads per
-        cycle."""
+        median of each host stage, and the mean blocking reads and
+        events per cycle."""
         n = len(self.cycles)
         if not n:
             return {"n": 0, "mean_s": 0.0, "p50_s": 0.0, "p95_s": 0.0,
-                    "max_s": 0.0, "host_reads": 0.0,
+                    "max_s": 0.0, "host_reads": 0.0, "events": 0.0,
                     "stage_p50_s": dict.fromkeys(STAGES, 0.0)}
         ws = [c.wall_seconds for c in self.cycles]
         return {
@@ -284,6 +284,7 @@ class Telemetry:
             "p95_s": _nearest_rank(ws, 0.95),
             "max_s": max(ws),
             "host_reads": sum(c.host_reads for c in self.cycles) / n,
+            "events": sum(c.events for c in self.cycles) / n,
             "stage_p50_s": {
                 s: _nearest_rank([c.stages.get(s, 0.0) for c in self.cycles],
                                 0.5) for s in STAGES},
@@ -331,14 +332,33 @@ class StageMeter:
 
     @contextlib.contextmanager
     def pump(self, cycles: List[CycleRecord]) -> Iterator[None]:
-        """Meter one pump: totals start at zero, and a cycle the pump
-        records gets the pump's stages and counts when it returns."""
+        """Meter one pump: totals start at zero, and the cycle the pump
+        records gets the pump's stages and counts when it returns.
+
+        An entry point run inside another (a push-mode ``on_event``
+        fired by the enclosing pump's ``qrun``) is metered on its own:
+        a cycle it records gets its own stages and counts, which leave
+        the enclosing pump's.  One that records no cycle adds its
+        events, reads and compiles to the enclosing pump.  Either way
+        its time is in the enclosing pump's ``twin.qrun``."""
+        enclosing = (self.stages, self.events, self._reads0,
+                     self._compiles0)
         self.reset()
         n0 = len(cycles)
-        yield
-        if len(cycles) > n0:
-            rec = cycles[-1]
-            rec.stages = dict(self.stages)
-            rec.host_reads = _ProcessCounts.host_reads - self._reads0
-            rec.compiles = _ProcessCounts.compiles - self._compiles0
-            rec.events = self.events
+        try:
+            yield
+        finally:
+            stages, events = self.stages, self.events
+            reads = _ProcessCounts.host_reads - self._reads0
+            compiles = _ProcessCounts.compiles - self._compiles0
+            (self.stages, self.events, self._reads0,
+             self._compiles0) = enclosing
+            if len(cycles) > n0:
+                rec = cycles[n0]
+                rec.stages = dict(stages)
+                rec.host_reads, rec.compiles = reads, compiles
+                rec.events = events
+                self._reads0 += reads
+                self._compiles0 += compiles
+            else:
+                self.events += events

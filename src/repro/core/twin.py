@@ -248,7 +248,7 @@ class SchedTwin:
         except BusReadError:
             ing.read_failures += 1
             return 0
-        self._meter.events = len(events)
+        self._meter.events += len(events)
         needs_cycle = False
         lost_any = False
         with span("twin.ingest"):
@@ -279,7 +279,7 @@ class SchedTwin:
         span = self._meter.span
         with span("twin.read"):
             self.bus.read(self.CONSUMER)  # keep offset in step with pushes
-        self._meter.events = 1
+        self._meter.events += 1
         with span("twin.ingest"):
             applied, needs_cycle, gap, lost = self._ingest(ev)
         if lost and self.jobs_probe is not None:

@@ -77,6 +77,8 @@ def test_every_cycle_has_all_stages_and_exact_counts():
     # pump: (events, reads before the decision) by hand:
     #   now 1, one mirror-state read per job event, and a JOBOBIT of a
     #   started job 2 more (its start and estimate for the residual).
+    #   The twin's first cycle also reads its pool's families and θ
+    #   once, for the engine's hoist plan.
     s.publish(_queue(0, 0.0, nodes=10, est=300.0))
     a = s.pump()                                  # QUEUEJOB 0: starts
     s.publish(_queue(1, 5.0, nodes=10, est=200.0))
@@ -88,7 +90,7 @@ def test_every_cycle_has_all_stages_and_exact_counts():
     s.publish(Event(EventKind.RUNJOB, 61.0, 3))   # unknown job: no cycle
     e = s.pump()
     # (events, reads before the decision, jobs started)
-    expected = {"a": (1, 1 + 1, 1), "b": (2, 1 + 2, 0),
+    expected = {"a": (1, 1 + 1 + 2, 1), "b": (2, 1 + 2, 0),
                 "c": (1, 1 + 1 + 2, 1), "d": (2, 1 + 2, 1)}
     for name, (dt, rec) in zip("abcd", (a, b, c, d)):
         events, reads, started = expected[name]
@@ -108,6 +110,54 @@ def test_every_cycle_has_all_stages_and_exact_counts():
     for rec in s.twin.telemetry.cycles:
         assert (rec.stages["twin.decide"] + rec.stages["twin.fetch"]
                 <= rec.wall_seconds)
+
+
+def test_a_nested_entry_point_meters_its_own_cycle():
+    # push mode: the first QUEUEJOB's cycle starts job 0, whose qrun
+    # fires on_event for RUNJOB 0 (no cycle) and for QUEUEJOB 1, which
+    # runs a cycle of its own inside the first one's qrun
+    s = ScriptedTwin()
+    s.twin.bus.subscribe(s.twin.on_event)
+
+    def queue_one_more():
+        if 1 not in s.nodes:
+            s.publish(_queue(1, 0.0, nodes=2))
+
+    s.qrun_extra = queue_one_more
+    before = telemetry._ProcessCounts.host_reads
+    s.publish(_queue(0, 0.0, nodes=4))
+    reads = telemetry._ProcessCounts.host_reads - before
+    outer, inner = s.twin.telemetry.cycles
+    assert outer.started_jobs == [0] and inner.started_jobs == [1]
+    for rec in (outer, inner):
+        assert set(rec.stages) == set(STAGES)
+        assert rec.host_reads >= _decision_reads(rec)
+    # each read is counted once; the cycle-less RUNJOBs fold into the
+    # entry that ran them, and the nested cycle's time lies in qrun
+    assert outer.host_reads + inner.host_reads == reads
+    # QUEUEJOB 0 + RUNJOB 0, and QUEUEJOB 1 + RUNJOB 1
+    assert outer.events == 2 and inner.events == 2
+    assert sum(inner.stages.values()) <= outer.stages["twin.qrun"]
+
+
+def test_a_raced_cycle_counts_the_reads_of_its_rungs():
+    from repro.core.fan import FanSpec
+    from repro.core.race import RaceSpec
+    counts = {}
+    for mode, kw in (("plain", {}),
+                     ("raced", {"race": RaceSpec(fan=FanSpec(n=8), f0=2)})):
+        s = ScriptedTwin()
+        s.twin = SchedTwin(bus=s.bus, qrun=s.qrun, total_nodes=NODES,
+                           max_jobs=MAX_JOBS,
+                           free_nodes_probe=lambda: s.free, **kw)
+        s.publish(_queue(0, 0.0, nodes=10))
+        _, rec = s.pump()
+        counts[mode] = rec
+    plain, raced = counts["plain"], counts["raced"]
+    assert raced.race_rungs >= 2
+    # each rung reads at least its members' costs, deadlocks, and the
+    # rung's cost, CI and width
+    assert raced.host_reads >= plain.host_reads + 5 * raced.race_rungs
 
 
 def test_a_cycle_counts_the_compiles_of_its_pump():
@@ -176,7 +226,7 @@ def test_cycle_latency_stats_by_nearest_rank():
         tm.record(CycleRecord(time=float(i), wall_seconds=i * 1e-3,
                               policy="FCFS", costs={}, n_started=0,
                               started_jobs=[], stages=stages,
-                              host_reads=i % 4))
+                              host_reads=i % 4, events=i % 3))
     st = tm.cycle_latency_stats()
     assert st["n"] == 100
     assert st["p50_s"] == pytest.approx(0.050)       # ws[n//2] read 0.051
@@ -184,6 +234,7 @@ def test_cycle_latency_stats_by_nearest_rank():
     assert st["mean_s"] == pytest.approx(0.0505)
     assert st["max_s"] == pytest.approx(0.100)
     assert st["host_reads"] == pytest.approx(1.5)
+    assert st["events"] == pytest.approx(1.0)
     assert st["stage_p50_s"]["twin.ingest"] == pytest.approx(0.0050)
     assert st["stage_p50_s"]["twin.qrun"] == 0.0
     empty = Telemetry().cycle_latency_stats()
@@ -214,6 +265,27 @@ def test_batched_replay_names_its_device_stages():
     scen = stack_scenarios(traces, 16, max_jobs=16)
     lowered = eng.lower_replay_grid(scen, parse_pool("extended").spec)
     assert _scopes(lowered) == set(SCOPES)
+
+
+def test_twin_driver_samples_every_cycle_or_raises(monkeypatch):
+    import contextlib
+    from bench.record import Record
+    from bench.tests.helpers import tiny_cell
+    cell = tiny_cell("paper32.twin")
+    d = cell.driver(cell, 2**31 + 5)
+    record = Record()
+    d.step(record, traced=False)
+    n = len(record.samples["traced"])
+    assert n == len(d.episodes[-1][1]) > 0
+    for key in STAGES + ("pump_s", "decide_s", "host_reads", "events",
+                         "compiles"):
+        assert len(record.samples[key]) == n, key
+    # a cycle the program leaves unstamped would shift every later
+    # sample against ``traced``: the driver refuses it
+    monkeypatch.setattr(telemetry.StageMeter, "pump",
+                        lambda self, cycles: contextlib.nullcontext())
+    with pytest.raises(KeyError, match="twin.read"):
+        d.step(Record(), traced=False)
 
 
 def test_traced_twin_cell_reports_the_stage_metrics():
