@@ -6,8 +6,6 @@ Every pump that records a decision cycle is timed on the host clock
 from the emulator's call to its return."""
 from __future__ import annotations
 
-import dataclasses
-import statistics
 import time
 from typing import Dict, List, Optional
 
@@ -16,17 +14,12 @@ import numpy as np
 from bench import check, gen
 from bench import reference as ref
 from bench.readings import quantile
-from bench.readings import untraced
 from bench.record import Record, span
 
 
 class Driver:
     pass_k = pass_j = 0
     spans = ("emulator", "pump")
-    # the program's own stage spans inside ``pump`` (``CycleRecord.stages``)
-    stages = ("twin.read", "twin.ingest", "twin.resync", "twin.decide",
-              "twin.fetch", "twin.unpack", "twin.qrun")
-    spans += stages
 
     def __init__(self, cell, seed: int):
         from repro.core.engine import DrainEngine
@@ -41,11 +34,6 @@ class Driver:
         self.traces = gen.scenario_traces(cell.family, self.config,
                                           int(self.traffic["episodes"]), seed)
         self._steps = 0
-        # whether the program stamps each cycle with its pump's stages
-        # and counts: then every recorded cycle carries them
-        from repro.core.telemetry import CycleRecord
-        self.staged = "stages" in {
-            f.name for f in dataclasses.fields(CycleRecord)}
         self.episodes: List[tuple] = []      # (trace, [check.Cycle])
         self.failed = 0
 
@@ -81,15 +69,6 @@ class Driver:
                 record.add("pump_s", dt)
                 record.add("decide_s", cycles[-1].wall_seconds)
                 record.add("traced", float(traced))
-                # the pump's stages and counts, where the program
-                # records them: one sample each per cycle
-                if self.staged:
-                    rec = cycles[-1]
-                    for name in self.stages:
-                        record.add(name, rec.stages[name])
-                    record.add("host_reads", rec.host_reads)
-                    record.add("events", rec.events)
-                    record.add("compiles", rec.compiles)
 
         with span("emulator", traced):
             em.run(on_event=pump, on_quiesce=twin.flush)
@@ -153,23 +132,4 @@ class Driver:
                          f"{1e3 * quantile(traced, 0.5):.3f} ms against "
                          f"{1e3 * quantile(window, 0.5):.3f} ms in the "
                          f"window")
-        # the window's median stage times, and what of the pump they
-        # leave unattributed (pump less the sum of stages, per cycle)
-        times = [untraced(record, name) for name in self.stages]
-        if all(times) and window:
-            lines.append("stage p50 ms: " + ", ".join(
-                f"{name} {1e3 * quantile(xs, 0.5):.4f}"
-                for name, xs in zip(self.stages, times)))
-            rest = [p - sum(parts) for p, parts in zip(window, zip(*times))]
-            reads = statistics.mean(untraced(record, "host_reads"))
-            events = statistics.mean(untraced(record, "events"))
-            lines.append(f"unattributed p50 {1e3 * quantile(rest, 0.5):.4f}"
-                         f" ms of pump p50 "
-                         f"{1e3 * quantile(window, 0.5):.4f} ms; "
-                         f"host reads per cycle {reads:.3f}, events per "
-                         f"cycle {events:.3f}")
-            every = sum(record.samples["compiles"])
-            calm = sum(untraced(record, "compiles"))
-            lines.append(f"program compiles in the pumps: window "
-                         f"{calm:.0f}, traced stretch {every - calm:.0f}")
         return lines
