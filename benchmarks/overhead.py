@@ -208,6 +208,7 @@ def main(seed: int = 0, pool_sizes: Sequence[int] = POOL_SIZES,
             f"p50_s={stats['p50_s']:.4f},p95_s={stats['p95_s']:.4f},"
             f"max_s={stats['max_s']:.4f},n={stats['n']},"
             f"host_reads={stats['host_reads']:.1f},"
+            f"uploads={stats['uploads']:.1f},"
             f"events={stats['events']:.2f},paper=a few seconds")
         extra["live_cycle"] = stats
 

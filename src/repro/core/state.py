@@ -1,10 +1,11 @@
-"""Struct-of-array job/cluster state — the twin's JAX-side mirror.
+"""Struct-of-array job/cluster state — the twin's mirror and every fork's.
 
 Fixed-capacity arrays (``max_jobs`` slots) so every simulation has a
 static shape: slot ``i`` is job ``i`` for the lifetime of a trace.  The
-same structures are used by (a) the twin's mirror of the physical system,
-(b) each what-if simulation fork, and (c) the cluster emulator's
-ground-truth state (which additionally knows true runtimes).
+same structures are used by (a) the twin's mirror of the physical system
+(numpy leaves on the host, uploaded once per decision), (b) each what-if
+simulation fork, and (c) the cluster emulator's ground-truth state
+(which additionally knows true runtimes).
 """
 from __future__ import annotations
 
@@ -12,6 +13,7 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # Job lifecycle states.
 INVALID = 0   # empty slot
@@ -54,24 +56,29 @@ class SimState(NamedTuple):
     now: jax.Array          # f32 scalar
 
 
-def empty_jobs(max_jobs: int) -> JobTable:
-    f = jnp.full((max_jobs,), TIME_NONE, dtype=jnp.float32)
+def empty_jobs(max_jobs: int, xp=jnp) -> JobTable:
+    def times():
+        return xp.full((max_jobs,), TIME_NONE, dtype=np.float32)
+
     return JobTable(
-        submit_t=f,
-        nodes=jnp.zeros((max_jobs,), dtype=jnp.int32),
-        est_runtime=jnp.zeros((max_jobs,), dtype=jnp.float32),
-        start_t=f,
-        end_t=f,
-        state=jnp.zeros((max_jobs,), dtype=jnp.int32),
+        submit_t=times(),
+        nodes=xp.zeros((max_jobs,), dtype=np.int32),
+        est_runtime=xp.zeros((max_jobs,), dtype=np.float32),
+        start_t=times(),
+        end_t=times(),
+        state=xp.zeros((max_jobs,), dtype=np.int32),
     )
 
 
-def empty_state(max_jobs: int, total_nodes: int) -> SimState:
+def empty_state(max_jobs: int, total_nodes: int, xp=jnp) -> SimState:
+    """An empty cluster: on the device, or with ``xp=np`` on the host
+    (the twin's mirror, ``core/sync.py``) with the same dtypes and
+    shapes."""
     return SimState(
-        jobs=empty_jobs(max_jobs),
-        free_nodes=jnp.asarray(total_nodes, dtype=jnp.int32),
-        total_nodes=jnp.asarray(total_nodes, dtype=jnp.int32),
-        now=jnp.asarray(0.0, dtype=jnp.float32),
+        jobs=empty_jobs(max_jobs, xp),
+        free_nodes=xp.asarray(total_nodes, dtype=np.int32),
+        total_nodes=xp.asarray(total_nodes, dtype=np.int32),
+        now=xp.asarray(0.0, dtype=np.float32),
     )
 
 

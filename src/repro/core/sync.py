@@ -1,6 +1,16 @@
 """Synchronization stage (§3.2) — keep the mirror consistent with the
 physical scheduler.
 
+The mirror is host-resident: a ``SimState`` whose leaves are numpy
+arrays (``state.empty_state(..., xp=np)``) with the device state's
+dtypes and shapes.  Every handler here is a plain numpy update that
+returns a new state and never mutates its input; the twin puts the
+mirror on the device in one upload per decision (``core/twin.py``), so
+ingesting an event costs no device dispatch and no device read.  The
+float32 arithmetic is the device updates' own (``core/state.py``'s
+``add_job``/``start_job``/... stay the jit-safe reference), value for
+value (tests/test_sync.py).
+
 Event handling mirrors the paper's block ④:
   * RUNJOB  -> insert predicted end event (start + user estimate) and
                exit immediately (run events imply no new scheduling
@@ -35,13 +45,75 @@ from __future__ import annotations
 
 from typing import Tuple
 
-import jax.numpy as jnp
+import numpy as np
 
 from repro.core.events import Event, EventKind
 from repro.core.state import (DONE, INVALID, QUEUED, RUNNING, TIME_NONE,
-                              JobTable, SimState, add_job, end_job,
-                              requeue_job, resize_cluster, start_job)
-from repro.core.telemetry import fetch
+                              JobTable, SimState)
+
+
+def _set(jobs: JobTable, job_id: int, **values) -> JobTable:
+    """``jobs`` with slot ``job_id`` of each named column set: copies of
+    the columns it writes, so the input table is never mutated."""
+    cols = {}
+    for name, v in values.items():
+        col = np.array(getattr(jobs, name))
+        col[job_id] = v
+        cols[name] = col
+    return jobs._replace(**cols)
+
+
+def _later(now, t: np.float32) -> np.float32:
+    return np.float32(np.maximum(now, t))
+
+
+def _queue(state: SimState, ev: Event) -> SimState:
+    t = np.float32(ev.time)
+    jobs = _set(state.jobs, ev.job_id, submit_t=t,
+                nodes=np.int32(int(ev.payload["nodes"])),
+                est_runtime=np.float32(ev.payload["est_runtime"]),
+                start_t=TIME_NONE, end_t=TIME_NONE, state=QUEUED)
+    return state._replace(jobs=jobs, now=_later(state.now, t))
+
+
+def _start(state: SimState, job_id: int, t: np.float32) -> SimState:
+    """Predicted end = start + user estimate (§3.2), in float32."""
+    jobs = state.jobs
+    jobs = _set(jobs, job_id, start_t=t,
+                end_t=t + np.float32(jobs.est_runtime[job_id]),
+                state=RUNNING)
+    return state._replace(
+        jobs=jobs,
+        free_nodes=np.int32(state.free_nodes - jobs.nodes[job_id]),
+        now=_later(state.now, t))
+
+
+def _end(state: SimState, job_id: int, t: np.float32) -> SimState:
+    """④A: the actual end replaces the predicted one, early or late."""
+    jobs = _set(state.jobs, job_id, end_t=t, state=DONE)
+    return state._replace(
+        jobs=jobs,
+        free_nodes=np.int32(state.free_nodes + jobs.nodes[job_id]),
+        now=_later(state.now, t))
+
+
+def _resize(state: SimState, delta: int) -> SimState:
+    return state._replace(
+        total_nodes=np.int32(state.total_nodes + delta),
+        free_nodes=np.int32(state.free_nodes + delta))
+
+
+def _requeue(state: SimState, job_id: int, t: np.float32) -> SimState:
+    """A node failure kills a running job: its nodes come back and it
+    returns to the queue; a job in any other state keeps it."""
+    jobs = state.jobs
+    was_running = int(jobs.state[job_id]) == RUNNING
+    freed = int(jobs.nodes[job_id]) if was_running else 0
+    jobs = _set(jobs, job_id, start_t=TIME_NONE, end_t=TIME_NONE,
+                state=QUEUED if was_running else jobs.state[job_id])
+    return state._replace(
+        jobs=jobs, free_nodes=np.int32(state.free_nodes + freed),
+        now=_later(state.now, t))
 
 
 def apply_event(state: SimState, ev: Event,
@@ -50,38 +122,27 @@ def apply_event(state: SimState, ev: Event,
     if idempotent and ev.kind in (EventKind.QUEUEJOB, EventKind.RUNJOB,
                                   EventKind.JOBOBIT):
         return _apply_job_event_idempotent(state, ev)
+    t = np.float32(ev.time)
     if ev.kind == EventKind.QUEUEJOB:
-        state = add_job(
-            state, ev.job_id,
-            submit_t=jnp.float32(ev.time),
-            nodes=jnp.int32(int(ev.payload["nodes"])),
-            est_runtime=jnp.float32(ev.payload["est_runtime"]),
-        )
-        return state, True
+        return _queue(state, ev), True
 
     if ev.kind == EventKind.RUNJOB:
         # Predicted end event enters the virtual horizon; no cycle (§3.2).
-        state = start_job(state, ev.job_id, jnp.float32(ev.time))
-        return state, False
+        return _start(state, ev.job_id, t), False
 
     if ev.kind == EventKind.JOBOBIT:
-        # ④A pull-back (early finish) or push-forward (cleanup delay):
-        # the predicted end is replaced with the actual one.
-        state = end_job(state, ev.job_id, jnp.float32(ev.time))
-        return state, True
+        return _end(state, ev.job_id, t), True
 
     if ev.kind == EventKind.NODEFAIL:
-        state = resize_cluster(state, -jnp.int32(int(ev.payload["nodes"])))
+        state = _resize(state, -int(ev.payload["nodes"]))
         victim = int(ev.payload.get("victim_job", -1))
         if victim >= 0:
-            state = requeue_job(state, victim, jnp.float32(ev.time))
-        state = state._replace(now=jnp.maximum(state.now, jnp.float32(ev.time)))
-        return state, True
+            state = _requeue(state, victim, t)
+        return state._replace(now=_later(state.now, t)), True
 
     if ev.kind == EventKind.NODEUP:
-        state = resize_cluster(state, jnp.int32(int(ev.payload["nodes"])))
-        state = state._replace(now=jnp.maximum(state.now, jnp.float32(ev.time)))
-        return state, True
+        state = _resize(state, int(ev.payload["nodes"]))
+        return state._replace(now=_later(state.now, t)), True
 
     raise ValueError(f"unknown event kind: {ev.kind}")
 
@@ -91,52 +152,39 @@ def _apply_job_event_idempotent(state: SimState,
     """State-guarded job-event handlers: each transition fires only from
     the lifecycle state it is valid from, so re-delivery is a no-op and
     a late straggler can only FILL IN what it knows (never re-run a
-    resource effect).  One host-side state read per event — the same
-    host-driven granularity as the normal path."""
-    cur = int(fetch(state.jobs.state[ev.job_id]))
+    resource effect)."""
+    cur = int(state.jobs.state[ev.job_id])
+    t = np.float32(ev.time)
 
     if ev.kind == EventKind.QUEUEJOB:
         if cur != INVALID:          # already known (duplicate / late)
             return state, False
-        state = add_job(
-            state, ev.job_id,
-            submit_t=jnp.float32(ev.time),
-            nodes=jnp.int32(int(ev.payload["nodes"])),
-            est_runtime=jnp.float32(ev.payload["est_runtime"]),
-        )
-        return state, True
+        return _queue(state, ev), True
 
     if ev.kind == EventKind.RUNJOB:
         if cur == QUEUED:           # the one valid transition
-            return start_job(state, ev.job_id, jnp.float32(ev.time)), False
+            return _start(state, ev.job_id, t), False
         if cur == DONE:             # arrived after its JOBOBIT: backfill
-            jobs = state.jobs      # start_t only — no resource effect
-            jobs = jobs._replace(
-                start_t=jobs.start_t.at[ev.job_id].set(
-                    jnp.float32(ev.time)))
-            return state._replace(jobs=jobs), False
+            # start_t only — no resource effect
+            return state._replace(
+                jobs=_set(state.jobs, ev.job_id, start_t=t)), False
         return state, False         # RUNNING duplicate / unknown job
 
     # EventKind.JOBOBIT
     if cur == RUNNING:              # the one valid transition
-        return end_job(state, ev.job_id, jnp.float32(ev.time)), True
+        return _end(state, ev.job_id, t), True
     if cur == QUEUED:
         # RUNJOB never arrived: the job is over, but this mirror never
         # charged its nodes — mark DONE without freeing anything.
-        jobs = state.jobs
-        jobs = jobs._replace(
-            end_t=jobs.end_t.at[ev.job_id].set(jnp.float32(ev.time)),
-            state=jobs.state.at[ev.job_id].set(DONE),
-        )
         return state._replace(
-            jobs=jobs,
-            now=jnp.maximum(state.now, jnp.float32(ev.time))), True
+            jobs=_set(state.jobs, ev.job_id, end_t=t, state=DONE),
+            now=_later(state.now, t)), True
     return state, False              # DONE duplicate / unknown job
 
 
 def resync_free_nodes(state: SimState, authoritative_free: int) -> SimState:
     """Overwrite mirror free-node count from the physical system."""
-    return state._replace(free_nodes=jnp.int32(authoritative_free))
+    return state._replace(free_nodes=np.int32(authoritative_free))
 
 
 def resync_jobs(state: SimState, view: dict) -> SimState:
@@ -152,25 +200,25 @@ def resync_jobs(state: SimState, view: dict) -> SimState:
     ``free_nodes`` scalars.  The §3.2 estimate asymmetry is preserved —
     running jobs get predicted ends ``start + estimate`` exactly as a
     replayed RUNJOB would; only DONE jobs carry their actual end."""
-    submit = jnp.asarray(view["submit_t"], jnp.float32)
-    nodes = jnp.asarray(view["nodes"], jnp.int32)
-    est = jnp.asarray(view["est_runtime"], jnp.float32)
-    st = jnp.asarray(view["state"], jnp.int32)
-    start = jnp.asarray(view["start_t"], jnp.float32)
-    end = jnp.asarray(view["end_t"], jnp.float32)
-    pred_end = jnp.where(st == RUNNING, start + est, end)
-    none = jnp.float32(TIME_NONE)
+    submit = np.asarray(view["submit_t"], np.float32)
+    nodes = np.asarray(view["nodes"], np.int32)
+    est = np.asarray(view["est_runtime"], np.float32)
+    st = np.asarray(view["state"], np.int32)
+    start = np.asarray(view["start_t"], np.float32)
+    end = np.asarray(view["end_t"], np.float32)
+    known = st != INVALID
+    none = np.float32(TIME_NONE)
     jobs = JobTable(
-        submit_t=jnp.where(st != INVALID, submit, none),
-        nodes=jnp.where(st != INVALID, nodes, 0),
-        est_runtime=jnp.where(st != INVALID, est, 0.0),
-        start_t=jnp.where(st == QUEUED, none,
-                          jnp.where(st != INVALID, start, none)),
-        end_t=jnp.where((st == RUNNING) | (st == DONE), pred_end, none),
+        submit_t=np.where(known, submit, none),
+        nodes=np.where(known, nodes, np.int32(0)),
+        est_runtime=np.where(known, est, np.float32(0.0)),
+        start_t=np.where(known & (st != QUEUED), start, none),
+        end_t=np.where(st == RUNNING, start + est,
+                       np.where(st == DONE, end, none)),
         state=st,
     )
     return state._replace(
         jobs=jobs,
-        free_nodes=jnp.int32(int(view["free_nodes"])),
-        total_nodes=jnp.int32(int(view["total_nodes"])),
+        free_nodes=np.int32(int(view["free_nodes"])),
+        total_nodes=np.int32(int(view["total_nodes"])),
     )

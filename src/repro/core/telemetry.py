@@ -6,9 +6,9 @@ Each pump of ``SchedTwin`` runs its stages (``STAGES``) under
 the pump's stage totals, and a ``jax.profiler.TraceAnnotation`` of the
 same name, so a profile shows the stage on the host plane on the device
 ops' clock.  Beside the stage totals the meter counts the pump's
-events, its blocking device-to-host reads (``fetch``) and its backend
-compiles; a pump that records a decision cycle stamps all of them on
-that ``CycleRecord``.
+events, its blocking device-to-host reads (``fetch``), its host-to-device
+uploads of the mirror (``upload``) and its backend compiles; a pump that
+records a decision cycle stamps all of them on that ``CycleRecord``.
 """
 from __future__ import annotations
 
@@ -22,8 +22,8 @@ import jax
 import numpy as np
 
 #: The twin's host stages, in the order a pump runs them.
-STAGES = ("twin.read", "twin.ingest", "twin.resync", "twin.decide",
-          "twin.fetch", "twin.unpack", "twin.qrun")
+STAGES = ("twin.read", "twin.ingest", "twin.resync", "twin.upload",
+          "twin.decide", "twin.fetch", "twin.unpack", "twin.qrun")
 
 #: JAX's monitoring event for one backend compile.
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
@@ -32,8 +32,17 @@ BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
 class _ProcessCounts:
     """Monotone counts over the whole process; a meter reads deltas."""
     host_reads = 0
+    uploads = 0
     compiles = 0
     listening = False
+
+
+#: The counts a ``StageMeter`` stamps on its pump's ``CycleRecord``.
+_COUNTS = ("host_reads", "uploads", "compiles")
+
+
+def _counts() -> Dict[str, int]:
+    return {name: getattr(_ProcessCounts, name) for name in _COUNTS}
 
 
 def _on_duration(event: str, duration: float, **kwargs) -> None:
@@ -54,6 +63,13 @@ def fetch(x) -> np.ndarray:
     if isinstance(x, jax.Array):
         _ProcessCounts.host_reads += 1
     return np.asarray(x)
+
+
+def upload(tree):
+    """``tree`` (host arrays) on the device in one ``jax.device_put``,
+    counted for the pump's ``uploads``."""
+    _ProcessCounts.uploads += 1
+    return jax.device_put(tree)
 
 
 def _nearest_rank(values: Sequence[float], q: float) -> float:
@@ -109,12 +125,13 @@ class CycleRecord:
     deadline_miss: bool = False
     # the pump that ran this cycle (``StageMeter``): host seconds per
     # stage (every name in ``STAGES``, 0.0 where it did not run), its
-    # blocking device-to-host reads, the events it consumed and the
-    # backend compiles inside it.
+    # blocking device-to-host reads, its uploads of the mirror to the
+    # device, the events it consumed and the backend compiles inside it.
     stages: Dict[str, float] = dataclasses.field(default_factory=dict)
     host_reads: int = 0
     events: int = 0
     compiles: int = 0
+    uploads: int = 0
 
 
 @dataclasses.dataclass
@@ -269,12 +286,13 @@ class Telemetry:
     # ---- overhead (paper: "a few seconds per scheduling cycle") -------
     def cycle_latency_stats(self) -> Dict[str, object]:
         """The decision span (``wall_seconds``) by nearest rank, the
-        median of each host stage, and the mean blocking reads and
-        events per cycle."""
+        median of each host stage, and the mean blocking reads, uploads
+        and events per cycle."""
         n = len(self.cycles)
         if not n:
             return {"n": 0, "mean_s": 0.0, "p50_s": 0.0, "p95_s": 0.0,
-                    "max_s": 0.0, "host_reads": 0.0, "events": 0.0,
+                    "max_s": 0.0, "host_reads": 0.0, "uploads": 0.0,
+                    "events": 0.0,
                     "stage_p50_s": dict.fromkeys(STAGES, 0.0)}
         ws = [c.wall_seconds for c in self.cycles]
         return {
@@ -284,6 +302,7 @@ class Telemetry:
             "p95_s": _nearest_rank(ws, 0.95),
             "max_s": max(ws),
             "host_reads": sum(c.host_reads for c in self.cycles) / n,
+            "uploads": sum(c.uploads for c in self.cycles) / n,
             "events": sum(c.events for c in self.cycles) / n,
             "stage_p50_s": {
                 s: _nearest_rank([c.stages.get(s, 0.0) for c in self.cycles],
@@ -318,8 +337,7 @@ class StageMeter:
     def reset(self) -> None:
         self.stages = dict.fromkeys(STAGES, 0.0)
         self.events = 0
-        self._reads0 = _ProcessCounts.host_reads
-        self._compiles0 = _ProcessCounts.compiles
+        self._counts0 = _counts()
 
     @contextlib.contextmanager
     def span(self, name: str) -> Iterator[None]:
@@ -339,26 +357,25 @@ class StageMeter:
         fired by the enclosing pump's ``qrun``) is metered on its own:
         a cycle it records gets its own stages and counts, which leave
         the enclosing pump's.  One that records no cycle adds its
-        events, reads and compiles to the enclosing pump.  Either way
+        events, reads, uploads and compiles to the enclosing pump.  Either way
         its time is in the enclosing pump's ``twin.qrun``."""
-        enclosing = (self.stages, self.events, self._reads0,
-                     self._compiles0)
+        enclosing = (self.stages, self.events, self._counts0)
         self.reset()
         n0 = len(cycles)
         try:
             yield
         finally:
             stages, events = self.stages, self.events
-            reads = _ProcessCounts.host_reads - self._reads0
-            compiles = _ProcessCounts.compiles - self._compiles0
-            (self.stages, self.events, self._reads0,
-             self._compiles0) = enclosing
+            end = _counts()
+            counts = {k: end[k] - self._counts0[k] for k in _COUNTS}
+            self.stages, self.events, counts0 = enclosing
             if len(cycles) > n0:
                 rec = cycles[n0]
                 rec.stages = dict(stages)
-                rec.host_reads, rec.compiles = reads, compiles
                 rec.events = events
-                self._reads0 += reads
-                self._compiles0 += compiles
+                for k, v in counts.items():
+                    setattr(rec, k, v)
+                self._counts0 = {k: counts0[k] + counts[k] for k in _COUNTS}
             else:
+                self._counts0 = counts0
                 self.events += events

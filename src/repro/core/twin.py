@@ -191,7 +191,9 @@ class SchedTwin:
         self.pool = normalize_pool(pool)
         self.objective = resolve_goal(objective, weights)
         self.max_jobs = max_jobs
-        self.state: SimState = empty_state(max_jobs, total_nodes)
+        # the mirror of record, on the host (``core/sync.py``): ingest
+        # never touches the device; each decision uploads it once.
+        self.state: SimState = empty_state(max_jobs, total_nodes, xp=np)
         self.telemetry = telemetry.Telemetry()
         self._meter = telemetry.StageMeter()
         self.free_nodes_probe = free_nodes_probe
@@ -252,7 +254,7 @@ class SchedTwin:
         needs_cycle = False
         lost_any = False
         with span("twin.ingest"):
-            t_latest = float(fetch(self.state.now))
+            t_latest = float(self.state.now)
             for ev in events:
                 applied, cycle, gap, lost = self._ingest(ev)
                 needs_cycle |= cycle
@@ -268,7 +270,7 @@ class SchedTwin:
             with span("twin.resync"):
                 self.state = sync.resync_jobs(self.state, self.jobs_probe())
                 ing.resyncs += 1
-                t_latest = max(t_latest, float(fetch(self.state.now)))
+                t_latest = max(t_latest, float(self.state.now))
         if needs_cycle:
             self._decision_cycle(t_latest)
         return len(events)
@@ -288,7 +290,7 @@ class SchedTwin:
                 self.telemetry.ingest.resyncs += 1
         if needs_cycle or gap or lost:
             self._decision_cycle(float(ev.time) if applied
-                                 else float(fetch(self.state.now)))
+                                 else float(self.state.now))
 
     def _ingest(self, ev: Event) -> Tuple[bool, bool, bool, bool]:
         """Sanitize + apply ONE event.  Returns ``(applied, needs_cycle,
@@ -334,27 +336,27 @@ class SchedTwin:
         ``FanSpec.from_history`` fits its lognormal σ to these pairs."""
         if ev.kind != EventKind.JOBOBIT or ev.job_id < 0:
             return
-        start = float(fetch(self.state.jobs.start_t[ev.job_id]))
+        start = float(self.state.jobs.start_t[ev.job_id])
         if start < 0.0:  # never started in the mirror — no ground truth
             return
-        est = float(fetch(self.state.jobs.est_runtime[ev.job_id]))
+        est = float(self.state.jobs.est_runtime[ev.job_id])
         self.telemetry.record_residual(est, ev.time - start)
 
     # ------------------------------------------------------------------
-    def _decide_at_level(self, level: int):
-        """One decision at the given ladder level (DESIGN.md §12).
-        Returns ``(decision, race_out, names, source)`` where ``names``
-        label the decision's forks and ``source`` ∈ {'pool',
-        'fallback'} says which pool the winning index refers to (the
-        incumbent bookkeeping).  Level 0 is the configured decision
-        mode verbatim; a mode with nothing to shrink falls through
-        level 1 to the static pool."""
+    def _decide_at_level(self, level: int, state: SimState):
+        """One decision on ``state`` (the mirror, on the device) at the
+        given ladder level (DESIGN.md §12).  Returns ``(decision,
+        race_out, names, source)`` where ``names`` label the decision's
+        forks and ``source`` ∈ {'pool', 'fallback'} says which pool the
+        winning index refers to (the incumbent bookkeeping).  Level 0
+        is the configured decision mode verbatim; a mode with nothing
+        to shrink falls through level 1 to the static pool."""
         if level >= 3 and self._incumbent is not None:
             # hold the incumbent: one k=1 schedule pass, no comparison
             src, idx = self._incumbent
             base = self.pool if src == "pool" else self.fallback_pool
             pool1 = _fork_pool(base, idx)
-            decision = self.engine.decide(self.state, pool1.spec,
+            decision = self.engine.decide(state, pool1.spec,
                                           self.objective)
             return decision, None, pool1.names, self._incumbent
         if level >= 2 or (level == 1 and self.race is None
@@ -362,7 +364,7 @@ class SchedTwin:
             # static fallback pool, single nominal future — the paper's
             # own baseline twin (also level 3 before any incumbent)
             decision = self.engine.decide(
-                self.state, self.fallback_pool.spec, self.objective)
+                state, self.fallback_pool.spec, self.objective)
             return (decision, None, self.fallback_pool.names,
                     ("fallback", None))
         if level == 1:
@@ -375,14 +377,14 @@ class SchedTwin:
                       if getattr(r, "budget_ms", None) else r.budget_ms)
                 shrunk = dataclasses.replace(r, fan=fan1, budget_ms=bm)
                 decision, race_out = self.engine.decide_race(
-                    self.state, self.pool.spec, shrunk,
+                    state, self.pool.spec, shrunk,
                     objective=self.objective)
                 return decision, race_out, self.pool.names, ("pool", None)
             if self.fan is not None:
                 fan1 = dataclasses.replace(
                     self.fan, n=max(1, int(np.ceil(self.fan.n * shrink))))
                 decision = self.engine.decide_fan(
-                    self.state, self.pool.spec, fan1,
+                    state, self.pool.spec, fan1,
                     objective=self.objective)
                 return decision, None, self.pool.names, ("pool", None)
             # ensemble: shrink member count (key consumption below is
@@ -390,28 +392,28 @@ class SchedTwin:
             self._key, sub = jax.random.split(self._key)
             n1 = max(2, int(np.ceil(self.ensemble * shrink)))
             decision = self.engine.decide_ensemble(
-                self.state, self.pool.spec, sub, n_ens=n1,
+                state, self.pool.spec, sub, n_ens=n1,
                 noise=self.ensemble_noise, objective=self.objective)
             return decision, None, self.pool.names, ("pool", None)
         # level 0 — the configured decision mode
         if self.race is not None:
             decision, race_out = self.engine.decide_race(
-                self.state, self.pool.spec, self.race,
+                state, self.pool.spec, self.race,
                 objective=self.objective)
             return decision, race_out, self.pool.names, ("pool", None)
         if self.fan is not None:
             decision = self.engine.decide_fan(
-                self.state, self.pool.spec, self.fan,
+                state, self.pool.spec, self.fan,
                 objective=self.objective)
             return decision, None, self.pool.names, ("pool", None)
         if self.ensemble > 1:
             self._key, sub = jax.random.split(self._key)
             decision = self.engine.decide_ensemble(
-                self.state, self.pool.spec, sub,
+                state, self.pool.spec, sub,
                 n_ens=self.ensemble, noise=self.ensemble_noise,
                 objective=self.objective)
             return decision, None, self.pool.names, ("pool", None)
-        decision = self.engine.decide(self.state, self.pool.spec,
+        decision = self.engine.decide(state, self.pool.spec,
                                       self.objective)
         return decision, None, self.pool.names, ("pool", None)
 
@@ -424,11 +426,14 @@ class SchedTwin:
                 self.state = sync.resync_free_nodes(
                     self.state, self.free_nodes_probe())
 
+        with span("twin.upload"):
+            state = telemetry.upload(self.state)
+
         level = self.guard.plan() if self.guard is not None else 0
         with telemetry.StopWatch(self._clock) as sw:
             with span("twin.decide"):
                 decision, race_out, names, source = \
-                    self._decide_at_level(level)
+                    self._decide_at_level(level, state)
             with span("twin.fetch"):
                 run_mask = fetch(decision.run_mask)  # blocks for timing
         with span("twin.unpack"):
@@ -514,8 +519,8 @@ class SchedTwin:
             if self.jobs_probe is not None:
                 self.state = sync.resync_jobs(self.state, self.jobs_probe())
                 ing.resyncs += 1
-            queued = bool((fetch(self.state.jobs.state) == QUEUED).any())
-            now = float(fetch(self.state.now)) if queued else 0.0
+            queued = bool((self.state.jobs.state == QUEUED).any())
+            now = float(self.state.now) if queued else 0.0
         if queued:
             self._decision_cycle(now)
             return True
@@ -525,7 +530,7 @@ class SchedTwin:
     def recover(self) -> None:
         """Rebuild the mirror from a full bus replay (twin restart)."""
         self.state = empty_state(self.state.jobs.capacity,
-                                 int(fetch(self.state.total_nodes)))
+                                 int(self.state.total_nodes), xp=np)
         for ev in self.bus.replay():
             self.state, _ = sync.apply_event(self.state, ev)
 
@@ -582,9 +587,8 @@ class SchedTwin:
                 f"no checkpoint to restore under {manager.root!r}")
         target = {"state": self.state, "key": self._key}
         tree, extra = manager.restore(step, target)
-        tree = jax.tree.map(jnp.asarray, tree)  # np -> jax (.at[] needed)
-        self.state = tree["state"]
-        self._key = tree["key"]
+        self.state = jax.tree.map(np.asarray, tree["state"])
+        self._key = jnp.asarray(tree["key"])
         self.bus.restore_offsets(
             {self.CONSUMER: int(extra["consumer_offset"])})
         self._tracker = SeqTracker.from_dict(extra["tracker"])

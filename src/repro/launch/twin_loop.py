@@ -646,8 +646,8 @@ def main() -> None:
     print("cycle stages, p50 ms: " + ", ".join(
         f"{name.split('.')[-1]} {s * 1e3:.3f}"
         for name, s in lat["stage_p50_s"].items())
-        + f"; host reads {lat['host_reads']:.1f}, events "
-        f"{lat['events']:.2f} per cycle")
+        + f"; host reads {lat['host_reads']:.1f}, uploads "
+        f"{lat['uploads']:.1f}, events {lat['events']:.2f} per cycle")
     res = twin.telemetry.resilience_stats()
     print(f"resilience: miss_rate={res['miss_rate']:.3f} "
           f"(misses={res['deadline_misses']}/{res['cycles']}, "

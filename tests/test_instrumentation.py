@@ -72,13 +72,16 @@ def _decision_reads(rec: CycleRecord) -> int:
     return 3 + len(next(iter(rec.term_costs.values())))
 
 
+#: A twin's first cycle reads its pool's families and θ once, for the
+#: engine's hoist plan.
+HOIST_PLAN_READS = 2
+
+
 def test_every_cycle_has_all_stages_and_exact_counts():
     s = ScriptedTwin()
-    # pump: (events, reads before the decision) by hand:
-    #   now 1, one mirror-state read per job event, and a JOBOBIT of a
-    #   started job 2 more (its start and estimate for the residual).
-    #   The twin's first cycle also reads its pool's families and θ
-    #   once, for the engine's hoist plan.
+    # pump: (events, reads before the decision) by hand: the mirror is
+    #   on the host, so ingest reads nothing from the device; only the
+    #   twin's first cycle reads, for its hoist plan.
     s.publish(_queue(0, 0.0, nodes=10, est=300.0))
     a = s.pump()                                  # QUEUEJOB 0: starts
     s.publish(_queue(1, 5.0, nodes=10, est=200.0))
@@ -90,8 +93,8 @@ def test_every_cycle_has_all_stages_and_exact_counts():
     s.publish(Event(EventKind.RUNJOB, 61.0, 3))   # unknown job: no cycle
     e = s.pump()
     # (events, reads before the decision, jobs started)
-    expected = {"a": (1, 1 + 1 + 2, 1), "b": (2, 1 + 2, 0),
-                "c": (1, 1 + 1 + 2, 1), "d": (2, 1 + 2, 1)}
+    expected = {"a": (1, HOIST_PLAN_READS, 1), "b": (2, 0, 0),
+                "c": (1, 0, 1), "d": (2, 0, 1)}
     for name, (dt, rec) in zip("abcd", (a, b, c, d)):
         events, reads, started = expected[name]
         assert rec is not None, name
@@ -100,6 +103,7 @@ def test_every_cycle_has_all_stages_and_exact_counts():
         assert sum(rec.stages.values()) <= dt, name
         assert rec.events == events, name
         assert rec.host_reads == reads + _decision_reads(rec), name
+        assert rec.uploads == 1, name
         assert rec.n_started == started, name
         assert (rec.stages["twin.qrun"] > 0.0) == bool(started), name
         for stage in STAGES[:-1]:
@@ -110,6 +114,27 @@ def test_every_cycle_has_all_stages_and_exact_counts():
     for rec in s.twin.telemetry.cycles:
         assert (rec.stages["twin.decide"] + rec.stages["twin.fetch"]
                 <= rec.wall_seconds)
+
+
+@pytest.mark.parametrize("n_events", [1, 8])
+def test_one_upload_per_decision_and_no_read_before_it(n_events):
+    s = ScriptedTwin()
+    for j in range(n_events):
+        s.publish(_queue(j, float(j), nodes=4))
+    before = telemetry._ProcessCounts.uploads
+    _, first = s.pump()
+    assert first.events == n_events and first.n_started >= 1
+    assert telemetry._ProcessCounts.uploads - before == 1
+    assert first.host_reads == HOIST_PLAN_READS + _decision_reads(first)
+    # the next pump carries the RUNJOBs the first one's qrun published
+    s.publish(_queue(n_events, 100.0, nodes=1))
+    _, second = s.pump()
+    assert second.events == first.n_started + 1
+    assert second.host_reads == _decision_reads(second)
+    for rec in (first, second):
+        assert rec.uploads == 1
+        assert rec.stages["twin.upload"] > 0.0
+    assert s.twin.telemetry.cycle_latency_stats()["uploads"] == 1.0
 
 
 def test_a_nested_entry_point_meters_its_own_cycle():
@@ -278,3 +303,4 @@ def test_twin_loop_prints_every_stage(monkeypatch, capsys):
     for name in STAGES:
         assert f" {name.split('.')[-1]} " in line, name
     assert "host reads" in line and "events" in line
+    assert "uploads 1.0" in line

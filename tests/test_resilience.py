@@ -26,6 +26,7 @@ contracts:
 import dataclasses
 import itertools
 
+import jax
 import numpy as np
 import pytest
 
@@ -230,15 +231,15 @@ def _lifecycle(j):
 
 
 def _apply_all(events, n_jobs, nodes=16):
-    state = empty_state(8, nodes)
+    state = empty_state(8, nodes, xp=np)
     for ev in events:
         state, _ = apply_event(state, ev, idempotent=True)
     return state
 
 
-def _check_invariant(order, dup_at, n_jobs=4):
-    """Interleave + re-deliver per ``order``/``dup_at``; final mirror
-    must match the clean in-order apply field-for-field."""
+def _delivery(order, dup_at, n_jobs=4):
+    """``(clean, shuffled)``: the lifecycles of ``n_jobs`` jobs in order,
+    and interleaved per ``order`` then re-delivered per ``dup_at``."""
     per_job = [_lifecycle(j) for j in range(n_jobs)]
     clean = [ev for life in per_job for ev in life]
     cursors = [0] * n_jobs
@@ -248,7 +249,13 @@ def _check_invariant(order, dup_at, n_jobs=4):
         cursors[j] += 1
     for i in sorted(dup_at):        # arbitrary re-delivery at the tail
         shuffled.append(shuffled[i])
+    return clean, shuffled
 
+
+def _check_invariant(order, dup_at, n_jobs=4):
+    """Interleave + re-deliver per ``order``/``dup_at``; final mirror
+    must match the clean in-order apply field-for-field."""
+    clean, shuffled = _delivery(order, dup_at, n_jobs)
     ref = _apply_all(clean, n_jobs)
     got = _apply_all(shuffled, n_jobs)
     for field in ("submit_t", "nodes", "est_runtime", "start_t",
@@ -291,7 +298,7 @@ def test_out_of_order_obit_never_double_frees():
     # JOBOBIT before its RUNJOB: the job ends without the mirror ever
     # charging its nodes — free_nodes must NOT exceed capacity
     q, r, o = _lifecycle(0)
-    state = empty_state(8, 16)
+    state = empty_state(8, 16, xp=np)
     for ev in (q, o, r):            # lifecycle order broken
         state, _ = apply_event(state, ev, idempotent=True)
     assert int(state.free_nodes) == 16
@@ -450,6 +457,12 @@ def test_snapshot_restore_bitwise_decision_parity(tmp_path, backend):
             step, app = fresh.restore(mgr)
             assert step == len(t.telemetry.cycles) and app is None
             assert len(fresh.telemetry.cycles) == step
+            # the restored mirror is on the host, bit for bit
+            for got, want in zip(jax.tree.leaves(fresh.state),
+                                 jax.tree.leaves(t.state)):
+                assert isinstance(got, np.ndarray)
+                assert got.dtype == want.dtype
+                np.testing.assert_array_equal(got, want)
             holder["twin"] = fresh
             holder["killed"] = True
 
