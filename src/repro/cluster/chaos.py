@@ -182,7 +182,7 @@ class ChaosBus:
         if mode == 0:
             return dataclasses.replace(ev, time=float("nan"))
         if mode == 1:
-            return dataclasses.replace(ev, job_id=10 ** 9)
+            return dataclasses.replace(ev, job_id=-(10 ** 9))
         if mode == 2:
             return dataclasses.replace(ev, kind=99)  # unknown kind
         return dataclasses.replace(

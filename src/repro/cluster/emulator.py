@@ -211,11 +211,13 @@ class ClusterEmulator:
     def jobs_view(self) -> Dict:
         """Authoritative full job-table probe — the ``qstat`` analogue
         of the ``free_nodes`` probe, consumed by ``sync.resync_jobs``
-        when the twin declares stream events LOST (DESIGN.md §12).
-        Exposes exactly what a scheduler CLI would: submit/start times,
-        node counts, USER estimates (never true runtimes — the §3.2
-        asymmetry), actual ends for finished jobs only, and the current
-        capacity/availability scalars."""
+        when the twin declares stream events LOST (DESIGN.md §12) and
+        when a twin attaches mid-stream (``SchedTwin.attach``).
+        Exposes exactly what a scheduler CLI would, row ``j`` for job
+        ``j``: submit/start times, node counts, USER estimates (never
+        true runtimes — the §3.2 asymmetry), actual ends for finished
+        jobs only, the current capacity/availability scalars, and the
+        scheduler's clock."""
         return {
             "submit_t": self.submit_t.copy(),
             "nodes": self.nodes.copy(),
@@ -225,6 +227,7 @@ class ClusterEmulator:
             "state": self.state.copy(),
             "free_nodes": int(self.free_nodes),
             "total_nodes": int(self.capacity_nodes),
+            "now": float(self.now),
         }
 
     # -- crash-safe co-simulation resume (DESIGN.md §12) ----------------
@@ -232,7 +235,8 @@ class ClusterEmulator:
         """JSON-serializable ground-truth snapshot: job arrays, event
         heap, stale-end guards, and capacity log — everything ``run``
         needs to continue mid-trace after a process restart (used by
-        ``twin_loop --snapshot-dir/--resume``)."""
+        ``twin_loop --snapshot-dir/--resume``), or to start a fresh
+        emulator at the instant ``advance`` stopped (``--attach``)."""
         return {
             "submit_t": self.submit_t.tolist(),
             "nodes": self.nodes.tolist(),
@@ -277,6 +281,65 @@ class ClusterEmulator:
         self._end_seq[:] = np.asarray(d["end_seq"], dtype=np.int64)
         self._capacity_log = [(float(t), int(c))
                               for t, c in d["capacity_log"]]
+
+    def start_at(self, report: RunReport, job: int) -> None:
+        """Put this fresh emulator (same trace, no failures) in the state
+        its static host loop holds right after the scheduling pass that
+        follows job ``job``'s submission, from that run's ``report``:
+        ``run(policy_id, fast=True)`` gives the host loop's times bit for
+        bit in one device replay, so a mid-stream snapshot costs one
+        replay and no per-event pass.  Jobs submitted by then are queued,
+        running or done by their times in ``report``; the heap holds the
+        arrivals still to come and the true ends of running jobs, whose
+        sequence numbers follow start order (by id within an instant).
+        ``snapshot_state`` or ``run``/``advance`` go on from there.
+
+        Refuses (``ValueError``) an instant that another event shares
+        (the next arrival, or a completion), where the report cannot say
+        on which side of the cut a start at that instant fell."""
+        if self.failures:
+            raise ValueError("start_at replays no failures")
+        n = len(self.trace)
+        sub = np.array([np.float32(j.submit_t) for j in self.trace],
+                       np.float64)
+        t = sub[job]
+        start = np.asarray(report.start_t, np.float64)
+        end = np.asarray(report.end_t, np.float64)
+        order = np.lexsort((np.arange(n), sub))        # arrival order
+        rank = np.empty(n, np.int64)
+        rank[order] = np.arange(n)
+        nxt = order[rank[job] + 1] if rank[job] + 1 < n else -1
+        if (nxt >= 0 and sub[nxt] == t) or np.any(end == t):
+            raise ValueError(f"another event shares job {job}'s submission "
+                             f"at t={t}")
+        submitted = rank <= rank[job]
+        started = submitted & (start >= 0) & (start <= t)
+        done = started & (end < t)
+        running = started & ~done
+        for j in np.flatnonzero(submitted):       # slot j is trace job j
+            spec = self.trace[j]
+            self.submit_t[j] = sub[j]
+            self.nodes[j] = spec.nodes
+            self.est[j] = np.float32(spec.est_runtime)
+            self.true_rt[j] = np.float32(spec.true_runtime)
+            self.state[j] = (DONE if done[j] else RUNNING if running[j]
+                             else QUEUED)
+            if started[j]:
+                self.start_t[j], self.end_t[j] = start[j], end[j]
+        by_start = np.flatnonzero(started)
+        by_start = by_start[np.argsort(start[by_start], kind="stable")]
+        self._heap = [(sub[j], int(j), _ARRIVAL, int(j))
+                      for j in np.flatnonzero(~submitted)]
+        for k, j in enumerate(by_start):
+            self._end_seq[j] = n + k
+            if running[j]:
+                self._heap.append((end[j], n + k, _END, int(j)))
+        heapq.heapify(self._heap)
+        self._seq = n + by_start.size
+        self.now = float(t)
+        self.free_nodes = self.capacity_nodes - int(
+            self.nodes[self.state == RUNNING].sum())
+        self.n_events = int(submitted.sum() + done.sum())
 
     def _static_schedule(self, policy) -> None:
         started = np.asarray(self.engine.schedule_pass_starts(
@@ -326,6 +389,18 @@ class ClusterEmulator:
         return self._stamp_objective(
             self._run(policy_id, on_event, fast, on_quiesce), objective)
 
+    def advance(self, until: Callable[[], bool], policy_id=None,
+                on_event: Optional[Callable[[], None]] = None) -> bool:
+        """The host event loop of ``run`` (static mode with
+        ``policy_id``, twin mode with ``on_event``), stopped after the
+        first event at which ``until()`` returns True, with the rest of
+        the stream left in the heap: ``snapshot_state`` then cuts there,
+        and a later ``advance`` or ``run`` goes on from it.  Returns
+        True iff ``until`` stopped it before the stream ended."""
+        if (policy_id is None) == (on_event is None):
+            raise ValueError("exactly one of policy_id / on_event required")
+        return self._loop(policy_id, on_event, None, until)
+
     def _stamp_objective(self, report: RunReport, objective) -> RunReport:
         if objective is not None:
             from repro.core.objective import (metrics_from_rows,
@@ -361,7 +436,10 @@ class ClusterEmulator:
                     "it, even after the run); run the host event loop "
                     "instead")
             return self._run_fast(policy_id)
+        self._loop(policy_id, on_event, on_quiesce, None)
+        return self._report()
 
+    def _loop(self, policy_id, on_event, on_quiesce, until) -> bool:
         while self._heap:
             t, seq, kind, ident = heapq.heappop(self._heap)
             self.now = max(self.now, t)
@@ -422,7 +500,9 @@ class ClusterEmulator:
                 # push fresh end events and the loop resumes.
                 on_quiesce()
 
-        return self._report()
+            if until is not None and until():
+                return True
+        return False
 
     # ------------------------------------------------------------------
     def _run_fast(self, policy) -> RunReport:

@@ -16,8 +16,10 @@ flat-Poisson scenarios (``python -m benchmarks.run bursty``).
 
 ``arch_job_mix`` maps the assigned LM architectures onto job classes so
 the same twin schedules a TPU training/serving fleet (examples/).
-``swf`` helpers read/write the Standard Workload Format for replaying
-real center logs (e.g. the Polaris-like distribution of Figure 1).
+``lublin_trace`` draws rigid jobs from the Lublin–Feitelson model's
+batch class, the stream of the Polaris-scale deployment (560 nodes) and
+of Figure 1's job distribution.  ``swf`` helpers read/write the
+Standard Workload Format for replaying real center logs.
 """
 from __future__ import annotations
 
@@ -184,6 +186,78 @@ _ARCH_PODS = {
     "olmoe-1b-7b": 1, "rwkv6-7b": 2, "recurrentgemma-2b": 1,
     "whisper-small": 1,
 }
+
+
+#: The batch class of the Lublin–Feitelson rigid-job model (Lublin and
+#: Feitelson, "The workload on parallel supercomputers: modeling the
+#: characteristics of rigid jobs", JPDC 63(11), 2003; the ``lublin99``
+#: program of the Parallel Workloads Archive), as recalled and not
+#: checked against the source: job size, runtime and arrival-gap
+#: parameters.  ``ulow`` and ``uprob`` were not recalled: they are this
+#: repository's choice.
+LUBLIN_BATCH = {
+    "size": {"p_serial": 0.2927, "p_pow2": 0.7866, "ulow": 0.8,
+             "umed_below_uhi": 2.5, "uprob": 0.86},
+    "runtime": {"a1": 6.57, "b1": 0.823, "a2": 639.1, "b2": 0.0156,
+                "pa": -0.003, "pb": 0.6986},
+    "arrival": {"a": 10.23, "b": 0.4871},
+}
+
+
+def lublin_trace(n_jobs: int, total_nodes: int, seed: int = 0,
+                 load: float = 0.9,
+                 accuracy: Tuple[float, float] = (0.5, 1.0),
+                 max_walltime: float = 24 * 3600.0,
+                 params: Optional[Dict[str, Dict[str, float]]] = None,
+                 rng: Optional[np.random.Generator] = None,
+                 ) -> List[JobSpec]:
+    """Rigid jobs from the Lublin–Feitelson model (``LUBLIN_BATCH``):
+
+    * size: one node with probability ``p_serial``; otherwise log2(size)
+      is uniform on [ulow, umed] with probability ``uprob`` and on
+      [umed, uhi] otherwise, uhi = log2(total_nodes) and umed = uhi −
+      ``umed_below_uhi``; rounded to a power of two with probability
+      ``p_pow2``, else to a whole number of nodes;
+    * runtime: ln(runtime) is hyper-gamma, Gamma(a1, b1) with
+      probability p = pa·log2(size) + pb (clipped to [0, 1]) and
+      Gamma(a2, b2) otherwise; at least 1 s and at most
+      ``max_walltime``;
+    * arrivals: ln(gap) is Gamma(a, b), the gaps then scaled so that the
+      jobs offer ``load`` of the cluster's node-seconds over the span
+      of their arrivals (the model's daily cycle is left out);
+    * the user estimate is runtime / U(``accuracy``), capped at
+      ``max_walltime``, so runtime <= estimate.
+
+    Every draw is one vectorized call over the jobs, in a fixed order
+    (serial, stage, u, pow2, class, gamma 1, gamma 2, accuracy, gap).
+    ``rng=`` substitutes a caller-owned generator for ``seed``'s."""
+    rng = np.random.default_rng(seed) if rng is None else rng
+    p = {**LUBLIN_BATCH, **(params or {})}
+    sz, rt, ar = p["size"], p["runtime"], p["arrival"]
+    n = int(n_jobs)
+    serial, stage, u, pow2, cls = (rng.random(n) for _ in range(5))
+    g1 = rng.gamma(rt["a1"], rt["b1"], n)
+    g2 = rng.gamma(rt["a2"], rt["b2"], n)
+    acc = rng.uniform(accuracy[0], accuracy[1], n)
+    gaps = np.exp(rng.gamma(ar["a"], ar["b"], n))
+
+    uhi = np.log2(total_nodes)
+    umed = uhi - sz["umed_below_uhi"]
+    x = np.where(stage < sz["uprob"], sz["ulow"] + u * (umed - sz["ulow"]),
+                 umed + u * (uhi - umed))
+    size = np.where(pow2 < sz["p_pow2"], 2.0 ** np.rint(x), np.rint(2.0 ** x))
+    size = np.where(serial < sz["p_serial"], 1.0, size)
+    size = np.clip(size, 1, total_nodes).astype(np.int64)
+    p_short = np.clip(rt["pa"] * np.log2(size) + rt["pb"], 0.0, 1.0)
+    runtime = np.clip(np.exp(np.where(cls < p_short, g1, g2)), 1.0,
+                      max_walltime)
+    est = np.minimum(runtime / acc, max_walltime)
+    submit = np.concatenate([[0.0], np.cumsum(gaps[1:])])
+    work = float((size * runtime).sum())
+    if n > 1:
+        submit *= work / (load * total_nodes * submit[-1])
+    return [JobSpec(j, float(submit[j]), int(size[j]), float(est[j]),
+                    float(runtime[j]), "lublin") for j in range(n)]
 
 
 def arch_job_mix(n_jobs: int, total_pods: int = 32, seed: int = 0,

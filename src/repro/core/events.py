@@ -47,8 +47,9 @@ class EventKind(enum.IntEnum):
 class Event:
     """A single scheduler event.
 
-    ``time`` is physical-system time in seconds.  ``job_id`` is the dense
-    job-slot index assigned at submission (also the twin's array slot).
+    ``time`` is physical-system time in seconds.  ``job_id`` is the
+    scheduler's id, assigned in submission order and unbounded; the twin
+    maps it to a slot of its job table (``sync.JobSlots``).
     ``payload`` carries kind-specific metadata (job size, walltimes, node
     counts for NODEFAIL/NODEUP, ...).
     """
@@ -88,13 +89,13 @@ _JOB_KINDS = (EventKind.QUEUEJOB, EventKind.RUNJOB, EventKind.JOBOBIT)
 _NODE_KINDS = (EventKind.NODEFAIL, EventKind.NODEUP)
 
 
-def validate_event(ev, max_jobs: Optional[int] = None) -> Optional[str]:
+def validate_event(ev) -> Optional[str]:
     """Triage one event BEFORE it reaches ``sync.apply_event``: returns
     ``None`` for a well-formed event, else a short reason string the
     dead-letter queue records.  Checks are the corruption modes a real
     hook stream exhibits (and ``cluster.chaos`` injects): unknown kind,
     non-finite/negative time, job events without a valid ``job_id``
-    (out of the mirror's slot range when ``max_jobs`` is given), and
+    (a non-negative integer: the scheduler's ids are unbounded), and
     kind-specific payload fields that are missing, non-numeric,
     non-finite, or out of range."""
     try:
@@ -108,8 +109,6 @@ def validate_event(ev, max_jobs: Optional[int] = None) -> Optional[str]:
         jid = ev.job_id
         if not isinstance(jid, int) or jid < 0:
             return f"bad job_id {jid!r}"
-        if max_jobs is not None and jid >= max_jobs:
-            return f"job_id {jid} out of range (max_jobs={max_jobs})"
     required = {EventKind.QUEUEJOB: ("nodes", "est_runtime"),
                 EventKind.NODEFAIL: ("nodes",),
                 EventKind.NODEUP: ("nodes",)}.get(kind, ())

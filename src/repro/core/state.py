@@ -1,11 +1,13 @@
 """Struct-of-array job/cluster state — the twin's mirror and every fork's.
 
 Fixed-capacity arrays (``max_jobs`` slots) so every simulation has a
-static shape: slot ``i`` is job ``i`` for the lifetime of a trace.  The
-same structures are used by (a) the twin's mirror of the physical system
-(numpy leaves on the host, uploaded once per decision), (b) each what-if
-simulation fork, and (c) the cluster emulator's ground-truth state
-(which additionally knows true runtimes).
+static shape.  The same structures are used by (a) the twin's mirror of
+the physical system (numpy leaves on the host, uploaded once per
+decision; a window of slots recycled as jobs end, ``sync.JobSlots``),
+(b) each what-if simulation fork, and (c) the cluster emulator's
+ground-truth state (which additionally knows true runtimes; there slot
+``i`` is job ``i`` for the lifetime of a trace).  Slot order is
+submission order wherever a pass reads the table.
 """
 from __future__ import annotations
 

@@ -40,16 +40,96 @@ RUNJOB never arrived marks the job DONE without freeing nodes it never
 took.  As long as each job's own lifecycle order is preserved, any
 cross-job interleaving of deliveries yields the identical final mirror
 (the hypothesis property in tests/test_resilience.py).
+
+The job table is a window (``JobSlots``): the scheduler numbers jobs
+for the life of the cluster, the mirror holds a fixed number of slots
+for the live ones.  The handlers here work on slots; the twin names
+each event's job by its slot before applying it, gives the slot back
+(``release``) when the job ends, and ``pack``s the live slots in
+submission order before each upload.
 """
 from __future__ import annotations
 
-from typing import Tuple
+from typing import Dict, Tuple
 
 import numpy as np
 
 from repro.core.events import Event, EventKind
 from repro.core.state import (DONE, INVALID, QUEUED, RUNNING, TIME_NONE,
                               JobTable, SimState)
+
+
+#: An empty row of the job table, column by column.
+_EMPTY = {"submit_t": TIME_NONE, "nodes": 0, "est_runtime": 0.0,
+          "start_t": TIME_NONE, "end_t": TIME_NONE, "state": INVALID}
+
+
+class WindowFull(RuntimeError):
+    """A new job arrived while every slot of the job table was live."""
+
+
+class JobSlots:
+    """The twin's job table as a window: external job id <-> mirror slot.
+
+    The scheduler numbers its jobs over the life of the cluster; the
+    mirror has ``capacity`` slots, enough for the jobs that are live
+    (queued or running) at once.  A job takes a free slot when it is
+    queued and gives it back when it ends, so ids are unbounded and the
+    mirror's shape never changes.  A new job that finds every slot live
+    is refused (``WindowFull``), never dropped in silence.
+
+    ``order`` lists the live slots in id order, which is the order the
+    scheduler submits in: ``pack`` uploads them in that order, so on the
+    device slot order is submission order, as the engine's stable tie
+    rule and its hoisted orders require."""
+
+    def __init__(self, capacity: int):
+        self.capacity = int(capacity)
+        self.ids = np.full(self.capacity, -1, np.int64)     # slot -> id
+        self._slot: Dict[int, int] = {}                      # id -> slot
+        self._free = list(range(self.capacity - 1, -1, -1))  # lowest last
+
+    def __len__(self) -> int:
+        return len(self._slot)
+
+    def slot(self, job_id: int) -> int:
+        """``job_id``'s slot, or -1 where the window does not hold it."""
+        return self._slot.get(job_id, -1)
+
+    def assign(self, job_id: int) -> int:
+        """``job_id``'s slot, taking a free one if it has none."""
+        if job_id in self._slot:
+            return self._slot[job_id]
+        if not self._free:
+            raise WindowFull(f"job {job_id}: all {self.capacity} job "
+                             f"slots are live")
+        slot = self._free.pop()
+        self._slot[job_id] = slot
+        self.ids[slot] = job_id
+        return slot
+
+    def release(self, job_id: int) -> int:
+        """Free ``job_id``'s slot; returns it."""
+        slot = self._slot.pop(job_id)
+        self.ids[slot] = -1
+        self._free.append(slot)
+        return slot
+
+    def order(self) -> np.ndarray:
+        """The live slots in id (submission) order."""
+        live = np.flatnonzero(self.ids >= 0)
+        return live[np.argsort(self.ids[live], kind="stable")]
+
+    @classmethod
+    def from_ids(cls, ids) -> "JobSlots":
+        """The map whose slot ``s`` holds job ``ids[s]`` (-1: free)."""
+        ids = np.asarray(ids, np.int64)
+        slots = cls(ids.shape[0])
+        slots.ids = ids.copy()
+        slots._slot = {int(j): int(s) for s, j in enumerate(ids) if j >= 0}
+        slots._free = [s for s in range(slots.capacity - 1, -1, -1)
+                       if ids[s] < 0]
+        return slots
 
 
 def _set(jobs: JobTable, job_id: int, **values) -> JobTable:
@@ -182,43 +262,78 @@ def _apply_job_event_idempotent(state: SimState,
     return state, False              # DONE duplicate / unknown job
 
 
+def release(state: SimState, slot: int) -> SimState:
+    """Clear ``slot`` of an ended job, so the next job to take it starts
+    from an empty row (an idempotent QUEUEJOB applies only to one)."""
+    return state._replace(jobs=_set(state.jobs, slot, **_EMPTY))
+
+
+def pack(state: SimState, slots: JobSlots) -> Tuple[SimState, np.ndarray]:
+    """The mirror with its live slots gathered to the front in
+    submission order (the rest empty), and the job id of each packed
+    row: what the device decides over, and how its run mask maps back
+    to jobs."""
+    order = slots.order()
+    return (state._replace(jobs=_rows(state.jobs, {
+        name: getattr(state.jobs, name)[order] for name in _EMPTY})),
+        slots.ids[order])
+
+
+def _rows(jobs: JobTable, rows: Dict[str, np.ndarray]) -> JobTable:
+    """A table of ``jobs``' shape and dtypes holding ``rows`` first and
+    empty rows after them."""
+    cols = {}
+    for name, fill in _EMPTY.items():
+        col = getattr(jobs, name)
+        out = np.full(col.shape, fill, col.dtype)
+        out[:len(rows[name])] = rows[name]
+        cols[name] = out
+    return JobTable(**cols)
+
+
 def resync_free_nodes(state: SimState, authoritative_free: int) -> SimState:
     """Overwrite mirror free-node count from the physical system."""
     return state._replace(free_nodes=np.int32(authoritative_free))
 
 
-def resync_jobs(state: SimState, view: dict) -> SimState:
+def resync_jobs(state: SimState,
+                view: dict) -> Tuple[SimState, JobSlots, int]:
     """Full job-table reconcile from an authoritative probe (the qstat
     analogue of ``resync_free_nodes``) — the heal of last resort when
     the stream has LOST events the idempotent handlers cannot repair
     (above all a dropped QUEUEJOB: the twin otherwise never learns the
-    job exists and can never feed it to ``qrun``).
+    job exists and can never feed it to ``qrun``), and how a twin
+    attaches to a scheduler that is already running.
 
     ``view`` is ``ClusterEmulator.jobs_view()`` (or a real qstat
-    adapter): per-slot ``submit_t``/``nodes``/``est_runtime``/
-    ``start_t``/``end_t``/``state`` plus ``total_nodes`` and
-    ``free_nodes`` scalars.  The §3.2 estimate asymmetry is preserved —
-    running jobs get predicted ends ``start + estimate`` exactly as a
-    replayed RUNJOB would; only DONE jobs carry their actual end."""
-    submit = np.asarray(view["submit_t"], np.float32)
-    nodes = np.asarray(view["nodes"], np.int32)
-    est = np.asarray(view["est_runtime"], np.float32)
+    adapter), keyed by external job id: row ``j`` of its
+    ``submit_t``/``nodes``/``est_runtime``/``start_t``/``end_t``/
+    ``state`` is job ``j``, beside ``total_nodes`` and ``free_nodes``
+    scalars.  The live jobs (queued and running) take the window's
+    slots in id order; ended jobs are left out, as no decision reads
+    them.  The §3.2 estimate asymmetry is preserved — running jobs get
+    predicted ends ``start + estimate`` exactly as a replayed RUNJOB
+    would.  Returns the mirror, its slot map, and how many live jobs
+    the window could not hold (the newest, left out)."""
     st = np.asarray(view["state"], np.int32)
-    start = np.asarray(view["start_t"], np.float32)
-    end = np.asarray(view["end_t"], np.float32)
-    known = st != INVALID
+    live = np.flatnonzero((st == QUEUED) | (st == RUNNING))
+    cap = state.jobs.capacity
+    refused = max(0, live.size - cap)
+    live = live[:cap]
+    slots = JobSlots(cap)
+    for j in live.tolist():
+        slots.assign(j)
+    running = st[live] == RUNNING
+    start = np.asarray(view["start_t"], np.float32)[live]
+    est = np.asarray(view["est_runtime"], np.float32)[live]
     none = np.float32(TIME_NONE)
-    jobs = JobTable(
-        submit_t=np.where(known, submit, none),
-        nodes=np.where(known, nodes, np.int32(0)),
-        est_runtime=np.where(known, est, np.float32(0.0)),
-        start_t=np.where(known & (st != QUEUED), start, none),
-        end_t=np.where(st == RUNNING, start + est,
-                       np.where(st == DONE, end, none)),
-        state=st,
-    )
-    return state._replace(
-        jobs=jobs,
+    rows = {"submit_t": np.asarray(view["submit_t"], np.float32)[live],
+            "nodes": np.asarray(view["nodes"], np.int32)[live],
+            "est_runtime": est,
+            "start_t": np.where(running, start, none),
+            "end_t": np.where(running, start + est, none),
+            "state": st[live]}
+    return (state._replace(
+        jobs=_rows(state.jobs, rows),
         free_nodes=np.int32(int(view["free_nodes"])),
-        total_nodes=np.int32(int(view["total_nodes"])),
-    )
+        total_nodes=np.int32(int(view["total_nodes"]))), slots, refused)

@@ -5,10 +5,14 @@ Each pump of ``SchedTwin`` runs its stages (``STAGES``) under
 ``StageMeter.span``: a ``time.perf_counter`` span whose seconds add to
 the pump's stage totals, and a ``jax.profiler.TraceAnnotation`` of the
 same name, so a profile shows the stage on the host plane on the device
-ops' clock.  Beside the stage totals the meter counts the pump's
-events, its blocking device-to-host reads (``fetch``), its host-to-device
-uploads of the mirror (``upload``) and its backend compiles; a pump that
-records a decision cycle stamps all of them on that ``CycleRecord``.
+ops' clock.  A stage opened inside another is charged to itself alone,
+so the totals of a pump never count a second twice.  Beside the stage
+totals the meter counts the pump's events, the job-table slots it
+released (``recycled``) and the events it refused for a full table
+(``window_full``), its blocking device-to-host reads (``fetch``), its
+host-to-device uploads of the mirror (``upload``) and its backend
+compiles; a pump that records a decision cycle stamps all of them on
+that ``CycleRecord``.
 """
 from __future__ import annotations
 
@@ -21,9 +25,15 @@ from typing import Callable, Dict, Iterator, List, Optional, Sequence
 import jax
 import numpy as np
 
-#: The twin's host stages, in the order a pump runs them.
-STAGES = ("twin.read", "twin.ingest", "twin.resync", "twin.upload",
-          "twin.decide", "twin.fetch", "twin.unpack", "twin.qrun")
+#: The twin's host stages, in the order a pump runs them; ``twin.attach``
+#: runs once, when a twin attaches to a running scheduler.
+STAGES = ("twin.attach", "twin.read", "twin.ingest", "twin.resync",
+          "twin.slots", "twin.upload", "twin.decide", "twin.fetch",
+          "twin.unpack", "twin.qrun")
+
+#: What the twin counts in a pump: events consumed, job-table slots
+#: released, events refused because the table was full.
+TALLIES = ("events", "recycled", "window_full")
 
 #: JAX's monitoring event for one backend compile.
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
@@ -132,6 +142,12 @@ class CycleRecord:
     events: int = 0
     compiles: int = 0
     uploads: int = 0
+    # the job table (``sync.JobSlots``): live jobs in the window at this
+    # decision, slots the pump released, and events it refused because
+    # every slot was live (each also a dead letter).
+    live_jobs: int = 0
+    recycled: int = 0
+    window_full: int = 0
 
 
 @dataclasses.dataclass
@@ -151,6 +167,7 @@ class IngestStats:
     resyncs: int = 0         # authoritative probe reconciliations
     read_retries: int = 0    # bus reads retried after transient failure
     read_failures: int = 0   # reads that exhausted every retry
+    window_full: int = 0     # live jobs refused: every job slot was live
 
     def as_dict(self) -> Dict[str, int]:
         return dataclasses.asdict(self)
@@ -286,24 +303,38 @@ class Telemetry:
     # ---- overhead (paper: "a few seconds per scheduling cycle") -------
     def cycle_latency_stats(self) -> Dict[str, object]:
         """The decision span (``wall_seconds``) by nearest rank, the
-        median of each host stage, and the mean blocking reads, uploads
-        and events per cycle."""
+        median of each host stage, the mean blocking reads, uploads,
+        events, live jobs and released slots per cycle, the run's live
+        jobs refused for a full job table (``IngestStats``: a pump that
+        refuses its only event records no cycle), and ``twin.attach``'s
+        seconds (its one cycle's, or 0.0)."""
         n = len(self.cycles)
         if not n:
             return {"n": 0, "mean_s": 0.0, "p50_s": 0.0, "p95_s": 0.0,
                     "max_s": 0.0, "host_reads": 0.0, "uploads": 0.0,
-                    "events": 0.0,
+                    "events": 0.0, "live_jobs": 0.0, "recycled": 0.0,
+                    "window_full": self.ingest.window_full,
+                    "attach_s": 0.0,
                     "stage_p50_s": dict.fromkeys(STAGES, 0.0)}
         ws = [c.wall_seconds for c in self.cycles]
+
+        def mean(field: str) -> float:
+            return sum(getattr(c, field) for c in self.cycles) / n
+
         return {
             "n": n,
             "mean_s": sum(ws) / n,
             "p50_s": _nearest_rank(ws, 0.5),
             "p95_s": _nearest_rank(ws, 0.95),
             "max_s": max(ws),
-            "host_reads": sum(c.host_reads for c in self.cycles) / n,
-            "uploads": sum(c.uploads for c in self.cycles) / n,
-            "events": sum(c.events for c in self.cycles) / n,
+            "host_reads": mean("host_reads"),
+            "uploads": mean("uploads"),
+            "events": mean("events"),
+            "live_jobs": mean("live_jobs"),
+            "recycled": mean("recycled"),
+            "window_full": self.ingest.window_full,
+            "attach_s": sum(c.stages.get("twin.attach", 0.0)
+                            for c in self.cycles),
             "stage_p50_s": {
                 s: _nearest_rank([c.stages.get(s, 0.0) for c in self.cycles],
                                 0.5) for s in STAGES},
@@ -336,17 +367,22 @@ class StageMeter:
 
     def reset(self) -> None:
         self.stages = dict.fromkeys(STAGES, 0.0)
-        self.events = 0
+        self.tally = dict.fromkeys(TALLIES, 0)
+        self._open: List[float] = []   # seconds of inner spans, per open span
         self._counts0 = _counts()
 
     @contextlib.contextmanager
     def span(self, name: str) -> Iterator[None]:
         with jax.profiler.TraceAnnotation(name):
             t0 = time.perf_counter()
+            self._open.append(0.0)
             try:
                 yield
             finally:
-                self.stages[name] += time.perf_counter() - t0
+                dt = time.perf_counter() - t0
+                self.stages[name] += dt - self._open.pop()
+                if self._open:
+                    self._open[-1] += dt
 
     @contextlib.contextmanager
     def pump(self, cycles: List[CycleRecord]) -> Iterator[None]:
@@ -357,25 +393,25 @@ class StageMeter:
         fired by the enclosing pump's ``qrun``) is metered on its own:
         a cycle it records gets its own stages and counts, which leave
         the enclosing pump's.  One that records no cycle adds its
-        events, reads, uploads and compiles to the enclosing pump.  Either way
-        its time is in the enclosing pump's ``twin.qrun``."""
-        enclosing = (self.stages, self.events, self._counts0)
+        tallies, reads, uploads and compiles to the enclosing pump.
+        Either way its time is in the enclosing pump's ``twin.qrun``."""
+        enclosing = (self.stages, self.tally, self._open, self._counts0)
         self.reset()
         n0 = len(cycles)
         try:
             yield
         finally:
-            stages, events = self.stages, self.events
+            stages, tally = self.stages, self.tally
             end = _counts()
             counts = {k: end[k] - self._counts0[k] for k in _COUNTS}
-            self.stages, self.events, counts0 = enclosing
+            self.stages, self.tally, self._open, counts0 = enclosing
             if len(cycles) > n0:
                 rec = cycles[n0]
                 rec.stages = dict(stages)
-                rec.events = events
-                for k, v in counts.items():
+                for k, v in {**tally, **counts}.items():
                     setattr(rec, k, v)
                 self._counts0 = {k: counts0[k] + counts[k] for k in _COUNTS}
             else:
                 self._counts0 = counts0
-                self.events += events
+                for k, v in tally.items():
+                    self.tally[k] += v

@@ -23,6 +23,11 @@ bit-for-bit.  Decision cycles can run under a wall-clock budget
 (``guard.DeadlineGuard``) that degrades the decision down a ladder
 rather than letting it arrive late, and ``snapshot()``/``restore()``
 make the whole twin crash-safe through ``checkpoint.manager``.
+
+The job table is a window over the scheduler's stream
+(``sync.JobSlots``): ``max_jobs`` slots for the live jobs, external ids
+unbounded, a slot recycled when its job ends.  ``attach()`` starts a
+twin beside a scheduler that already has a queue, from its job view.
 """
 from __future__ import annotations
 
@@ -47,7 +52,7 @@ from repro.core.policies import (PAPER_POOL, PolicyPool, PolicySpec,
                                  PoolLike, normalize_pool)
 from repro.core.race import RaceSpec, normalize_race
 from repro.core.scoring import ScoreWeights
-from repro.core.state import QUEUED, SimState, empty_state
+from repro.core.state import DONE, INVALID, QUEUED, SimState, empty_state
 from repro.core.telemetry import fetch
 
 
@@ -140,12 +145,16 @@ class SchedTwin:
         (shrunk race/fan → static fallback pool → hold incumbent) on
         budget pressure so ``qrun`` is always fed on time (DESIGN.md
         §12).  Ladder level / margin / misses land in ``CycleRecord``.
+    max_jobs : the job table's slots: how many jobs may be live (queued
+        or running) at once.  Job ids are the scheduler's own and
+        unbounded; a job's slot is recycled when it ends.
     jobs_probe : callable() -> dict, optional
         Authoritative full job-table probe (the qstat analogue of
         ``free_nodes_probe``; ``ClusterEmulator.jobs_view``).  When the
         stream declares events LOST (a sequence hole aged past the
         reorder window), the mirror is rebuilt from this probe — the
-        only heal for a dropped QUEUEJOB.
+        only heal for a dropped QUEUEJOB — and ``attach()`` builds the
+        first mirror from it.
     fallback_pool : the static pool the ladder's level 2 decides over
         (default: the paper's §4.1 pool).
     clock / sleep : injectable time sources (ladder determinism under a
@@ -194,6 +203,7 @@ class SchedTwin:
         # the mirror of record, on the host (``core/sync.py``): ingest
         # never touches the device; each decision uploads it once.
         self.state: SimState = empty_state(max_jobs, total_nodes, xp=np)
+        self.slots = sync.JobSlots(max_jobs)
         self.telemetry = telemetry.Telemetry()
         self._meter = telemetry.StageMeter()
         self.free_nodes_probe = free_nodes_probe
@@ -250,7 +260,7 @@ class SchedTwin:
         except BusReadError:
             ing.read_failures += 1
             return 0
-        self._meter.events += len(events)
+        self._meter.tally["events"] += len(events)
         needs_cycle = False
         lost_any = False
         with span("twin.ingest"):
@@ -268,7 +278,7 @@ class SchedTwin:
             # rebuild the job table from the authoritative probe — the
             # only heal for a dropped QUEUEJOB.
             with span("twin.resync"):
-                self.state = sync.resync_jobs(self.state, self.jobs_probe())
+                self._resync_jobs(self.jobs_probe())
                 ing.resyncs += 1
                 t_latest = max(t_latest, float(self.state.now))
         if needs_cycle:
@@ -281,12 +291,12 @@ class SchedTwin:
         span = self._meter.span
         with span("twin.read"):
             self.bus.read(self.CONSUMER)  # keep offset in step with pushes
-        self._meter.events += 1
+        self._meter.tally["events"] += 1
         with span("twin.ingest"):
             applied, needs_cycle, gap, lost = self._ingest(ev)
         if lost and self.jobs_probe is not None:
             with span("twin.resync"):
-                self.state = sync.resync_jobs(self.state, self.jobs_probe())
+                self._resync_jobs(self.jobs_probe())
                 self.telemetry.ingest.resyncs += 1
         if needs_cycle or gap or lost:
             self._decision_cycle(float(ev.time) if applied
@@ -297,7 +307,7 @@ class SchedTwin:
         gap_detected, losses_declared)``.  Never raises: malformed
         events and handler failures land in ``dead_letters``."""
         ing = self.telemetry.ingest
-        reason = validate_event(ev, self.max_jobs)
+        reason = validate_event(ev)
         if reason is not None:
             self.dead_letters.append(DeadLetter(ev, reason))
             ing.quarantined += 1
@@ -316,10 +326,14 @@ class SchedTwin:
                 return False, False, gap, lost
             if obs.status == "reordered":
                 ing.reordered += 1
-        self._capture_residual(ev)
         try:
-            self.state, cycle = sync.apply_event(self.state, ev,
-                                                 idempotent=True)
+            cycle = self._apply(ev, idempotent=True)
+        except sync.WindowFull as exc:
+            self.dead_letters.append(DeadLetter(ev, f"window full: {exc}"))
+            ing.quarantined += 1
+            ing.window_full += 1
+            self._meter.tally["window_full"] += 1
+            return False, False, gap, lost
         except Exception as exc:  # noqa: BLE001 — quarantine boundary
             self.dead_letters.append(
                 DeadLetter(ev, f"apply failed: {type(exc).__name__}: "
@@ -328,12 +342,62 @@ class SchedTwin:
             return False, False, gap, lost
         return True, cycle, gap, lost
 
+    def _apply(self, ev: Event, idempotent: bool) -> bool:
+        """Apply one event to the mirror through the job table
+        (``twin.slots``): a QUEUEJOB takes a slot (``sync.WindowFull``
+        when none is free), a job event the window does not hold (never
+        queued here, or already ended) changes nothing, a NODEFAIL's
+        victim is named by its slot, and a job that ends gives its slot
+        back, cleared.  Returns whether a decision cycle is due."""
+        span = self._meter.span
+        with span("twin.slots"):
+            if ev.kind == EventKind.QUEUEJOB:
+                slot = self.slots.assign(ev.job_id)
+            elif ev.kind in (EventKind.RUNJOB, EventKind.JOBOBIT):
+                slot = self.slots.slot(ev.job_id)
+                if slot < 0:
+                    return False
+            else:
+                slot = -1
+                victim = int(ev.payload.get("victim_job", -1))
+                if victim >= 0:
+                    ev = dataclasses.replace(ev, payload={
+                        **ev.payload,
+                        "victim_job": float(self.slots.slot(victim))})
+            job = ev.job_id
+            if slot >= 0:
+                ev = dataclasses.replace(ev, job_id=slot)
+        self._capture_residual(ev)
+        try:
+            self.state, cycle = sync.apply_event(self.state, ev,
+                                                 idempotent=idempotent)
+        except Exception:
+            if (ev.kind == EventKind.QUEUEJOB
+                    and int(self.state.jobs.state[slot]) == INVALID):
+                self.slots.release(job)
+            raise
+        if (ev.kind == EventKind.JOBOBIT
+                and int(self.state.jobs.state[slot]) == DONE):
+            with span("twin.slots"):
+                self.state = sync.release(self.state, slot)
+                self.slots.release(job)
+                self._meter.tally["recycled"] += 1
+        return cycle
+
+    def _resync_jobs(self, view: dict) -> None:
+        """The mirror and its job table rebuilt from ``view``; live jobs
+        the window cannot hold are counted as refused."""
+        self.state, self.slots, refused = sync.resync_jobs(self.state, view)
+        self.telemetry.ingest.window_full += refused
+        self._meter.tally["window_full"] += refused
+
     def _capture_residual(self, ev: Event) -> None:
         """§3.2 estimate-vs-true runtime residual: a JOBOBIT reveals the
         actual walltime (obit time − recorded start) of a job the twin
         only ever knew by its user estimate.  Recorded host-side into
         telemetry before the mirror forgets the start time;
-        ``FanSpec.from_history`` fits its lognormal σ to these pairs."""
+        ``FanSpec.from_history`` fits its lognormal σ to these pairs.
+        ``ev`` names its job by slot."""
         if ev.kind != EventKind.JOBOBIT or ev.job_id < 0:
             return
         start = float(self.state.jobs.start_t[ev.job_id])
@@ -426,8 +490,10 @@ class SchedTwin:
                 self.state = sync.resync_free_nodes(
                     self.state, self.free_nodes_probe())
 
+        with span("twin.slots"):
+            packed, ids = sync.pack(self.state, self.slots)
         with span("twin.upload"):
-            state = telemetry.upload(self.state)
+            state = telemetry.upload(packed)
 
         level = self.guard.plan() if self.guard is not None else 0
         with telemetry.StopWatch(self._clock) as sw:
@@ -438,7 +504,8 @@ class SchedTwin:
                 run_mask = fetch(decision.run_mask)  # blocks for timing
         with span("twin.unpack"):
             job_ids = self._record_cycle(t, level, sw.seconds, decision,
-                                         race_out, names, source, run_mask)
+                                         race_out, names, source, run_mask,
+                                         ids)
         if job_ids:
             # ⑦ qrun — the physical system will emit RUNJOB events that
             # flow back through the bus and insert predicted-end events.
@@ -446,9 +513,11 @@ class SchedTwin:
                 self.qrun(job_ids, t)
 
     def _record_cycle(self, t: float, level: int, seconds: float, decision,
-                      race_out, names, source, run_mask) -> List[int]:
+                      race_out, names, source, run_mask,
+                      ids: np.ndarray) -> List[int]:
         """The decision's results to the host, recorded as one
-        ``CycleRecord``; returns the jobs to ``qrun``."""
+        ``CycleRecord``; returns the jobs to ``qrun``, by their ids
+        (``ids[i]`` is the job in row ``i`` of the packed mirror)."""
         guard_fields = {}
         if self.guard is not None:
             missed, margin = self.guard.observe(level, seconds)
@@ -457,7 +526,8 @@ class SchedTwin:
                 deadline_s=self.guard.spec.budget_s,
                 margin_s=margin, deadline_miss=missed)
 
-        job_ids = [int(j) for j in np.nonzero(run_mask)[0]]
+        with self._meter.span("twin.slots"):
+            job_ids = ids[np.nonzero(run_mask)[0]].tolist()
         # decisions are reported by family name + θ ("WFP",
         # "wfp[a=2,tau=600]", ...); pool position stays the tie-break.
         win_idx = int(fetch(decision.policy_index))
@@ -498,7 +568,8 @@ class SchedTwin:
             costs=costs, n_started=len(job_ids), started_jobs=job_ids,
             objective=str(self.objective), term_costs=term_costs,
             cost_ci=cost_ci, fan_width=fan_width,
-            fan_size=decision.fan_size, **race_fields, **guard_fields))
+            fan_size=decision.fan_size, live_jobs=len(ids),
+            **race_fields, **guard_fields))
         return job_ids
 
     @_metered
@@ -517,7 +588,7 @@ class SchedTwin:
             ing.lost += newly
         with self._meter.span("twin.resync"):
             if self.jobs_probe is not None:
-                self.state = sync.resync_jobs(self.state, self.jobs_probe())
+                self._resync_jobs(self.jobs_probe())
                 ing.resyncs += 1
             queued = bool((self.state.jobs.state == QUEUED).any())
             now = float(self.state.now) if queued else 0.0
@@ -526,20 +597,43 @@ class SchedTwin:
             return True
         return False
 
+    @_metered
+    def attach(self) -> bool:
+        """Start beside a scheduler that is already running: build the
+        mirror from its job view (``jobs_probe``, the qstat analogue),
+        keyed by external id, and its clock; read the bus from its end
+        on (the view holds what came before); and decide at once if
+        anything is queued.  Returns True iff a cycle ran."""
+        if self.jobs_probe is None:
+            raise ValueError("attach() needs a jobs_probe")
+        with self._meter.span("twin.attach"):
+            view = self.jobs_probe()
+            self._resync_jobs(view)
+            self.state = self.state._replace(now=np.float32(
+                max(float(self.state.now), float(view["now"]))))
+            head = len(self.bus)
+            self.bus.restore_offsets({self.CONSUMER: head})
+            self._tracker.max_seen = max(self._tracker.max_seen, head - 1)
+            queued = bool((self.state.jobs.state == QUEUED).any())
+        if queued:
+            self._decision_cycle(float(self.state.now))
+        return queued
+
     # ------------------------------------------------------------------
     def recover(self) -> None:
         """Rebuild the mirror from a full bus replay (twin restart)."""
         self.state = empty_state(self.state.jobs.capacity,
                                  int(self.state.total_nodes), xp=np)
+        self.slots = sync.JobSlots(self.state.jobs.capacity)
         for ev in self.bus.replay():
-            self.state, _ = sync.apply_event(self.state, ev)
+            self._apply(ev, idempotent=False)
 
     # -- crash-safe snapshots (DESIGN.md §12) ---------------------------
     def snapshot(self, manager, step: Optional[int] = None,
                  app_extra: Optional[Dict] = None) -> str:
         """Serialize the ENTIRE decision-relevant twin runtime through
-        ``checkpoint.CheckpointManager``: the SimState mirror and RNG
-        key ride the array tree (bitwise npz round-trip); the consumer
+        ``checkpoint.CheckpointManager``: the SimState mirror, its job
+        table's id of each slot and the RNG key ride the array tree (bitwise npz round-trip); the consumer
         offset, SeqTracker, guard ladder state, incumbent, dead letters,
         and telemetry ride the JSON ``extra``.  A twin built with the
         same configuration and ``restore()``d from this snapshot
@@ -571,7 +665,8 @@ class SchedTwin:
         }
         if app_extra is not None:
             extra["app"] = app_extra
-        return manager.save(step, {"state": self.state, "key": self._key},
+        return manager.save(step, {"state": self.state, "key": self._key,
+                                   "slots": self.slots.ids},
                             _jsonable(extra))
 
     def restore(self, manager,
@@ -585,9 +680,11 @@ class SchedTwin:
         if step is None:
             raise FileNotFoundError(
                 f"no checkpoint to restore under {manager.root!r}")
-        target = {"state": self.state, "key": self._key}
+        target = {"state": self.state, "key": self._key,
+                  "slots": self.slots.ids}
         tree, extra = manager.restore(step, target)
         self.state = jax.tree.map(np.asarray, tree["state"])
+        self.slots = sync.JobSlots.from_ids(tree["slots"])
         self._key = jnp.asarray(tree["key"])
         self.bus.restore_offsets(
             {self.CONSUMER: int(extra["consumer_offset"])})

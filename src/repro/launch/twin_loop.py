@@ -5,6 +5,10 @@
     python -m repro.launch.twin_loop --failures 2     # fault injection
     python -m repro.launch.twin_loop --backend pallas # kernel what-ifs
     python -m repro.launch.twin_loop --trace bursty   # diurnal arrivals
+    python -m repro.launch.twin_loop --trace lublin --nodes 560 \
+        --jobs 2048 --attach 1536 --window 1024
+        # Polaris-scale: the scheduler runs 1,536 submissions on its
+        # own, then a twin with a 1,024-slot job table attaches
     python -m repro.launch.twin_loop --replay-grid 8  # S x P baseline grid
     python -m repro.launch.twin_loop --replay-grid 64 \\
         --shard 0 --block-size 16      # fleet: sharded + block-streamed
@@ -29,6 +33,12 @@
 validated (parse -> spec -> parse round-trip) and the resolved goal is
 logged at startup.  In twin mode it drives every decision cycle; in
 ``--replay-grid`` mode it drives the per-scenario policy selection.
+
+``--attach N`` lets the emulator's own scheduler (FCFS + EASY) run the
+stream through submission N before the twin attaches from its job view
+(``SchedTwin.attach``), as a twin started beside a running PBS server
+would; ``--window W`` gives the twin's job table W slots (default: the
+emulator's, the whole stream), recycled as jobs end.
 
 ``--replay-grid S`` skips the co-simulation and instead evaluates the
 full (S scenarios × pool) baseline grid in ONE batched device replay
@@ -81,8 +91,8 @@ import dataclasses
 import numpy as np
 
 from repro.cluster.emulator import ClusterEmulator, FailureSpec
-from repro.cluster.workload import (bursty_trace, paper_synthetic_trace,
-                                    poisson_trace)
+from repro.cluster.workload import (bursty_trace, lublin_trace,
+                                    paper_synthetic_trace, poisson_trace)
 from repro.core.engine import PASS_BACKENDS, DrainEngine
 from repro.core.events import EventBus
 from repro.core.fan import FanSpec
@@ -317,8 +327,17 @@ def train_mode(args, engine: DrainEngine, goal: Objective,
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--trace", choices=("paper", "poisson", "bursty"),
+    ap.add_argument("--trace", choices=("paper", "poisson", "bursty",
+                                        "lublin"),
                     default="paper")
+    ap.add_argument("--attach", type=int, default=0, metavar="N",
+                    help="run the stream's first N submissions under the "
+                         "emulator's own FCFS + EASY scheduler, then "
+                         "attach the twin from its job view")
+    ap.add_argument("--window", type=int, default=0, metavar="W",
+                    help="the twin's job table: W slots for live jobs, "
+                         "recycled as jobs end (default: the emulator's "
+                         "table, the whole stream)")
     ap.add_argument("--no-compile-cache", action="store_true",
                     help="skip the persistent XLA compilation cache "
                          "(default: cache compiled engines under "
@@ -476,9 +495,13 @@ def main() -> None:
             and not args.race:
         ap.error("--race-f0/--budget-ms/--race-members apply to --race")
     if args.replay_grid and (args.chaos or args.snapshot_dir
-                             or args.budget_s):
-        ap.error("--chaos/--snapshot-dir/--budget-s apply to the twin "
-                 "co-simulation, not --replay-grid")
+                             or args.budget_s or args.attach
+                             or args.window):
+        ap.error("--chaos/--snapshot-dir/--budget-s/--attach/--window "
+                 "apply to the twin co-simulation, not --replay-grid")
+    if args.attach and args.resume:
+        ap.error("--resume continues an attached run from its snapshot; "
+                 "drop --attach")
     if args.train:
         if args.replay_grid:
             ap.error("--train and --replay-grid are mutually exclusive")
@@ -516,6 +539,8 @@ def main() -> None:
     elif args.trace == "bursty":
         trace = bursty_trace(args.jobs, args.nodes, 8.0, (1, args.nodes),
                              (30.0, 900.0), seed=args.seed)
+    elif args.trace == "lublin":
+        trace = lublin_trace(args.jobs, args.nodes, seed=args.seed)
     else:
         trace = poisson_trace(args.jobs, args.nodes, 8.0, (1, args.nodes),
                               (30.0, 900.0), seed=args.seed)
@@ -559,7 +584,7 @@ def main() -> None:
         print(f"chaos: {view.spec}")
     twin = SchedTwin(
         bus=view, qrun=em.qrun, total_nodes=args.nodes,
-        max_jobs=em.max_jobs, pool=pool, objective=goal,
+        max_jobs=args.window or em.max_jobs, pool=pool, objective=goal,
         free_nodes_probe=lambda: em.free_nodes,
         jobs_probe=em.jobs_view, guard=args.budget_s or None,
         ensemble=args.ensemble, fan=None if race else make_fan(args),
@@ -569,6 +594,15 @@ def main() -> None:
         em.restore_state(app["emulator"])
         print(f"resumed from snapshot step {step} "
               f"({len(twin.telemetry.cycles)} cycles already decided)")
+    if args.attach:
+        from repro.core.policies import FCFS
+        from repro.core.state import INVALID
+        em.advance(lambda: int(np.count_nonzero(em.state != INVALID))
+                   >= args.attach, policy_id=FCFS)
+        twin.attach()
+        print(f"attached at t={em.now:.1f} s after {args.attach} "
+              f"submissions: {len(twin.slots)} live jobs in "
+              f"{twin.max_jobs} slots")
 
     def take_snapshot():
         twin.snapshot(manager, app_extra={
@@ -647,7 +681,10 @@ def main() -> None:
         f"{name.split('.')[-1]} {s * 1e3:.3f}"
         for name, s in lat["stage_p50_s"].items())
         + f"; host reads {lat['host_reads']:.1f}, uploads "
-        f"{lat['uploads']:.1f}, events {lat['events']:.2f} per cycle")
+        f"{lat['uploads']:.1f}, events {lat['events']:.2f}, live_jobs "
+        f"{lat['live_jobs']:.1f}, recycled {lat['recycled']:.2f} per "
+        f"cycle; window_full {lat['window_full']}; attach "
+        f"{lat['attach_s'] * 1e3:.3f} ms")
     res = twin.telemetry.resilience_stats()
     print(f"resilience: miss_rate={res['miss_rate']:.3f} "
           f"(misses={res['deadline_misses']}/{res['cycles']}, "

@@ -1,10 +1,11 @@
-"""Host-emulator regressions: the stale-end sequence guard and the
-failure-timeline utilization denominator."""
+"""Host-emulator regressions: the stale-end sequence guard, the
+failure-timeline utilization denominator, and a static run cut at a
+submission (``start_at``)."""
 import numpy as np
 import pytest
 
 from repro.cluster.emulator import ClusterEmulator, FailureSpec
-from repro.cluster.workload import JobSpec
+from repro.cluster.workload import JobSpec, poisson_trace
 from repro.core.policies import FCFS
 
 
@@ -78,3 +79,39 @@ def test_event_times_are_f32_representable():
     rep = ClusterEmulator(trace, 16).run(policy_id=FCFS)
     for arr in (rep.start_t, rep.end_t, rep.submit_t):
         np.testing.assert_array_equal(arr, arr.astype(np.float32))
+
+
+@pytest.mark.parametrize("cut", [10, 57, 99, 118])
+def test_start_at_is_the_host_loop_cut_at_a_submission(cut):
+    """A fresh emulator put by ``start_at`` at a submission of a static
+    run holds what the host loop holds there: the same snapshot, heap
+    included, and the same rest of the run."""
+    from repro.core.engine import DrainEngine
+    from repro.core.state import INVALID
+    eng = DrainEngine("reference")
+    trace = poisson_trace(120, 16, 20.0, (1, 8), (30.0, 600.0), seed=3)
+    report = ClusterEmulator(trace, 16, engine=eng).run(policy_id=FCFS,
+                                                        fast=True)
+    host = ClusterEmulator(trace, 16, engine=eng)
+    assert host.advance(lambda: int(np.count_nonzero(host.state != INVALID))
+                        > cut, policy_id=FCFS)
+    fast = ClusterEmulator(trace, 16, engine=eng)
+    fast.start_at(report, cut)
+    h, f = host.snapshot_state(), fast.snapshot_state()
+    assert sorted(map(tuple, h.pop("heap"))) == \
+        sorted(map(tuple, f.pop("heap")))
+    assert h == f
+    rest = fast.run(policy_id=FCFS)
+    np.testing.assert_array_equal(rest.start_t, report.start_t)
+    np.testing.assert_array_equal(rest.end_t, report.end_t)
+
+
+def test_start_at_refuses_an_instant_another_event_shares():
+    trace = [JobSpec(0, 0.0, 4, 10.0, 10.0), JobSpec(1, 5.0, 4, 10.0, 10.0),
+             JobSpec(2, 5.0, 4, 10.0, 10.0), JobSpec(3, 10.0, 4, 10.0, 10.0)]
+    report = ClusterEmulator(trace, 8).run(policy_id=FCFS, fast=True)
+    with pytest.raises(ValueError, match="shares"):
+        ClusterEmulator(trace, 8).start_at(report, 1)   # job 2 at t=5
+    with pytest.raises(ValueError, match="shares"):
+        ClusterEmulator(trace, 8).start_at(report, 3)   # job 0 ends at 10
+    ClusterEmulator(trace, 8).start_at(report, 2)

@@ -106,8 +106,11 @@ def test_every_cycle_has_all_stages_and_exact_counts():
         assert rec.uploads == 1, name
         assert rec.n_started == started, name
         assert (rec.stages["twin.qrun"] > 0.0) == bool(started), name
-        for stage in STAGES[:-1]:
+        # every pump runs each stage but qrun (only when it starts jobs)
+        # and attach (only in ``SchedTwin.attach``)
+        for stage in set(STAGES) - {"twin.attach", "twin.qrun"}:
             assert rec.stages[stage] > 0.0, (name, stage)
+        assert rec.stages["twin.attach"] == 0.0, name
     assert e[1] is None
     assert len(s.twin.telemetry.cycles) == 4
     # the decision span is decide + fetch, as before
@@ -235,7 +238,7 @@ def test_stages_nest_in_the_drivers_pump_span_of_a_profile(tmp_path):
     assert len(pumps) == 1
     p = pumps[0]
     found = {e.name: e for e in host if e.name in STAGES}
-    assert set(found) == set(STAGES)
+    assert set(found) == set(STAGES) - {"twin.attach"}
     for e in found.values():
         assert p.start <= e.start and e.start + e.dur <= p.start + p.dur
     # given the stage names, an idle gap inside a stage is named by it
@@ -304,3 +307,21 @@ def test_twin_loop_prints_every_stage(monkeypatch, capsys):
         assert f" {name.split('.')[-1]} " in line, name
     assert "host reads" in line and "events" in line
     assert "uploads 1.0" in line
+
+
+def test_twin_loop_attaches_to_a_lublin_stream(monkeypatch, capsys):
+    """The Polaris-scale path at a CPU size: a Lublin stream run by the
+    emulator's own scheduler through 40 submissions, then a twin with
+    32 job slots attached; its stage line carries the job table."""
+    from repro.launch import twin_loop
+    monkeypatch.setattr("sys.argv", [
+        "twin_loop", "--trace", "lublin", "--jobs", "60", "--nodes", "64",
+        "--attach", "40", "--window", "32", "--backend", "reference",
+        "--no-compile-cache"])
+    twin_loop.main()
+    out = capsys.readouterr().out.splitlines()
+    assert any(x.startswith("attached at ") for x in out)
+    line = next(x for x in out if x.startswith("cycle stages, p50 ms: "))
+    for part in (" attach ", " slots ", "live_jobs ", "recycled ",
+                 "window_full 0"):
+        assert part in line, part

@@ -60,8 +60,8 @@ def build_cosim(trace, total_nodes=16, view_wrap=None, **twin_kw):
     bus = EventBus()
     em = ClusterEmulator(trace, total_nodes, bus=bus)
     view = view_wrap(bus) if view_wrap else bus
+    twin_kw.setdefault("max_jobs", em.max_jobs)
     twin = SchedTwin(bus=view, qrun=em.qrun, total_nodes=total_nodes,
-                     max_jobs=em.max_jobs,
                      free_nodes_probe=lambda: em.free_nodes,
                      jobs_probe=em.jobs_view,
                      sleep=lambda s: None, **twin_kw)
@@ -125,7 +125,7 @@ def test_bus_dump_round_trip():
            {"nodes": float("inf"), "est_runtime": 5.0}), "nodes"),
 ])
 def test_validate_event_rejects(ev, reason):
-    err = validate_event(ev, max_jobs=8)
+    err = validate_event(ev)
     assert err is not None and reason in err
 
 
@@ -138,7 +138,7 @@ def test_validate_event_accepts_emulator_shapes():
                 {"nodes": 0.0, "victim_job": 0.0}),
           Event(EventKind.NODEUP, 4.0, -1, {"nodes": 4.0})]
     for ev in ok:
-        assert validate_event(ev, max_jobs=8) is None, ev
+        assert validate_event(ev) is None, ev
 
 
 def test_twin_quarantines_instead_of_crashing():
@@ -158,6 +158,36 @@ def test_twin_quarantines_instead_of_crashing():
     assert len(twin.dead_letters) > 0
     assert twin.telemetry.ingest.quarantined == len(twin.dead_letters)
     assert all(dl.reason for dl in twin.dead_letters)
+
+
+def test_a_full_job_table_refuses_loudly():
+    # five jobs that never start (qrun ignores the twin) into a job table
+    # of three slots: the last two are dead letters, counted as refused;
+    # ids run far past the table's size
+    bus = EventBus()
+    twin = SchedTwin(bus=bus, qrun=lambda jobs, t: None, total_nodes=4,
+                     max_jobs=3)
+    for j in range(5):
+        bus.publish(Event(EventKind.QUEUEJOB, float(j), 1000 + j,
+                          {"nodes": 8.0, "est_runtime": 30.0}))
+    twin.pump()
+    rec = twin.telemetry.cycles[-1]
+    assert [dl.event.job_id for dl in twin.dead_letters] == [1003, 1004]
+    assert all(dl.reason.startswith("window full")
+               for dl in twin.dead_letters)
+    assert twin.telemetry.ingest.window_full == 2
+    assert twin.telemetry.ingest.quarantined == 2
+    assert (rec.window_full, rec.live_jobs, rec.recycled) == (2, 3, 0)
+    # a job that ends gives its slot back, cleared, to the next one
+    bus.publish(Event(EventKind.JOBOBIT, 9.0, 1001))
+    bus.publish(Event(EventKind.QUEUEJOB, 10.0, 1005,
+                      {"nodes": 1.0, "est_runtime": 30.0}))
+    twin.pump()
+    rec = twin.telemetry.cycles[-1]
+    assert (rec.window_full, rec.live_jobs, rec.recycled) == (0, 3, 1)
+    assert len(twin.dead_letters) == 2
+    assert sorted(twin.slots.ids.tolist()) == [1000, 1002, 1005]
+    assert twin.telemetry.cycle_latency_stats()["window_full"] == 2
 
 
 # ----------------------------------------------------------------------
@@ -431,26 +461,36 @@ def test_guarded_cycles_stamp_telemetry_and_stats():
 # crash-safe snapshots: bitwise decision parity on both backends
 # ----------------------------------------------------------------------
 
-@pytest.mark.parametrize("backend", ["reference", "pallas"])
-def test_snapshot_restore_bitwise_decision_parity(tmp_path, backend):
+@pytest.mark.parametrize("backend,n_jobs,window,cut", [
+    pytest.param("reference", 10, None, 4, id="reference"),
+    pytest.param("pallas", 10, None, 4, id="pallas"),
+    # 40 jobs through a 28-slot job table, cut after slots recycled
+    pytest.param("reference", 40, 28, 50, id="reference-recycled"),
+])
+def test_snapshot_restore_bitwise_decision_parity(tmp_path, backend, n_jobs,
+                                                   window, cut):
     eng = DrainEngine(backend, interpret=(backend == "pallas"))
-    trace = tiny_trace(10, seed=5)
+    trace = tiny_trace(n_jobs, seed=5)
+    table = {"max_jobs": window} if window else {}
 
-    bus, em, _, twin = build_cosim(trace, engine=eng)
+    bus, em, _, twin = build_cosim(trace, engine=eng, **table)
     em.run(on_event=twin.pump, on_quiesce=twin.flush)
     ref = decisions(twin)
+    assert not twin.dead_letters
 
-    bus, em, _, twin = build_cosim(trace, engine=eng)
+    bus, em, _, twin = build_cosim(trace, engine=eng, **table)
     mgr = CheckpointManager(str(tmp_path / backend))
     holder = {"twin": twin, "killed": False}
 
     def pump():
         t = holder["twin"]
         t.pump()
-        if not holder["killed"] and len(t.telemetry.cycles) >= 4:
+        if not holder["killed"] and len(t.telemetry.cycles) >= cut:
+            if window:
+                assert sum(c.recycled for c in t.telemetry.cycles) > 0
             t.snapshot(mgr)
             fresh = SchedTwin(bus=bus, qrun=em.qrun, total_nodes=16,
-                              max_jobs=em.max_jobs,
+                              max_jobs=window or em.max_jobs,
                               free_nodes_probe=lambda: em.free_nodes,
                               jobs_probe=em.jobs_view, engine=eng,
                               sleep=lambda s: None)
@@ -463,6 +503,10 @@ def test_snapshot_restore_bitwise_decision_parity(tmp_path, backend):
                 assert isinstance(got, np.ndarray)
                 assert got.dtype == want.dtype
                 np.testing.assert_array_equal(got, want)
+            # and so is its job table's id <-> slot map
+            np.testing.assert_array_equal(fresh.slots.ids, t.slots.ids)
+            assert [fresh.slots.slot(j) for j in range(n_jobs)] == \
+                [t.slots.slot(j) for j in range(n_jobs)]
             holder["twin"] = fresh
             holder["killed"] = True
 

@@ -65,22 +65,25 @@ def _device_apply(state, ev, idempotent):
 
 
 def _device_resync_jobs(state, view):
+    """The live jobs of ``view`` (queued, running) in id order at the
+    front of an empty table, running ones with predicted ends."""
     st = jnp.asarray(view["state"], jnp.int32)
-    start = jnp.asarray(view["start_t"], jnp.float32)
-    est = jnp.asarray(view["est_runtime"], jnp.float32)
+    live = jnp.flatnonzero((st == QUEUED) | (st == RUNNING))
+    running = st[live] == RUNNING
+    start = jnp.asarray(view["start_t"], jnp.float32)[live]
+    est = jnp.asarray(view["est_runtime"], jnp.float32)[live]
     none = jnp.float32(TIME_NONE)
-    known = st != INVALID
+    n = live.shape[0]
+    empty = empty_state(state.jobs.capacity, 0).jobs
     jobs = JobTable(
-        submit_t=jnp.where(known, jnp.asarray(view["submit_t"],
-                                              jnp.float32), none),
-        nodes=jnp.where(known, jnp.asarray(view["nodes"], jnp.int32), 0),
-        est_runtime=jnp.where(known, est, 0.0),
-        start_t=jnp.where(known & (st != QUEUED), start, none),
-        end_t=jnp.where(st == RUNNING, start + est,
-                        jnp.where(st == DONE,
-                                  jnp.asarray(view["end_t"], jnp.float32),
-                                  none)),
-        state=st)
+        submit_t=empty.submit_t.at[:n].set(
+            jnp.asarray(view["submit_t"], jnp.float32)[live]),
+        nodes=empty.nodes.at[:n].set(
+            jnp.asarray(view["nodes"], jnp.int32)[live]),
+        est_runtime=empty.est_runtime.at[:n].set(est),
+        start_t=empty.start_t.at[:n].set(jnp.where(running, start, none)),
+        end_t=empty.end_t.at[:n].set(jnp.where(running, start + est, none)),
+        state=empty.state.at[:n].set(st[live]))
     return state._replace(jobs=jobs,
                           free_nodes=jnp.int32(view["free_nodes"]),
                           total_nodes=jnp.int32(view["total_nodes"]))
@@ -193,8 +196,12 @@ def test_host_mirror_matches_device_updates(stream):
             host = sync.resync_free_nodes(host, op[1])
             device = device._replace(free_nodes=jnp.int32(op[1]))
         else:
-            host = sync.resync_jobs(host, op[1])
+            host, slots, refused = sync.resync_jobs(host, op[1])
             device = _device_resync_jobs(device, op[1])
+            live = np.flatnonzero(np.isin(op[1]["state"],
+                                          (QUEUED, RUNNING)))
+            assert refused == 0 and slots.ids[:live.size].tolist() == \
+                live.tolist()
         _assert_same(host, device, f"step {i} {op[:2]}")
         # a handler returns a new state: the one it was given is intact
         jax.tree.map(np.testing.assert_array_equal, given, before)

@@ -124,3 +124,83 @@ def test_ensemble_twin_drains():
     trace = tiny_trace(16, seed=7)
     report, twin = run_twin(trace, ensemble=4, ensemble_noise=0.3)
     assert report.n_jobs == 16
+
+
+# ----------------------------------------------------------------------
+# the job table as a window over the stream (``sync.JobSlots``)
+# ----------------------------------------------------------------------
+
+def _cosim(trace, total_nodes, max_jobs):
+    bus = EventBus()
+    em = ClusterEmulator(trace, total_nodes, bus=bus, check_invariants=True)
+    twin = SchedTwin(bus=bus, qrun=em.qrun, total_nodes=total_nodes,
+                     max_jobs=max_jobs,
+                     free_nodes_probe=lambda: em.free_nodes,
+                     jobs_probe=em.jobs_view)
+    em.run(on_event=twin.pump, on_quiesce=twin.flush)
+    assert not twin.dead_letters
+    return twin
+
+
+@pytest.mark.parametrize("seed", [0, 2])
+def test_a_window_decides_as_the_whole_table(seed):
+    """200 jobs on 16 nodes through a 32-slot job table: every slot is
+    recycled several times, and every cycle's policy and qrun set are
+    those of a twin whose table holds the whole stream; the costs sum
+    over other slot positions, so they agree to float32 rounding."""
+    from repro.cluster.workload import poisson_trace
+    trace = poisson_trace(200, 16, 40.0, (1, 8), (30.0, 600.0), seed=seed)
+    small, whole = _cosim(trace, 16, 32), _cosim(trace, 16, 256)
+    a, b = small.telemetry.cycles, whole.telemetry.cycles
+    assert len(a) == len(b) > 200
+    assert max(c.live_jobs for c in a) <= 32
+    assert sum(c.recycled for c in a) == 200
+    for x, y in zip(a, b):
+        assert (x.time, x.policy, x.started_jobs) == \
+            (y.time, y.policy, y.started_jobs)
+        assert x.costs.keys() == y.costs.keys()
+        for name in x.costs:
+            assert x.costs[name] == pytest.approx(y.costs[name], rel=1e-6)
+
+
+def test_an_attached_twin_decides_as_the_reference():
+    """A twin attached to a snapshot taken mid-stream, with a job table
+    smaller than every id it sees, against the reference judge that
+    starts from the same snapshot (``bench/attach.py``)."""
+    from bench import attach, check, gen
+    from bench import reference as ref
+    from repro.cluster.workload import poisson_trace
+    from repro.core.state import INVALID
+    nodes, attach_at = 16, 120
+    trace = poisson_trace(180, nodes, 25.0, (1, 8), (30.0, 600.0), seed=4)
+    pre = ClusterEmulator(trace, nodes)
+    pre.advance(lambda: int(np.count_nonzero(pre.state != INVALID))
+                >= attach_at, policy_id=FCFS)
+    snapshot = pre.snapshot_state()
+    bus = EventBus()
+    em = ClusterEmulator(trace, nodes, bus=bus, check_invariants=True)
+    em.restore_state(snapshot)
+    twin = SchedTwin(bus=bus, qrun=em.qrun, total_nodes=nodes, max_jobs=64,
+                     free_nodes_probe=lambda: em.free_nodes,
+                     jobs_probe=em.jobs_view)
+    assert twin.attach()                     # a queue: it decides at once
+    cycles = twin.telemetry.cycles
+    assert em.advance(lambda: len(cycles) >= 60, on_event=twin.pump)
+    assert not twin.dead_letters
+    first = cycles[0]
+    assert first.stages["twin.attach"] > 0.0 and first.live_jobs > 0
+    assert all(c.stages["twin.attach"] == 0.0 for c in cycles[1:])
+    assert sum(c.recycled for c in cycles) > 0
+    names = list(twin.pool.names)
+    plain = gen.as_trace([j.submit_t for j in trace],
+                         [j.nodes for j in trace],
+                         [j.est_runtime for j in trace],
+                         [j.true_runtime for j in trace])
+    out = attach.attach_episode(
+        plain, nodes, ref.parse_pool("paper"), snapshot,
+        [check.Cycle(c.time, names.index(c.policy),
+                     [c.costs[n] for n in names], c.started_jobs)
+         for c in cycles], judge=range(len(cycles)))
+    assert out["judged"] == len(cycles)
+    assert (out["off"], out["missing"]) == (0, 0), out
+    assert out["cost_gap"] < 1e-3
