@@ -124,7 +124,35 @@ def as_pool(policy) -> EnginePool:
     return arr.reshape(1) if arr.ndim == 0 else arr
 
 
-class Decision(NamedTuple):
+class _DecisionFields(NamedTuple):
+    policy_index: jax.Array   # index into the pool (NOT the policy id)
+    costs: jax.Array          # (k,) per-policy objective cost
+    run_mask: jax.Array       # bool (max_jobs,) jobs to start now (qrun set)
+    metrics: DrainMetrics     # (k,)-leading metrics for telemetry
+    deadlocked: jax.Array     # (k,) bool
+    cost_terms: Optional[Dict[str, jax.Array]] = None  # term -> (k,)
+    cost_ci: Optional[jax.Array] = None    # (k,) 95% CI half-width
+    fan_width: Optional[jax.Array] = None  # (k,) member-cost spread
+    fan_size: int = 1                      # members behind the costs
+    words: Optional[jax.Array] = None      # int32 host view (``packed``)
+
+
+#: The fields ``Decision.words`` holds.
+_WORD_FIELDS = frozenset({"run_mask", "policy_index", "costs", "cost_terms",
+                          "cost_ci", "fan_width"})
+
+
+class HostDecision(NamedTuple):
+    """A decision's results on the host, read from its ``words``."""
+    run_mask: np.ndarray                  # bool (J,)
+    policy_index: int
+    costs: np.ndarray                     # float32 (k,)
+    cost_terms: Dict[str, np.ndarray]     # term -> float32 (k,)
+    cost_ci: Optional[np.ndarray]
+    fan_width: Optional[np.ndarray]
+
+
+class Decision(_DecisionFields):
     """One scheduling cycle's outcome (re-exported by ``whatif``).
 
     ``costs`` is the goal's compiled cost per fork (argmin = winner);
@@ -139,16 +167,55 @@ class Decision(NamedTuple):
     member deadlocked), ``fan_width`` the full member-cost spread
     (worst − best member; the "how sure is the twin" headline), and
     ``fan_size`` the member count F.  Single-future decisions leave
-    them None/1."""
-    policy_index: jax.Array   # index into the pool (NOT the policy id)
-    costs: jax.Array          # (k,) per-policy objective cost
-    run_mask: jax.Array       # bool (max_jobs,) jobs to start now (qrun set)
-    metrics: DrainMetrics     # (k,)-leading metrics for telemetry
-    deadlocked: jax.Array     # (k,) bool
-    cost_terms: Optional[Dict[str, jax.Array]] = None  # term -> (k,)
-    cost_ci: Optional[jax.Array] = None    # (k,) 95% CI half-width
-    fan_width: Optional[jax.Array] = None  # (k,) member-cost spread
-    fan_size: int = 1                      # members behind the costs
+    them None/1.
+
+    ``words`` is what the host reads of a decision, in ONE transfer: a
+    flat int32 buffer built in the producing program (``packed``) —
+    the run mask as 0/1 (J,), the winner's index, then the float32
+    rows ``costs``, each ``cost_terms`` value in sorted key order (a
+    jitted program's dict outputs come back sorted), ``cost_ci``
+    and ``fan_width`` where present, each (k,) and bitcast, so every
+    value (an inf of a deadlocked fork too) crosses bit for bit.
+    ``unpack`` reads it back from the shapes and keys the decision
+    already has on the host; ``_replace`` of a field it holds packs it
+    anew, so the words never disagree with the fields."""
+    __slots__ = ()
+
+    def packed(self) -> "Decision":
+        """This decision with its ``words``."""
+        terms = self.cost_terms or {}
+        rows = [self.costs, *(terms[t] for t in sorted(terms)),
+                *(x for x in (self.cost_ci, self.fan_width)
+                  if x is not None)]
+        floats = jnp.stack(rows).astype(jnp.float32)
+        words = jnp.concatenate([
+            jnp.asarray(self.run_mask).astype(jnp.int32),
+            jnp.reshape(jnp.asarray(self.policy_index, jnp.int32), (1,)),
+            jax.lax.bitcast_convert_type(floats, jnp.int32).reshape(-1)])
+        return super()._replace(words=words)
+
+    def unpack(self, words: np.ndarray) -> HostDecision:
+        """``words``, read to the host, as its fields."""
+        J = self.run_mask.shape[-1]
+        k = self.costs.shape[-1]
+        rows = words[J + 1:].view(np.float32).reshape(-1, k)
+        terms = list(self.cost_terms or {})
+        by_name = dict(zip(sorted(terms), rows[1:]))
+        rest = iter(rows[1 + len(terms):])
+        return HostDecision(
+            run_mask=words[:J] != 0,
+            policy_index=int(words[J]),
+            costs=rows[0],
+            cost_terms={t: by_name[t] for t in terms},
+            cost_ci=next(rest) if self.cost_ci is not None else None,
+            fan_width=next(rest) if self.fan_width is not None else None)
+
+    def _replace(self, **changes) -> "Decision":
+        out = super()._replace(**changes)
+        if (self.words is not None and "words" not in changes
+                and not _WORD_FIELDS.isdisjoint(changes)):
+            return out.packed()
+        return out
 
 
 class ReplayOutcome(NamedTuple):
@@ -756,7 +823,7 @@ def _decide_impl(engine: DrainEngine, state: SimState, pool: EnginePool,
             metrics=metrics,
             deadlocked=res.deadlocked,
             cost_terms=objective.cost_terms(metrics),
-        )
+        ).packed()
 
 
 @functools.partial(jax.jit, static_argnames=("engine", "objective", "plan"))
@@ -820,7 +887,7 @@ def _decide_ensemble(engine: DrainEngine, state: SimState, pool: EnginePool,
         cost_terms=objective.cost_terms(mean_metrics),
         cost_ci=ci,
         fan_width=width,
-    )
+    ).packed()
 
 
 @functools.partial(jax.jit,
@@ -897,7 +964,7 @@ def _decide_fan(engine: DrainEngine, state: SimState, pool: EnginePool,
         cost_terms=dist.cost_terms(mean_metrics),
         cost_ci=ci,
         fan_width=width,
-    )
+    ).packed()
 
 
 # ----------------------------------------------------------------------

@@ -399,7 +399,7 @@ def decide_race(state, pool, race, objective=None, *, engine=None):
         cost_ci=jnp.asarray(full["ci"]),
         fan_width=jnp.asarray(full["width"]),
         fan_size=out.fan_size,
-    )
+    ).packed()
     return decision, out
 
 

@@ -501,10 +501,11 @@ class SchedTwin:
                 decision, race_out, names, source = \
                     self._decide_at_level(level, state)
             with span("twin.fetch"):
-                run_mask = fetch(decision.run_mask)  # blocks for timing
+                # every result in one read, which waits for the device
+                words = fetch(decision.words)
         with span("twin.unpack"):
             job_ids = self._record_cycle(t, level, sw.seconds, decision,
-                                         race_out, names, source, run_mask,
+                                         race_out, names, source, words,
                                          ids)
         if job_ids:
             # ⑦ qrun — the physical system will emit RUNJOB events that
@@ -513,11 +514,12 @@ class SchedTwin:
                 self.qrun(job_ids, t)
 
     def _record_cycle(self, t: float, level: int, seconds: float, decision,
-                      race_out, names, source, run_mask,
+                      race_out, names, source, words: np.ndarray,
                       ids: np.ndarray) -> List[int]:
-        """The decision's results to the host, recorded as one
-        ``CycleRecord``; returns the jobs to ``qrun``, by their ids
-        (``ids[i]`` is the job in row ``i`` of the packed mirror)."""
+        """The decision's results, read to the host as ``words``
+        (``Decision.packed``), recorded as one ``CycleRecord``; returns
+        the jobs to ``qrun``, by their ids (``ids[i]`` is the job in row
+        ``i`` of the packed mirror)."""
         guard_fields = {}
         if self.guard is not None:
             missed, margin = self.guard.observe(level, seconds)
@@ -526,36 +528,32 @@ class SchedTwin:
                 deadline_s=self.guard.spec.budget_s,
                 margin_s=margin, deadline_miss=missed)
 
+        view = decision.unpack(words)
         with self._meter.span("twin.slots"):
-            job_ids = ids[np.nonzero(run_mask)[0]].tolist()
+            job_ids = ids[np.nonzero(view.run_mask)[0]].tolist()
         # decisions are reported by family name + θ ("WFP",
         # "wfp[a=2,tau=600]", ...); pool position stays the tie-break.
-        win_idx = int(fetch(decision.policy_index))
+        win_idx = view.policy_index
         winner = names[win_idx]
         src, idx = source
         self._incumbent = (src, win_idx) if idx is None else (src, idx)
-        costs = {name: float(c)
-                 for name, c in zip(names, fetch(decision.costs))}
+        costs = {name: float(c) for name, c in zip(names, view.costs)}
         # the goal's per-term device-computed breakdown for ALL k forks
         # (policy -> term -> cost): downstream reports (radar areas,
         # summarize-style tables) consume this instead of recomputing
         # costs on the host from raw metrics.
-        term_arrays = {term: fetch(v)
-                       for term, v in (decision.cost_terms or {}).items()}
         term_costs = {name: {term: float(v[i])
-                             for term, v in term_arrays.items()}
+                             for term, v in view.cost_terms.items()}
                       for i, name in enumerate(names)}
         # fan/ensemble decisions carry device-computed per-policy
         # uncertainty (DESIGN.md §10); record it as-is, no host math.
         cost_ci = {}
         fan_width = {}
-        if decision.cost_ci is not None:
-            cost_ci = {name: float(c)
-                       for name, c in zip(names, fetch(decision.cost_ci))}
-        if decision.fan_width is not None:
+        if view.cost_ci is not None:
+            cost_ci = {name: float(c) for name, c in zip(names, view.cost_ci)}
+        if view.fan_width is not None:
             fan_width = {name: float(w)
-                         for name, w in zip(names,
-                                            fetch(decision.fan_width))}
+                         for name, w in zip(names, view.fan_width)}
         race_fields = {}
         if race_out is not None:
             race_fields = dict(

@@ -137,7 +137,7 @@ def decide_legacy_vmap(state: SimState, pool: jax.Array,
         run_mask=first_started[best],
         metrics=metrics,
         deadlocked=dead,
-    )
+    ).packed()
 
 
 # ----------------------------------------------------------------------
@@ -200,7 +200,7 @@ def _decide_fleet(engine: DrainEngine, mesh: Mesh, axis: str,
         metrics=metrics,
         deadlocked=res.deadlocked,
         cost_terms=objective.cost_terms(metrics),
-    )
+    ).packed()
 
 
 def sharded_whatif(mesh: Mesh, axis: str = "data",

@@ -673,7 +673,7 @@ def main() -> None:
                   f"reduction), mean {memb / len(cs):.1f}/cycle, "
                   f"stops {stops}")
     lat = twin.telemetry.cycle_latency_stats()
-    print(f"cycle latency (decide + run-mask read): p50 "
+    print(f"cycle latency (decide + its one read): p50 "
           f"{lat['p50_s'] * 1e3:.2f} ms, p95 {lat['p95_s'] * 1e3:.2f} ms, "
           f"mean {lat['mean_s'] * 1e3:.2f} ms, max "
           f"{lat['max_s'] * 1e3:.2f} ms over {lat['n']} cycles")

@@ -67,9 +67,9 @@ class ScriptedTwin:
         return dt, (cycles[-1] if len(cycles) > n0 else None)
 
 
-def _decision_reads(rec: CycleRecord) -> int:
-    """run mask, winner index, costs, and one read per goal term."""
-    return 3 + len(next(iter(rec.term_costs.values())))
+#: A decision's results reach the host in one read (``Decision.words``),
+#: whatever its goal's terms.
+DECISION_READS = 1
 
 
 #: A twin's first cycle reads its pool's families and θ once, for the
@@ -102,7 +102,7 @@ def test_every_cycle_has_all_stages_and_exact_counts():
         assert all(v >= 0.0 for v in rec.stages.values()), name
         assert sum(rec.stages.values()) <= dt, name
         assert rec.events == events, name
-        assert rec.host_reads == reads + _decision_reads(rec), name
+        assert rec.host_reads == reads + DECISION_READS, name
         assert rec.uploads == 1, name
         assert rec.n_started == started, name
         assert (rec.stages["twin.qrun"] > 0.0) == bool(started), name
@@ -113,6 +113,9 @@ def test_every_cycle_has_all_stages_and_exact_counts():
         assert rec.stages["twin.attach"] == 0.0, name
     assert e[1] is None
     assert len(s.twin.telemetry.cycles) == 4
+    steady = Telemetry()
+    steady.cycles = s.twin.telemetry.cycles[1:]
+    assert steady.cycle_latency_stats()["host_reads"] == 1.0
     # the decision span is decide + fetch, as before
     for rec in s.twin.telemetry.cycles:
         assert (rec.stages["twin.decide"] + rec.stages["twin.fetch"]
@@ -128,12 +131,12 @@ def test_one_upload_per_decision_and_no_read_before_it(n_events):
     _, first = s.pump()
     assert first.events == n_events and first.n_started >= 1
     assert telemetry._ProcessCounts.uploads - before == 1
-    assert first.host_reads == HOIST_PLAN_READS + _decision_reads(first)
+    assert first.host_reads == HOIST_PLAN_READS + DECISION_READS
     # the next pump carries the RUNJOBs the first one's qrun published
     s.publish(_queue(n_events, 100.0, nodes=1))
     _, second = s.pump()
     assert second.events == first.n_started + 1
-    assert second.host_reads == _decision_reads(second)
+    assert second.host_reads == DECISION_READS
     for rec in (first, second):
         assert rec.uploads == 1
         assert rec.stages["twin.upload"] > 0.0
@@ -159,7 +162,7 @@ def test_a_nested_entry_point_meters_its_own_cycle():
     assert outer.started_jobs == [0] and inner.started_jobs == [1]
     for rec in (outer, inner):
         assert set(rec.stages) == set(STAGES)
-        assert rec.host_reads >= _decision_reads(rec)
+        assert rec.host_reads >= DECISION_READS
     # each read is counted once; the cycle-less RUNJOBs fold into the
     # entry that ran them, and the nested cycle's time lies in qrun
     assert outer.host_reads + inner.host_reads == reads

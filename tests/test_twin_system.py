@@ -1,4 +1,6 @@
 """End-to-end system tests: the twin in the loop with the emulator."""
+import dataclasses
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -204,3 +206,88 @@ def test_an_attached_twin_decides_as_the_reference():
     assert out["judged"] == len(cycles)
     assert (out["off"], out["missing"]) == (0, 0), out
     assert out["cost_gap"] < 1e-3
+
+
+# ----------------------------------------------------------------------
+# a decision's results reach the host in one read (``Decision.words``)
+# ----------------------------------------------------------------------
+
+class FieldByFieldTwin(SchedTwin):
+    """A twin that also reads each decision's fields on their own, one
+    read each, as the record of the cycle would have them."""
+
+    def __init__(self, *args, **kw):
+        super().__init__(*args, **kw)
+        self.by_field = []
+
+    def _record_cycle(self, t, level, seconds, decision, race_out, names,
+                      source, words, ids):
+        field = lambda x: {n: float(v) for n, v in          # noqa: E731
+                           zip(names, np.asarray(x))}
+        terms = {t: np.asarray(v)
+                 for t, v in (decision.cost_terms or {}).items()}
+        self.by_field.append(dict(
+            policy=names[int(np.asarray(decision.policy_index))],
+            costs=field(decision.costs),
+            term_costs={n: {t: float(v[i]) for t, v in terms.items()}
+                        for i, n in enumerate(names)},
+            cost_ci=(field(decision.cost_ci)
+                     if decision.cost_ci is not None else {}),
+            fan_width=(field(decision.fan_width)
+                       if decision.fan_width is not None else {}),
+            started_jobs=ids[np.nonzero(
+                np.asarray(decision.run_mask))[0]].tolist()))
+        return super()._record_cycle(t, level, seconds, decision, race_out,
+                                     names, source, words, ids)
+
+
+def _same_bits(a: dict, b: dict) -> bool:
+    return (list(a) == list(b)
+            and all(np.float32(a[k]).view(np.int32)
+                    == np.float32(b[k]).view(np.int32) for k in a))
+
+
+def _fan_modes():
+    from repro.core.fan import FanSpec
+    from repro.core.race import RaceSpec
+    fan = FanSpec(n=4, runtime_noise=0.3, seed=1)
+    return {"fan": {"fan": fan}, "ensemble": {"ensemble": 3},
+            "race": {"race": RaceSpec(fan=dataclasses.replace(fan, n=8),
+                                      f0=2)}}
+
+
+@pytest.mark.parametrize("trace,mode", [
+    ("paper0", "plain"), ("paper1", "plain"), ("paper2", "plain"),
+    ("tiny", "fan"), ("tiny", "ensemble"), ("tiny", "race")])
+def test_every_cycle_record_is_its_decision_read_field_by_field(trace,
+                                                                mode):
+    """Three paper traces, 32 nodes, a 256-slot table (and a tiny trace
+    under each fan mode): each cycle's record, built from the one read
+    of its decision's words, is what reading every field of the same
+    decision on its own gives."""
+    if trace == "tiny":
+        jobs, nodes, least = tiny_trace(16, seed=7), 16, 16
+        kw = _fan_modes()[mode]
+    else:
+        jobs = paper_synthetic_trace(seed=int(trace[-1]))
+        nodes, least = 32, 290
+        kw = {}
+    bus = EventBus()
+    em = ClusterEmulator(jobs, nodes, bus=bus, check_invariants=True)
+    twin = FieldByFieldTwin(bus=bus, qrun=em.qrun, total_nodes=nodes,
+                            max_jobs=256,
+                            free_nodes_probe=lambda: em.free_nodes, **kw)
+    em.run(on_event=twin.pump)
+    cycles = twin.telemetry.cycles
+    assert len(cycles) == len(twin.by_field) >= least
+    for c, want in zip(cycles, twin.by_field):
+        assert c.policy == want["policy"]
+        assert c.started_jobs == want["started_jobs"]
+        assert _same_bits(c.costs, want["costs"])
+        assert c.term_costs.keys() == want["term_costs"].keys()
+        for name in c.term_costs:
+            assert len(c.term_costs[name]) == 4
+            assert _same_bits(c.term_costs[name], want["term_costs"][name])
+        assert _same_bits(c.cost_ci, want["cost_ci"])
+        assert _same_bits(c.fan_width, want["fan_width"])
+        assert bool(c.cost_ci) == (mode != "plain")
