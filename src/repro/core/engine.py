@@ -29,19 +29,27 @@ Backends (registered in ``PASS_BACKENDS``):
     VMEM.  Compiled on a TPU, interpret mode elsewhere (the CPU test
     runs); ``interpret=None`` resolves from the platform.
 
-Every consumer routes through here: ``whatif.decide`` /
-``decide_ensemble`` (ensemble members ride the same batch axis —
-k * n_ens forks in one drain), ``whatif.sharded_whatif`` (shards the
-fork axis), ``SchedTwin`` (engine injected at construction) and the
-cluster emulator's static mode (a k=1 engine, so baselines stay
-bit-identical to the twin's simulator).
+Every decision is one program (DESIGN.md §1): ``_members`` broadcasts
+the snapshot to ``W·k`` forks (fork ``f = w·k + p``, member w's
+estimates and capacity from a member source, member 0 exact), drains
+them once (here, or per shard under a ``(mesh, axis)`` placement), and
+``_decision`` reduces the members to a packed ``Decision``.  The
+sources: none (``decide``, the nominal program the twin runs), a fan
+window (``decide_fan`` over ``[0, F)``, ``race.decide_race``'s rungs
+over later windows) and the ensemble's estimate noise
+(``decide_ensemble``); ``whatif.sharded_whatif`` is the nominal decide
+with a placement.  ``SchedTwin`` gets its engine at construction, and
+the cluster emulator's static mode runs a k=1 engine, so baselines
+stay bit-identical to the twin's simulator.
 
 The engine also hosts the **scenario-vectorized replay** (DESIGN.md
 §6): ``replay`` / ``replay_grid`` drive ``des.simulate_replay_batched``
 over a ``workload.ScenarioSet``, stacking an S-scenario axis on top of
 the P-policy fork axis (flat fork f = s·P + p) — a whole baseline grid
 in one device computation, bit-identical to the host emulator's event
-loop, sharded by scenario via ``whatif.sharded_replay_grid``.
+loop, sharded by scenario via ``whatif.sharded_replay_grid``.  A
+Monte-Carlo fan (§10) is the member window ``[0, F)`` of
+``fan_window_grid``.
 """
 from __future__ import annotations
 
@@ -54,13 +62,14 @@ from typing import Callable, Dict, NamedTuple, Optional, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec
 
 from repro.core import scoring
 from repro.core.backfill import (priority_order,
                                  schedule_pass_with_order,
                                  static_priority_order)
-from repro.core.fan import (FanSpec, normalize_fan, perturb_block,
-                            perturb_window)
+from repro.core.fan import (FanSpec, _member_draws, failure_downs,
+                            lognormal_noise, normalize_fan, perturb_window)
 from repro.core.objective import (DEFAULT_OBJECTIVE, Objective,
                                   ObjectiveLike, as_distributional,
                                   resolve_goal)
@@ -72,6 +81,7 @@ from repro.core.policies import PolicySpec, time_invariant_mask
 from repro.core.state import (QUEUED, RUNNING, TIME_NONE, JobTable,
                               SimState)
 from repro.kernels import policy_eval as _pe
+from repro.launch.mesh import shard_map
 
 logger = logging.getLogger(__name__)
 
@@ -430,6 +440,25 @@ def hoisted_orders(states0: SimState, pool: EnginePool, plan: HoistPlan,
             states_ti, _index_pool(pool, ti_idx), ever_queued[ti_idx])
 
 
+def on_shards(body: Callable, mesh, axis: str, plan: HoistPlan,
+              ever_queued: jax.Array, states: SimState, pool: EnginePool,
+              *operands):
+    """``body(states, pool, *operands, hoisted=None)`` run per shard of
+    the fork axis under ``shard_map`` (DESIGN.md §9): every argument
+    splits on its leading axis over ``axis`` of ``mesh``, and ``plan``
+    is the shard-local hoist plan.  The hoisted static argsorts are
+    computed HERE, outside the body, and cross the shard boundary as a
+    sharded input (see ``hoisted_orders``): ``plan`` tiled once per
+    shard is the global plan, so each device gets its own rows."""
+    args = (states, pool, *operands)
+    if plan is not None:
+        args += (hoisted_orders(states, pool, plan * mesh.shape[axis],
+                                ever_queued),)
+    spec = PartitionSpec(axis)
+    return shard_map(body, mesh, in_specs=(spec,) * len(args),
+                     out_specs=spec)(*args)
+
+
 def make_order_fn(states0: SimState, pool: EnginePool, plan: HoistPlan,
                   ever_queued: jax.Array,
                   hoisted: Optional[jax.Array] = None,
@@ -616,13 +645,9 @@ class DrainEngine:
         if S != 1:
             raise ValueError(
                 f"replay takes one scenario (got {S}); use replay_grid")
-        goal = resolve_goal(objective, weights)
-        pool = as_pool(pool)
-        P = pool_size(pool)
-        inputs = replay_inputs(scenario, pool)
-        res, metrics, costs, best = _replay(self, *inputs, self.plan(pool),
-                                            goal, P)
-        return _shape_outcome(res, metrics, (P,), costs, best)
+        args = self._grid_args(scenario, pool, objective, weights)
+        res, metrics, costs, best = _replay(*args)
+        return _shape_outcome(res, metrics, (args[-1],), costs, best)
 
     def replay_grid(self, scenarios, pool, objective: ObjectiveLike = None,
                     *, weights: Optional[scoring.ScoreWeights] = None
@@ -668,31 +693,8 @@ class DrainEngine:
         ``objective`` selects per scenario after the distributional
         reduction over the fan axis.  ``FanSpec(n=1)`` (and any
         degenerate spec) is bitwise ``replay_grid``."""
-        goal = resolve_goal(objective, weights)
-        spec = normalize_fan(fan)
-        pool = as_pool(pool)
-        S = int(scenarios.total_nodes.shape[0])
-        P = pool_size(pool)
-        plan = self.plan(pool)                 # fork f = (s·F + φ)·P + p
-        res, metrics, member, costs, best, ci, width = _fan_replay(
-            self, *_scenario_arrays(scenarios), pool,
-            plan * (S * spec.n) if plan is not None else None,
-            goal, P, S, spec)
-        shape = (S, spec.n, P)
-        rs = lambda x: x.reshape(shape + x.shape[1:])
-        return FanOutcome(
-            start_t=rs(res.state.jobs.start_t),
-            end_t=rs(res.state.jobs.end_t),
-            metrics=jax.tree.map(rs, metrics),
-            deadlocked=rs(res.deadlocked),
-            events=rs(res.events),
-            result=res,
-            member_costs=member,
-            costs=costs,
-            best=best,
-            cost_ci=ci,
-            fan_width=width,
-        )
+        return self.fan_window_grid(scenarios, pool, fan, objective,
+                                    weights=weights)
 
     def fan_window_grid(self, scenarios, pool, fan,
                         objective: ObjectiveLike = None, *,
@@ -701,7 +703,7 @@ class DrainEngine:
                         ) -> FanOutcome:
         """Replay ONLY members ``φ ∈ [lo, lo+width)`` of the fan — the
         racing/donation suffix.  CRN prefix-stability (``fan.
-        perturb_rows`` keys on (s, φ) alone) makes every returned
+        perturb_window`` keys on (s, φ) alone) makes every returned
         member bitwise the corresponding member of the full
         ``fan_grid``, so windows replayed at different times
         concatenate into the full fan without ever re-replaying a
@@ -721,25 +723,11 @@ class DrainEngine:
         S = int(scenarios.total_nodes.shape[0])
         P = pool_size(pool)
         plan = self.plan(pool)              # fork f = (s·width + w)·P + p
-        res, metrics, member, costs, best, ci, cwidth = _fan_window_replay(
+        res, metrics, selected = _fan_window_replay(
             self, *_scenario_arrays(scenarios), pool,
             plan * (S * width) if plan is not None else None,
             goal, P, S, spec, lo, width)
-        shape = (S, width, P)
-        rs = lambda x: x.reshape(shape + x.shape[1:])
-        return FanOutcome(
-            start_t=rs(res.state.jobs.start_t),
-            end_t=rs(res.state.jobs.end_t),
-            metrics=jax.tree.map(rs, metrics),
-            deadlocked=rs(res.deadlocked),
-            events=rs(res.events),
-            result=res,
-            member_costs=member,
-            costs=costs,
-            best=best,
-            cost_ci=ci,
-            fan_width=cwidth,
-        )
+        return _fan_outcome(res, metrics, (S, width, P), selected)
 
     # -- population training (DESIGN.md §13) ---------------------------
     def generation_costs(self, scenarios, pool,
@@ -805,32 +793,128 @@ def _drain(engine: DrainEngine, states: SimState,
     return _drain_impl(engine, states, pool, plan)
 
 
-def _decide_impl(engine: DrainEngine, state: SimState, pool: EnginePool,
-                 objective: Objective = DEFAULT_OBJECTIVE,
-                 plan: HoistPlan = None) -> Decision:
+class Members(NamedTuple):
+    """What a decision's forks drained to.  With a member axis (fan
+    and ensemble decisions) every field leads with (W, k), fork
+    ``f = w·k + p``; the nominal decide has no member axis, (k,)."""
+    metrics: DrainMetrics     # per fork, over the snapshot's queued jobs
+    deadlocked: jax.Array     # bool
+    costs: jax.Array          # the goal's cost per fork, +inf if deadlocked
+    first0: jax.Array         # bool (k, J) member 0's first-started rows
+
+
+def _fan_draws(state: SimState, spec: FanSpec, lo: int, width: int):
+    """Members ``φ ∈ [lo, lo+width)`` of a decision's fan: their (W, J)
+    estimates and (W,) capacity losses, None where ``spec`` draws
+    none.  The draws are the replay fan's (``fan._member_draws`` and
+    ``fan.failure_downs`` at s=0: a decision has one base snapshot),
+    keyed on (seed, φ) alone, so any window is bitwise those members
+    of the whole fan.  ``runtime_noise`` scales the walltime
+    ESTIMATES (a drain's predicted ends: what the twin is unsure of);
+    arrival warps have no drain-side meaning.  Member 0 is exact."""
+    if spec.degenerate:
+        return None, None
+    cap = state.jobs.capacity
+    phi = lo + jnp.arange(width)
+    eps, _, u = jax.vmap(
+        lambda p: _member_draws(spec.seed, jnp.int32(0), p, cap))(phi)
+    est = down = None
+    if spec.runtime_noise > 0.0:
+        est = lognormal_noise(state.jobs.est_runtime[None, :], eps,
+                              spec.runtime_noise, phi == 0)
+    if spec.failure_prob > 0.0:
+        down = failure_downs(spec, jnp.zeros_like(phi), phi, u,
+                             jnp.broadcast_to(state.total_nodes, (width,)))
+    return est, down
+
+
+def _members(engine: DrainEngine, state: SimState, pool: EnginePool,
+             objective: Objective, plan: HoistPlan,
+             width: Optional[int] = None, est: Optional[jax.Array] = None,
+             down: Optional[jax.Array] = None, placement=None) -> Members:
+    """The one program that builds and drains a decision's forks.
+
+    The snapshot is broadcast to ``W·k`` forks, fork ``f = w·k + p``
+    running policy p under member w, whose estimates are row w of
+    ``est`` (W, J) and whose capacity is down by ``down[w]`` nodes
+    (None: unperturbed).  ``width=None`` is the nominal decide: one
+    future and no member axis.  All forks drain in one lock-step loop,
+    here or under ``placement``; the goal's cost is evaluated per
+    member, so rank-based goals rank within a member."""
     k = pool_size(pool)
     eval_mask = state.jobs.state == QUEUED
-    res = _drain_impl(engine, broadcast_state(state, k), pool, plan)
+    states = broadcast_state(state, (width or 1) * k)
+    if est is not None:
+        states = states._replace(jobs=states.jobs._replace(
+            est_runtime=jnp.repeat(est, k, axis=0)))
+    if down is not None:
+        down = jnp.repeat(down, k)
+        states = states._replace(
+            free_nodes=jnp.maximum(states.free_nodes - down, 0),
+            total_nodes=jnp.maximum(states.total_nodes - down, 1))
+    if width is not None:
+        pool = tile_pool(pool, width)
+        plan = plan * width if plan is not None else None
+    if placement is None:
+        res = _drain_impl(engine, states, pool, plan)
+    else:                       # per shard of the fork axis (§9)
+        mesh, axis = placement
+        local = shard_local_plan(plan, mesh.shape[axis])
+        res = on_shards(
+            lambda st, pl, hoisted=None: _drain_impl(
+                engine, st, pl, local, hoisted=hoisted),
+            mesh, axis, local, states.jobs.state == QUEUED, states, pool)
     with jax.named_scope("select"):
         metrics = jax.vmap(drain_metrics, in_axes=(0, None))(res, eval_mask)
-        costs = objective.costs(metrics)
-        costs = jnp.where(res.deadlocked, jnp.inf, costs)
+        dead, first0 = res.deadlocked, res.first_started
+        if width is not None:
+            metrics, dead = jax.tree.map(
+                lambda x: x.reshape(width, k), (metrics, dead))
+            first0 = first0[:k]
+        costs = jnp.where(dead, jnp.inf, objective.costs(metrics))
+        return Members(metrics, dead, costs, first0)
+
+
+def _decision(objective: Objective, m: Members,
+              mean_cost: bool = False) -> Decision:
+    """The one reduction from members to a packed ``Decision``.  Over a
+    member axis the policy cost is the goal's distributional reduction
+    of the member costs (``mean_cost``: the cost of the members' mean
+    metrics instead, the ensemble's rule), any deadlocked member marks
+    its policy deadlocked, and the decision carries the members'
+    ``member_uncertainty``.  The qrun set is member 0's, the winner's."""
+    with jax.named_scope("select"):
+        metrics, dead, costs = m.metrics, m.deadlocked, m.costs
+        ci = spread = None
+        if dead.ndim == 2:
+            metrics = jax.tree.map(lambda x: jnp.mean(x, axis=0), metrics)
+            dead = jnp.any(dead, axis=0)
+            if mean_cost:
+                costs = jnp.where(dead, jnp.inf, objective.costs(metrics))
+            else:
+                costs = as_distributional(objective).reduce_fan(m.costs)
+            ci, spread = member_uncertainty(m.costs, axis=0)
         best = scoring.select_policy(costs)
         return Decision(
             policy_index=best,
             costs=costs,
-            run_mask=res.first_started[best],
+            run_mask=m.first0[best],
             metrics=metrics,
-            deadlocked=res.deadlocked,
+            deadlocked=dead,
             cost_terms=objective.cost_terms(metrics),
+            cost_ci=ci,
+            fan_width=spread,
         ).packed()
 
 
-@functools.partial(jax.jit, static_argnames=("engine", "objective", "plan"))
+@functools.partial(jax.jit,
+                   static_argnames=("engine", "objective", "plan",
+                                    "placement"))
 def _decide(engine: DrainEngine, state: SimState, pool: EnginePool,
             objective: Objective = DEFAULT_OBJECTIVE,
-            plan: HoistPlan = None) -> Decision:
-    return _decide_impl(engine, state, pool, objective, plan)
+            plan: HoistPlan = None, placement=None) -> Decision:
+    return _decision(objective, _members(engine, state, pool, objective,
+                                         plan, placement=placement))
 
 
 @functools.partial(jax.jit,
@@ -840,54 +924,30 @@ def _decide_ensemble(engine: DrainEngine, state: SimState, pool: EnginePool,
                      key: jax.Array, n_ens: int, noise: float,
                      objective: Objective = DEFAULT_OBJECTIVE,
                      plan: HoistPlan = None) -> Decision:
-    """k * n_ens forks ride ONE batch axis through ONE drain.
+    """The ensemble's member source: member e's estimates under
+    lognormal noise from ``key``'s normals, member 0 exact (so the
+    qrun set is the mirror's); the policy cost is the cost of the
+    members' mean metrics."""
+    eps = jax.random.normal(key, (n_ens, state.jobs.capacity))
+    est = lognormal_noise(state.jobs.est_runtime[None, :], eps, noise,
+                          jnp.arange(n_ens) == 0)
+    m = _members(engine, state, pool, objective, plan, n_ens, est=est)
+    return _decision(objective, m, mean_cost=True)
 
-    Fork f = e * k + p simulates policy ``pool[p]`` under ensemble
-    member e's lognormal walltime-estimate perturbation (member 0 is
-    exact, so actions stay consistent with the mirror).  The policy
-    cost is the ensemble mean; the qrun set comes from member 0 of the
-    winning policy.
-    """
-    k = pool_size(pool)
-    cap = state.jobs.capacity
 
-    eps = jax.random.normal(key, (n_ens, cap))
-    eps = eps.at[0].set(0.0)
-    scale = jnp.exp(noise * eps - 0.5 * noise * noise)       # (n_ens, J)
-    est_b = jnp.repeat(scale, k, axis=0) * state.jobs.est_runtime[None, :]
-
-    states = broadcast_state(state, n_ens * k)
-    states = states._replace(jobs=states.jobs._replace(est_runtime=est_b))
-    pool_b = tile_pool(pool, n_ens)
-    plan_b = plan * n_ens if plan is not None else None
-
-    eval_mask = state.jobs.state == QUEUED
-    res = _drain_impl(engine, states, pool_b, plan_b)
-    metrics = jax.vmap(drain_metrics, in_axes=(0, None))(res, eval_mask)
-    mean_metrics = jax.tree.map(
-        lambda x: jnp.mean(x.reshape(n_ens, k), axis=0), metrics)
-    member_dead = res.deadlocked.reshape(n_ens, k)
-    dead = jnp.any(member_dead, axis=0)
-    costs = objective.costs(mean_metrics)
-    costs = jnp.where(dead, jnp.inf, costs)
-    best = scoring.select_policy(costs)
-    # Per-member costs back the CI/width stamps only — selection stays
-    # the cost of the MEAN metrics, bit-identical to the pre-fan path.
-    member_costs = jnp.where(
-        member_dead, jnp.inf,
-        objective.costs(jax.tree.map(
-            lambda x: x.reshape(n_ens, k), metrics)))
-    ci, width = member_uncertainty(member_costs, axis=0)
-    return Decision(
-        policy_index=best,
-        costs=costs,
-        run_mask=res.first_started.reshape(n_ens, k, cap)[0, best],
-        metrics=mean_metrics,
-        deadlocked=dead,
-        cost_terms=objective.cost_terms(mean_metrics),
-        cost_ci=ci,
-        fan_width=width,
-    ).packed()
+@functools.partial(jax.jit,
+                   static_argnames=("engine", "spec", "objective", "plan",
+                                    "lo", "width"))
+def _fan_members(engine: DrainEngine, state: SimState, pool: EnginePool,
+                 spec: FanSpec, objective: Objective, plan: HoistPlan,
+                 lo: int, width: int) -> Members:
+    """Members ``φ ∈ [lo, lo+width)`` of a decision's fan (fork
+    ``f = w·k + p``, member ``φ = lo + w``): a racing rung
+    (``race.decide_race``), or with ``[0, F)`` the whole fan.  The
+    per-member costs are the goal's inner costs; reduction happens in
+    the caller."""
+    est, down = _fan_draws(state, spec, lo, width)
+    return _members(engine, state, pool, objective, plan, width, est, down)
 
 
 @functools.partial(jax.jit,
@@ -896,75 +956,8 @@ def _decide_fan(engine: DrainEngine, state: SimState, pool: EnginePool,
                 spec: FanSpec = FanSpec(),
                 objective: Objective = DEFAULT_OBJECTIVE,
                 plan: HoistPlan = None) -> Decision:
-    """k · F forks ride ONE batch axis through ONE drain (fork
-    f = φ·k + p, the ``_decide_ensemble`` layout).  Member φ's draws
-    come from the same ``fan._member_draws`` chains as the replay fan
-    (s=0: a decision has one base snapshot), so fans are deterministic
-    and prefix-stable here too.  Perturbations with a drain-side
-    meaning: ``runtime_noise`` scales the walltime ESTIMATES (the
-    drain's predicted ends — what the twin is unsure about) and
-    ``failure_prob`` draws capacity reductions; arrival warps are
-    no-ops (drains simulate no future arrivals).  Member 0 is exact.
-    Selection is the goal's distributional reduction of the per-member
-    costs; deadlocked members cost +inf (a policy whose tail deadlocks
-    is exactly as bad as the reduction is risk-averse)."""
-    from repro.core.fan import _member_draws, failure_downs
-    k = pool_size(pool)
-    cap = state.jobs.capacity
-    F = spec.n
-    dist = as_distributional(objective)
-
-    states = broadcast_state(state, F * k)
-    if not spec.degenerate:
-        phi = jnp.arange(F)
-        eps, _, u = jax.vmap(
-            lambda p: _member_draws(spec.seed, jnp.int32(0), p, cap))(phi)
-        exact = phi == 0
-        if spec.runtime_noise > 0.0:
-            sig = spec.runtime_noise
-            scale = jnp.exp(sig * eps - 0.5 * sig * sig)     # (F, J)
-            est = state.jobs.est_runtime[None, :]
-            est_m = jnp.where(exact[:, None], est, est * scale)
-            states = states._replace(jobs=states.jobs._replace(
-                est_runtime=jnp.repeat(est_m, k, axis=0)))
-        if spec.failure_prob > 0.0:
-            tot = states.total_nodes                          # (F·k,)
-            # one shared implementation with the replay-side fan
-            # (fan.failure_downs): same i.i.d. draws bitwise, same
-            # correlated rack/power-domain model when failure_domains>0
-            # (s=0 — a decision has one base snapshot)
-            down = failure_downs(
-                spec, jnp.zeros_like(phi), phi, u,
-                jnp.broadcast_to(state.total_nodes, (F,)))
-            down_b = jnp.repeat(down, k)
-            states = states._replace(
-                free_nodes=jnp.maximum(states.free_nodes - down_b, 0),
-                total_nodes=jnp.maximum(tot - down_b, 1))
-
-    pool_b = tile_pool(pool, F)
-    plan_b = plan * F if plan is not None else None
-    eval_mask = state.jobs.state == QUEUED
-    res = _drain_impl(engine, states, pool_b, plan_b)
-    metrics = jax.vmap(drain_metrics, in_axes=(0, None))(res, eval_mask)
-    member_metrics = jax.tree.map(lambda x: x.reshape(F, k), metrics)
-    member_dead = res.deadlocked.reshape(F, k)
-    member_costs = jnp.where(member_dead, jnp.inf,
-                             dist.member_costs(member_metrics))
-    costs = dist.reduce_fan(member_costs)                    # (k,)
-    best = scoring.select_policy(costs)
-    ci, width = member_uncertainty(member_costs, axis=0)
-    mean_metrics = jax.tree.map(lambda x: jnp.mean(x, axis=0),
-                                member_metrics)
-    return Decision(
-        policy_index=best,
-        costs=costs,
-        run_mask=res.first_started.reshape(F, k, cap)[0, best],
-        metrics=mean_metrics,
-        deadlocked=jnp.any(member_dead, axis=0),
-        cost_terms=dist.cost_terms(mean_metrics),
-        cost_ci=ci,
-        fan_width=width,
-    ).packed()
+    return _decision(objective, _fan_members(engine, state, pool, spec,
+                                             objective, plan, 0, spec.n))
 
 
 # ----------------------------------------------------------------------
@@ -1161,30 +1154,6 @@ def _replay(engine: DrainEngine, states: SimState, arrival_t: jax.Array,
 
 @functools.partial(jax.jit,
                    static_argnames=("engine", "plan", "objective", "P",
-                                    "S", "spec"))
-def _fan_replay(engine: DrainEngine, submit, nodes, est, true_rt, valid,
-                totals, pool: EnginePool, plan: HoistPlan = None,
-                objective: Objective = DEFAULT_OBJECTIVE, P: int = 1,
-                S: int = 1, spec: FanSpec = FanSpec()):
-    """The fused fan: perturbation expansion + (S·F·P)-fork replay +
-    distributional selection in ONE compiled computation.  Only the
-    UNTILED base (S, J) arrays cross host->device — H2D is O(1) in F —
-    and every expanded buffer is born inside the jit, so XLA reuses it
-    in place without donation bookkeeping."""
-    g = jnp.arange(S * spec.n)
-    rows = perturb_block(submit, nodes, est, true_rt, valid, totals,
-                         spec, g, S)
-    states, arrival_t, true_rep, pool_t, valid_rep = \
-        _assemble_replay_inputs(*rows, pool, P)
-    res, metrics = _replay_impl(engine, states, arrival_t, true_rep,
-                                pool_t, valid_rep, plan)
-    member, costs, best, ci, width = fan_select(
-        objective, metrics, res.deadlocked, spec.n, P)
-    return res, metrics, member, costs, best, ci, width
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("engine", "plan", "objective", "P",
                                     "S", "spec", "lo", "width"))
 def _fan_window_replay(engine: DrainEngine, submit, nodes, est, true_rt,
                        valid, totals, pool: EnginePool,
@@ -1192,12 +1161,16 @@ def _fan_window_replay(engine: DrainEngine, submit, nodes, est, true_rt,
                        objective: Objective = DEFAULT_OBJECTIVE,
                        P: int = 1, S: int = 1, spec: FanSpec = FanSpec(),
                        lo: int = 0, width: int = 1):
-    """``_fan_replay`` restricted to members ``φ ∈ [lo, lo+width)`` —
-    the racing-rung suffix.  Row ``r = s·width + w`` is member
-    ``lo + w`` of scenario s (fork ``f = r·P + p``); the per-member
-    draws key on (seed, s, φ) alone, so each row is bitwise the
-    ``s·F + φ`` row of the full fan.  ``lo``/``width`` are static —
-    the rung schedule is fixed, so each rung shape compiles once."""
+    """The fused fan window: perturbation expansion + replay +
+    distributional selection in ONE compiled computation, over members
+    ``φ ∈ [lo, lo+width)`` (the whole fan is ``[0, F)``).  Row
+    ``r = s·width + w`` is member ``lo + w`` of scenario s (fork
+    ``f = r·P + p``); the per-member draws key on (seed, s, φ) alone,
+    so each row is bitwise the ``s·F + φ`` row of the full fan.  Only
+    the UNTILED base (S, J) arrays cross host->device (H2D is O(1) in
+    F), and every expanded buffer is born inside the jit.  ``lo`` and
+    ``width`` are static: a rung schedule is fixed, so each rung shape
+    compiles once."""
     r = jnp.arange(S * width)
     rows = perturb_window(submit, nodes, est, true_rt, valid, totals,
                           spec, r, lo, width, S)
@@ -1205,69 +1178,29 @@ def _fan_window_replay(engine: DrainEngine, submit, nodes, est, true_rt,
         _assemble_replay_inputs(*rows, pool, P)
     res, metrics = _replay_impl(engine, states, arrival_t, true_rep,
                                 pool_t, valid_rep, plan)
-    member, costs, best, ci, cwidth = fan_select(
-        objective, metrics, res.deadlocked, width, P)
-    return res, metrics, member, costs, best, ci, cwidth
+    return res, metrics, fan_select(objective, metrics, res.deadlocked,
+                                    width, P)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("engine", "spec", "objective", "plan",
-                                    "lo", "width"))
-def _decide_fan_window(engine: DrainEngine, state: SimState,
-                       pool: EnginePool, spec: FanSpec = FanSpec(),
-                       objective: Objective = DEFAULT_OBJECTIVE,
-                       plan: HoistPlan = None, lo: int = 0,
-                       width: int = 1):
-    """``_decide_fan`` restricted to members ``φ ∈ [lo, lo+width)`` —
-    the drain-side racing rung (fork ``f = w·k + p``, member
-    ``φ = lo + w``).  Same (seed, φ) draw chains as ``_decide_fan``,
-    so window members are bitwise the full fan's members and rungs
-    concatenate without replaying a member twice.  Returns per-member
-    pieces (costs, deadlocks, metrics, member-0 first-started) for the
-    host-side race controller to accumulate — selection over the
-    concatenated members happens in ``race.rung_stats``."""
-    from repro.core.fan import _member_draws, failure_downs
-    k = pool_size(pool)
-    cap = state.jobs.capacity
-    dist = as_distributional(objective)
-    phi = lo + jnp.arange(width)
-
-    states = broadcast_state(state, width * k)
-    if not spec.degenerate:
-        eps, _, u = jax.vmap(
-            lambda p: _member_draws(spec.seed, jnp.int32(0), p, cap))(phi)
-        exact = phi == 0
-        if spec.runtime_noise > 0.0:
-            sig = spec.runtime_noise
-            scale = jnp.exp(sig * eps - 0.5 * sig * sig)     # (W, J)
-            est = state.jobs.est_runtime[None, :]
-            est_m = jnp.where(exact[:, None], est, est * scale)
-            states = states._replace(jobs=states.jobs._replace(
-                est_runtime=jnp.repeat(est_m, k, axis=0)))
-        if spec.failure_prob > 0.0:
-            tot = states.total_nodes                          # (W·k,)
-            # shared with fan.perturb_rows / _decide_fan: bitwise the
-            # full fan's member draws (CRN window contract) under both
-            # the i.i.d. and the correlated-domain model
-            down = failure_downs(
-                spec, jnp.zeros_like(phi), phi, u,
-                jnp.broadcast_to(state.total_nodes, (width,)))
-            down_b = jnp.repeat(down, k)
-            states = states._replace(
-                free_nodes=jnp.maximum(states.free_nodes - down_b, 0),
-                total_nodes=jnp.maximum(tot - down_b, 1))
-
-    pool_b = tile_pool(pool, width)
-    plan_b = plan * width if plan is not None else None
-    eval_mask = state.jobs.state == QUEUED
-    res = _drain_impl(engine, states, pool_b, plan_b)
-    metrics = jax.vmap(drain_metrics, in_axes=(0, None))(res, eval_mask)
-    member_metrics = jax.tree.map(lambda x: x.reshape(width, k), metrics)
-    member_dead = res.deadlocked.reshape(width, k)
-    member_costs = jnp.where(member_dead, jnp.inf,
-                             dist.member_costs(member_metrics))
-    first0 = res.first_started.reshape(width, k, cap)[0]
-    return member_costs, member_dead, member_metrics, first0
+def _fan_outcome(res: ReplayResult, metrics: DrainMetrics, shape,
+                 selected) -> FanOutcome:
+    """A flat (k = S·F·P) fan replay and its ``fan_select`` as an
+    (S, F, P) ``FanOutcome``."""
+    member, costs, best, ci, width = selected
+    rs = lambda x: x.reshape(shape + x.shape[1:])
+    return FanOutcome(
+        start_t=rs(res.state.jobs.start_t),
+        end_t=rs(res.state.jobs.end_t),
+        metrics=jax.tree.map(rs, metrics),
+        deadlocked=rs(res.deadlocked),
+        events=rs(res.events),
+        result=res,
+        member_costs=member,
+        costs=costs,
+        best=best,
+        cost_ci=ci,
+        fan_width=width,
+    )
 
 
 def _shape_outcome(res: ReplayResult, metrics: DrainMetrics, shape,

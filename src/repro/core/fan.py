@@ -81,7 +81,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import NamedTuple, Optional, Tuple
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -90,8 +90,8 @@ import numpy as np
 from repro.core.telemetry import fetch
 
 __all__ = [
-    "FanSpec", "PruneInfo", "perturb_block", "perturb_rows",
-    "perturb_window", "materialize_fan", "dominance_keep",
+    "FanSpec", "PruneInfo", "perturb_window", "lognormal_noise",
+    "materialize_fan", "dominance_keep",
     "pruned_fan_grid", "normalize_fan", "fit_runtime_sigma",
     "failure_downs",
 ]
@@ -223,8 +223,8 @@ def _domain_fragility(seed: int, s: jax.Array, D: int) -> jax.Array:
 def failure_downs(spec: FanSpec, s: jax.Array, phi: jax.Array,
                   u: jax.Array, tot: jax.Array) -> jax.Array:
     """Per-row node-capacity reductions of the failure model — the ONE
-    implementation shared by ``perturb_rows`` (replay-side fans) and
-    the drain-side ``engine._decide_fan``, so both fan surfaces agree
+    implementation shared by ``perturb_window`` (replay-side fans) and
+    the drain-side ``engine._fan_draws``, so both fan surfaces agree
     on the correlation structure.
 
     ``u`` is the (B, 2) member uniform pair from ``_member_draws``;
@@ -256,16 +256,39 @@ def failure_downs(spec: FanSpec, s: jax.Array, phi: jax.Array,
     return down.astype(tot.dtype)
 
 
-def perturb_rows(submit, nodes, est, true_rt, valid, totals,
-                 spec: FanSpec, s: jax.Array, phi: jax.Array,
-                 inert: jax.Array):
-    """Perturb explicit (scenario, member) row vectors — the shared
-    core of ``perturb_block`` (contiguous fans, ``φ = g mod F``) and
-    ``perturb_window`` (member windows ``φ ∈ [lo, hi)``, the racing
-    suffix replays).  Perturbations depend ONLY on ``(spec.seed, s,
-    φ)`` — never on how the rows were batched — which is the CRN
-    prefix-stability contract the donation/racing paths rely on: a row
-    built here is bitwise the same row of the full fan."""
+def lognormal_noise(x: jax.Array, eps: jax.Array, sigma: float,
+                    exact: jax.Array) -> jax.Array:
+    """Rows of ``x`` (B, J), or one row (1, J) broadcast to B, under
+    mean-preserving lognormal noise ``exp(σ·ε − σ²/2)`` from the (B, J)
+    normals ``eps``; rows where ``exact`` holds are left as they are
+    (``jnp.where``, not arithmetic, so bitwise)."""
+    scale = jnp.exp(sigma * eps - 0.5 * sigma * sigma)
+    return jnp.where(exact[:, None], x, x * scale)
+
+
+def perturb_window(submit, nodes, est, true_rt, valid, totals,
+                   spec: FanSpec, r: jax.Array, lo, width: int, S: int):
+    """Expand base (S, J) scenario arrays into the perturbed
+    pseudo-scenarios of members ``φ ∈ [lo, lo+width)`` — pure device
+    code, called INSIDE the fan jits.  The whole fan is the window
+    ``[0, F)``; a racing rung or a pruning suffix is a later window.
+
+    Row ``r = s·width + w`` is member ``φ = lo + w`` of scenario s.
+    Perturbations depend ONLY on ``(spec.seed, s, φ)``, never on how
+    the rows were batched: the CRN prefix-stability contract the
+    donation/racing paths rely on, each row bitwise the row ``s·F + φ``
+    of the full fan.  ``lo`` may be a traced scalar.  Ids past
+    ``S·width`` become INERT rows (valid all-False, ``total_nodes=1``,
+    the ``pad_scenarios`` convention), so the fleet streamer can pad
+    its last block.  Member φ=0 selects the unperturbed base bitwise
+    (``jnp.where``, not arithmetic), and each model is gated on a
+    static Python ``if`` — a degenerate spec compiles to the plain
+    gather, which is how F=1 parity with ``replay_grid`` is bit-exact.
+    """
+    inert = r >= S * width
+    rc = jnp.minimum(r, S * width - 1)
+    s, w = rc // width, rc % width
+    phi = lo + w
     sub = submit[s]
     nod = nodes[s]
     es = est[s]
@@ -279,9 +302,7 @@ def perturb_rows(submit, nodes, est, true_rt, valid, totals,
             functools.partial(_member_draws, spec.seed, J=J))(s, phi)
         exact = phi == 0
         if spec.runtime_noise > 0.0:
-            sig = spec.runtime_noise
-            scale = jnp.exp(sig * eps - 0.5 * sig * sig)
-            tr = jnp.where(exact[:, None], tr, tr * scale)
+            tr = lognormal_noise(tr, eps, spec.runtime_noise, exact)
         if spec.burst_amplitude > 0.0:
             omega = 2.0 * np.pi / spec.burst_period
             amp = spec.burst_amplitude / omega
@@ -302,42 +323,6 @@ def perturb_rows(submit, nodes, est, true_rt, valid, totals,
     return sub, nod, es, tr, val, tot
 
 
-def perturb_block(submit, nodes, est, true_rt, valid, totals,
-                  spec: FanSpec, g: jax.Array, S: int):
-    """Expand base (S, J) scenario arrays into a block of perturbed
-    pseudo-scenarios — pure device code, called INSIDE the fan jits.
-
-    ``g`` is the (G,) pseudo-scenario id vector (``g = s·F + φ``); ids
-    past ``S·F`` become INERT rows (valid all-False, ``total_nodes=1``,
-    the ``pad_scenarios`` convention) so the fleet streamer can pad its
-    last block.  Member φ=0 selects the unperturbed base bitwise
-    (``jnp.where``, not arithmetic), and each model is gated on a
-    static Python ``if`` — a degenerate spec compiles to the plain
-    gather, which is how F=1 parity with ``replay_grid`` is bit-exact.
-    """
-    F = spec.n
-    inert = g >= S * F
-    gc = jnp.minimum(g, S * F - 1)
-    s, phi = gc // F, gc % F
-    return perturb_rows(submit, nodes, est, true_rt, valid, totals,
-                        spec, s, phi, inert)
-
-
-def perturb_window(submit, nodes, est, true_rt, valid, totals,
-                   spec: FanSpec, r: jax.Array, lo, width: int, S: int):
-    """Expand ONLY members ``φ ∈ [lo, lo+width)`` of each scenario —
-    the racing suffix: row ``r = s·width + w`` is member ``φ = lo + w``
-    of scenario s, bitwise the row ``s·F + φ`` of the full fan
-    (``perturb_rows`` keys on (s, φ) alone).  ``lo`` may be a traced
-    scalar so fleet blocks at different offsets share one compile; ids
-    past ``S·width`` are inert padding rows as in ``perturb_block``."""
-    inert = r >= S * width
-    rc = jnp.minimum(r, S * width - 1)
-    s, w = rc // width, rc % width
-    return perturb_rows(submit, nodes, est, true_rt, valid, totals,
-                        spec, s, lo + w, inert)
-
-
 # ----------------------------------------------------------------------
 # Host materialization — the bit-exact oracle (and the benchmark's
 # naive baseline): the SAME per-member perturbations pulled to host and
@@ -347,9 +332,8 @@ def perturb_window(submit, nodes, est, true_rt, valid, totals,
 @functools.partial(jax.jit, static_argnames=("spec", "S"))
 def _materialize_arrays(submit, nodes, est, true_rt, valid, totals,
                         spec: FanSpec, S: int):
-    g = jnp.arange(S * spec.n)
-    return perturb_block(submit, nodes, est, true_rt, valid, totals,
-                         spec, g, S)
+    return perturb_window(submit, nodes, est, true_rt, valid, totals,
+                          spec, jnp.arange(S * spec.n), 0, spec.n, S)
 
 
 def materialize_fan(scenarios, spec: FanSpec):
@@ -426,7 +410,7 @@ def pruned_fan_grid(scenarios, pool, fan, objective=None, *,
 
     The pre-pass members are DONATED into the deciding fan via CRN
     prefix-stability — member φ of the pre-pass is bitwise member φ of
-    the full fan (``perturb_rows`` keys on (s, φ) alone), so the
+    the full fan (``perturb_window`` keys on (s, φ) alone), so the
     donated prefix concatenates with the ``fan_window_grid`` suffix
     into exactly the full fan's member grid without replaying any
     (scenario, policy, member) triple twice.  ``info.members`` vs

@@ -11,7 +11,7 @@ bound exceeds the incumbent's CI upper bound are eliminated; the fan
 doubles for survivors.  The unlock is the §10 CRN prefix-stability:
 member draws key on ``fold_in(fold_in(key, s), φ)`` — independent of F
 — so rung i+1 replays ONLY the new member suffix
-(``engine.fan_window_grid`` / ``_decide_fan_window``) and concatenates
+(``engine.fan_window_grid`` / ``engine._fan_members``) and concatenates
 it with the donated prior-rung members.  No (scenario, policy, member)
 triple is ever replayed twice.
 
@@ -55,7 +55,7 @@ from repro.core.telemetry import fetch
 
 __all__ = [
     "RaceSpec", "RungRecord", "RaceOutcome", "normalize_race",
-    "rung_stats", "race_grid", "decide_race",
+    "rung_stats", "race_grid", "decide_race", "sub_pools",
 ]
 
 
@@ -185,6 +185,23 @@ def _separation(costs: np.ndarray, ci: np.ndarray) -> np.ndarray:
     return np.where(np.isnan(sep), -np.inf, sep).astype(np.float32)
 
 
+def sub_pools(pool) -> Callable[[np.ndarray], object]:
+    """``active -> pool`` restricted to those full-pool indices (ascending,
+    so the argmin tie-break matches the full pool's), one sub-pool kept
+    per distinct active set across a race's rungs."""
+    from repro.core.engine import _index_pool, pool_size
+    P = pool_size(pool)
+    made = {}
+
+    def sub(active: np.ndarray):
+        key = tuple(int(i) for i in active)
+        if key not in made:
+            made[key] = (pool if len(key) == P
+                         else _index_pool(pool, jnp.asarray(active)))
+        return made[key]
+    return sub
+
+
 def run_race(spec: RaceSpec, S: int, P: int, objective,
              eval_window: Callable[[np.ndarray, int, int], np.ndarray],
              on_rung: Optional[Callable] = None,
@@ -305,18 +322,12 @@ def race_grid(scenarios, pool, race, objective=None, *,
     pool = _eng.as_pool(pool)
     P = _eng.pool_size(pool)
     S = int(scenarios.total_nodes.shape[0])
-    sub_pools = {}
+    sub_pool = sub_pools(pool)
     passes = [0]
 
     def eval_window(active, lo, hi):
-        key = tuple(int(i) for i in active)
-        sub = sub_pools.get(key)
-        if sub is None:
-            sub = (pool if len(active) == P
-                   else _eng._index_pool(pool, jnp.asarray(active)))
-            sub_pools[key] = sub
-        out = eng.fan_window_grid(scenarios, sub, spec.fan, goal,
-                                  lo=lo, width=hi - lo)
+        out = eng.fan_window_grid(scenarios, sub_pool(active), spec.fan,
+                                  goal, lo=lo, width=hi - lo)
         passes[0] += int(out.result.pass_invocations)
         return out.member_costs
 
@@ -330,13 +341,13 @@ def race_grid(scenarios, pool, race, objective=None, *,
 
 def decide_race(state, pool, race, objective=None, *, engine=None):
     """One raced decision cycle: ``decide_fan``'s member fan grown rung
-    by rung (``engine._decide_fan_window``) with CI elimination and
-    anytime budgets.  Returns ``(Decision, RaceOutcome)`` — the
-    decision spans the FULL pool (eliminated policies keep the
-    costs/CI from their elimination rung; their members simply stopped
-    growing), ``fan_size`` is the fidelity the survivors reached, and
-    the qrun set comes from member 0 of the winner (member 0 is exact
-    and always in rung 0)."""
+    by rung (``engine._fan_members`` over each rung's window) with CI
+    elimination and anytime budgets.  Returns ``(Decision,
+    RaceOutcome)`` — the decision spans the FULL pool (eliminated
+    policies keep the costs/CI from their elimination rung; their
+    members simply stopped growing), ``fan_size`` is the fidelity the
+    survivors reached, and the qrun set comes from member 0 of the
+    winner (member 0 is exact and always in rung 0)."""
     from repro.core import engine as _eng
     from repro.core.objective import as_distributional, resolve_goal
     eng = engine if engine is not None else _eng.DEFAULT_ENGINE
@@ -346,7 +357,7 @@ def decide_race(state, pool, race, objective=None, *, engine=None):
     pool = _eng.as_pool(pool)
     k = _eng.pool_size(pool)
 
-    sub_pools = {}
+    sub_pool = sub_pools(pool)
     full = {"costs": np.full(k, np.inf, np.float32),
             "ci": np.full(k, np.inf, np.float32),
             "width": np.full(k, np.inf, np.float32)}
@@ -357,26 +368,20 @@ def decide_race(state, pool, race, objective=None, *, engine=None):
 
     def eval_window(active, lo, hi):
         nonlocal msum
-        key = tuple(int(i) for i in active)
-        sub = sub_pools.get(key)
-        if sub is None:
-            sub = (pool if len(active) == k
-                   else _eng._index_pool(pool, jnp.asarray(active)))
-            sub_pools[key] = sub
-        mc, md, mm, f0 = _eng._decide_fan_window(
-            eng, state, sub, spec.fan, goal, eng.plan(sub),
-            lo, hi - lo)
+        sub = sub_pool(active)
+        m = _eng._fan_members(eng, state, sub, spec.fan, goal,
+                              eng.plan(sub), lo, hi - lo)
         if lo == 0:
-            first0["mask"] = fetch(f0)           # (k, J): rung 0 = full pool
-        dead[active] |= fetch(md).any(axis=0)
+            first0["mask"] = fetch(m.first0)     # (k, J): rung 0 = full pool
+        dead[active] |= fetch(m.deadlocked).any(axis=0)
         sums = jax.tree.map(lambda x: fetch(x).sum(axis=0, dtype=np.float64),
-                            mm)
+                            m.metrics)
         if msum is None:
             msum = jax.tree.map(lambda s: np.zeros(k, np.float64), sums)
         msum = jax.tree.map(
             lambda acc, s: _scatter_add(acc, active, s), msum, sums)
         mcount[active] += hi - lo
-        return fetch(mc)[None]                   # (S=1, W, Pa)
+        return fetch(m.costs)[None]              # (S=1, W, Pa)
 
     def on_rung(active, costs, ci, width):
         full["costs"][active] = costs[0]

@@ -24,9 +24,15 @@ This module is the thin public API over the engine:
   * ``decide`` / ``decide_ensemble`` — one scheduling cycle on the
     default (or a caller-supplied) engine; ensemble members ride the
     same batch axis, so k * n_ens forks drain in ONE while_loop;
-  * ``sharded_whatif`` — the fork axis of the batched engine sharded
-    over a device mesh for pools of hundreds of forks (θ shards with
-    the fork axis: a parameter sweep is just a longer, shardable pool);
+  * ``sharded_whatif`` — the engine's decision program with its fork
+    axis sharded over a device mesh, for pools of hundreds of forks
+    (θ shards with the fork axis: a parameter sweep is just a longer,
+    shardable pool);
+  * ``sharded_replay_grid`` / ``sharded_fan_grid`` /
+    ``sharded_race_grid`` — replay, fan and racing grids streamed over
+    a mesh in fixed-size blocks by ONE block streamer
+    (``_stream_blocks``); a fan is one member window of blocks
+    expanded on device, a race one window per rung;
   * ``decide_legacy_vmap`` — the pre-engine path (``jax.vmap`` over the
     scalar DES), kept as a regression oracle and as the baseline the
     overhead benchmark compares the batched engine against.
@@ -39,15 +45,14 @@ from typing import Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh
 
 from repro.core import scoring
 from repro.core.des import drain_metrics, simulate_to_drain
 from repro.core.engine import (DEFAULT_ENGINE, Decision, DrainEngine,
                                EnginePool, _quiet_donation)
 from repro.core.objective import ObjectiveLike, resolve_goal
-from repro.core.policies import (PolicyPool, PolicySpec, normalize_pool,
-                                 parse_pool)
+from repro.core.policies import PolicyPool, PolicySpec, parse_pool
 from repro.core.state import QUEUED, SimState
 
 __all__ = [
@@ -144,65 +149,6 @@ def decide_legacy_vmap(state: SimState, pool: jax.Array,
 # Fleet scale: shard the fork axis of the batched engine (DESIGN.md §9).
 # ----------------------------------------------------------------------
 
-@functools.partial(jax.jit,
-                   static_argnames=("engine", "mesh", "axis", "objective",
-                                    "plan"))
-def _decide_fleet(engine: DrainEngine, mesh: Mesh, axis: str,
-                  state: SimState, pool: EnginePool, objective, plan):
-    """The sharded decision cycle: the DRAIN runs per shard under
-    ``shard_map`` (each device forks/drains its own chunk of the pool,
-    with its own shard-local hoist plan and pass bound), the selection
-    (metrics -> costs -> argmin) runs on the concatenated result so the
-    winner is global and keeps ``select_policy``'s first-occurrence
-    tie-break over the FULL pool order."""
-    from repro.core.des import broadcast_state, drain_metrics
-    from repro.core.engine import _drain_impl, hoisted_orders, pool_size
-    from repro.launch.mesh import shard_map
-
-    n_shards = mesh.shape[axis]
-    k_local = pool_size(pool) // n_shards
-
-    if plan is None:
-        def local(st: SimState, pool_shard: EnginePool):
-            return _drain_impl(engine, broadcast_state(st, k_local),
-                               pool_shard, plan)
-
-        res = shard_map(local, mesh, in_specs=(P(), P(axis)),
-                        out_specs=P(axis))(state, pool)
-    else:
-        # Hoisted static argsorts are computed HERE, in the GSPMD region,
-        # and cross the shard boundary as a sharded input — jax 0.4
-        # miscompiles the sort if it is traced inside the shard_map body
-        # (see engine.hoisted_orders).  `plan` is shard-local; the global
-        # plan is its n_shards-fold tile, so np.nonzero enumerates each
-        # shard's time-invariant rows contiguously and P(axis) hands
-        # every device exactly its own rows.
-        states_full = broadcast_state(state, k_local * n_shards)
-        hoisted = hoisted_orders(states_full, pool, plan * n_shards,
-                                 states_full.jobs.state == QUEUED)
-
-        def local(st: SimState, pool_shard: EnginePool, hoist_shard):
-            return _drain_impl(engine, broadcast_state(st, k_local),
-                               pool_shard, plan, hoisted=hoist_shard)
-
-        res = shard_map(local, mesh, in_specs=(P(), P(axis), P(axis)),
-                        out_specs=P(axis))(state, pool, hoisted)
-
-    eval_mask = state.jobs.state == QUEUED
-    metrics = jax.vmap(drain_metrics, in_axes=(0, None))(res, eval_mask)
-    costs = objective.costs(metrics)
-    costs = jnp.where(res.deadlocked, jnp.inf, costs)
-    best = scoring.select_policy(costs)
-    return Decision(
-        policy_index=best,
-        costs=costs,
-        run_mask=res.first_started[best],
-        metrics=metrics,
-        deadlocked=res.deadlocked,
-        cost_terms=objective.cost_terms(metrics),
-    ).packed()
-
-
 def sharded_whatif(mesh: Mesh, axis: str = "data",
                    engine: Optional[DrainEngine] = None,
                    objective: ObjectiveLike = None, *,
@@ -211,8 +157,10 @@ def sharded_whatif(mesh: Mesh, axis: str = "data",
     batched engine sharded over ``axis`` of ``mesh``.  Returns a jitted
     function with the same signature as ``decide`` whose pool size must
     be divisible by the axis size.  The snapshot is replicated (it is a
-    few KB); only the fork axis is split, mirroring "k simulator copies
-    sharing one database" at pod scale.
+    few KB) and broadcast to the forks; only the fork axis is split,
+    mirroring "k simulator copies sharing one database" at pod scale.
+    It is ``engine._decide`` with a ``(mesh, axis)`` placement: the
+    same member program and reduction as the local decide.
 
     The pool sharding is a PyTree prefix, so it applies equally to a
     legacy (k,) id vector and to a ``PolicySpec`` stack — for specs the
@@ -230,7 +178,7 @@ def sharded_whatif(mesh: Mesh, axis: str = "data",
     dynamic pass bound is likewise shard-local: a deep queue on one
     shard no longer widens every other shard's pass.
     """
-    from repro.core.engine import pool_size, shard_local_plan
+    from repro.core.engine import _decide, pool_size
 
     eng = engine or DEFAULT_ENGINE
     goal = resolve_goal(objective, weights)
@@ -243,8 +191,8 @@ def sharded_whatif(mesh: Mesh, axis: str = "data",
             raise ValueError(
                 f"pool size k={k} not divisible by {n_shards}-way "
                 f"'{axis}' axis")
-        plan = shard_local_plan(eng.plan(pool), n_shards)
-        return _decide_fleet(eng, mesh, axis, state, pool, goal, plan)
+        return _decide(eng, state, pool, goal, eng.plan(pool),
+                       placement=(mesh, axis))
 
     return wrapper
 
@@ -264,47 +212,105 @@ def _replay_block_sharded(engine: DrainEngine, mesh: Mesh, axis: str,
     shard so the stacked output carries one count per device; the
     streamer sums them.  ``states`` is donated — the per-block carry
     updates in place across the stream."""
-    from repro.core.engine import _replay_impl, hoisted_orders
-    from repro.launch.mesh import shard_map
+    from repro.core.engine import _replay_impl, on_shards
 
-    if plan is None:
-        def local(states, arrival_t, true_rt, pool, valid):
-            res, metrics = _replay_impl(engine, states, arrival_t,
-                                        true_rt, pool, valid, plan)
-            res = res._replace(
-                iters=res.iters.reshape(1),
-                pass_invocations=res.pass_invocations.reshape(1))
-            return res, metrics
-
-        return shard_map(local, mesh, in_specs=(P(axis),) * 5,
-                         out_specs=P(axis))(states, arrival_t, true_rt,
-                                            pool, valid)
-
-    # Hoisting on: the static argsorts cross the shard boundary as a
-    # sharded input (engine.hoisted_orders — jax 0.4 miscompiles them
-    # when traced inside the shard_map body).  `plan` is shard-local
-    # and periodic, so its n_shards-fold tile is the global plan and
-    # P(axis) gives each device its own forks' rows.
-    ever_q = jnp.isfinite(arrival_t) | (states.jobs.state == QUEUED)
-    hoisted = hoisted_orders(states, pool, plan * mesh.shape[axis],
-                             ever_q)
-
-    def local(states, arrival_t, true_rt, pool, valid, hoist_shard):
+    def local(states, pool, arrival_t, true_rt, valid, hoisted=None):
         res, metrics = _replay_impl(engine, states, arrival_t, true_rt,
-                                    pool, valid, plan,
-                                    hoisted=hoist_shard)
-        res = res._replace(
+                                    pool, valid, plan, hoisted=hoisted)
+        return res._replace(
             iters=res.iters.reshape(1),
-            pass_invocations=res.pass_invocations.reshape(1))
-        return res, metrics
+            pass_invocations=res.pass_invocations.reshape(1)), metrics
 
-    return shard_map(local, mesh, in_specs=(P(axis),) * 6,
-                     out_specs=P(axis))(states, arrival_t, true_rt,
-                                        pool, valid, hoisted)
+    ever_q = jnp.isfinite(arrival_t) | (states.jobs.state == QUEUED)
+    return on_shards(local, mesh, axis, plan, ever_q, states, pool,
+                     arrival_t, true_rt, valid)
 
 
 def _round_up(n: int, multiple: int) -> int:
     return -(-n // multiple) * multiple
+
+
+def _stream_blocks(engine: DrainEngine, mesh: Mesh, axis: str,
+                   pool: EnginePool, B: int, blocks, sync: bool = False):
+    """The one block streamer (DESIGN.md §9): each ``(inputs, rows)`` of
+    ``blocks`` is one fixed-shape block of B rows (scenarios or fan
+    rows, P forks each) replayed through ``_replay_block_sharded``,
+    of which the first ``rows`` are real.  A partial block's padding
+    is trimmed, the blocks join on the fork axis, and ``iters`` /
+    ``pass_invocations`` sum over (shard, block).  Blocks dispatch
+    back to back (jax's async dispatch pipelines them); ``sync``
+    waits for each.  Returns ``(ReplayResult, metrics)``."""
+    from repro.core.des import ReplayResult
+    from repro.core.engine import pool_size
+
+    P = pool_size(pool)
+    plan = engine.plan(pool)           # P-periodic: one chunk per shard
+    plan = plan * (B // mesh.shape[axis]) if plan is not None else None
+    res_blocks, met_blocks = [], []
+    for inputs, rows in blocks:
+        res, metrics = _replay_block_sharded(engine, mesh, axis, plan,
+                                             *inputs)
+        if sync:
+            jax.block_until_ready((res, metrics))
+        if rows != B:                  # only a partial block pays a trim
+            trim = lambda x: x[:rows * P]
+            res = res._replace(state=jax.tree.map(trim, res.state),
+                               events=trim(res.events),
+                               deadlocked=trim(res.deadlocked))
+            metrics = jax.tree.map(trim, metrics)
+        res_blocks.append(res)
+        met_blocks.append(metrics)
+    if not res_blocks:
+        raise ValueError("no scenario blocks")
+    cat = (lambda *xs: xs[0] if len(xs) == 1
+           else jnp.concatenate(xs, axis=0))
+    res = ReplayResult(
+        state=jax.tree.map(cat, *[r.state for r in res_blocks]),
+        events=cat(*[r.events for r in res_blocks]),
+        iters=sum(r.iters.sum() for r in res_blocks),
+        deadlocked=cat(*[r.deadlocked for r in res_blocks]),
+        pass_invocations=sum(r.pass_invocations.sum()
+                             for r in res_blocks))
+    return res, jax.tree.map(cat, *met_blocks)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("spec", "P", "B", "S", "lo", "width"))
+def _window_block_inputs(submit, nodes, est, true_rt, valid, totals, pool,
+                         spec, P, B, S, lo, width, blo):
+    """One fixed-shape block of a fan window, expanded ON DEVICE from
+    the one uploaded base: window rows ``r = blo .. blo+B`` of the
+    ``S·width`` rectangle (``r = s·width + w`` ⇒ member ``φ = lo + w``;
+    ids past S·width are inert padding), assembled into donatable
+    (B·P)-fork replay inputs.  ``lo``/``width`` are static (a rung
+    schedule is fixed, so each rung compiles once); ``blo`` is a
+    dynamic operand, so every block of a window shares one compile."""
+    from repro.core.engine import _assemble_replay_inputs
+    from repro.core.fan import perturb_window
+    r = blo + jnp.arange(B)
+    rows = perturb_window(submit, nodes, est, true_rt, valid, totals,
+                          spec, r, lo, width, S)
+    return _assemble_replay_inputs(*rows, pool, P)
+
+
+def _stream_window(engine: DrainEngine, mesh: Mesh, axis: str, scenarios,
+                   pool: EnginePool, spec, lo: int, width: int,
+                   block_size: Optional[int]):
+    """Members ``φ ∈ [lo, lo+width)`` of the fan over ``pool``, streamed:
+    the ``S·width`` window rows in blocks of ``block_size`` (rounded up
+    to the axis size), each expanded on device from the scenarios'
+    device arrays, uploaded once (H2D stays O(1) in F).  The whole fan
+    is the window ``[0, F)``."""
+    from repro.core.engine import _scenario_arrays, pool_size
+    base = _scenario_arrays(scenarios)
+    S = int(scenarios.total_nodes.shape[0])
+    R = S * width
+    B = _round_up(block_size or R, mesh.shape[axis])
+    P = pool_size(pool)
+    blocks = ((_window_block_inputs(*base, pool, spec, P, B, S, lo, width,
+                                    jnp.int32(blo)), min(B, R - blo))
+              for blo in range(0, R, B))
+    return _stream_blocks(engine, mesh, axis, pool, B, blocks)
 
 
 def sharded_replay_grid(mesh: Mesh, axis: str = "data",
@@ -361,7 +367,6 @@ def sharded_replay_grid(mesh: Mesh, axis: str = "data",
     ``costs``/``best`` selection; ``iters``/``pass_invocations`` on the
     raw result aggregate over (shard, block).
     """
-    from repro.core.des import ReplayResult
     from repro.core.engine import (_shape_outcome, as_pool,
                                    grid_select_jit, pool_size,
                                    replay_inputs)
@@ -376,7 +381,6 @@ def sharded_replay_grid(mesh: Mesh, axis: str = "data",
     def wrapper(scenarios, pool: PoolArg):
         pool = as_pool(_engine_pool(pool))
         Psz = pool_size(pool)
-        plan_P = eng.plan(pool)          # per-scenario chunk (hoisting)
 
         if isinstance(scenarios, ScenarioSet):
             S_real = scenarios.n_scenarios
@@ -391,11 +395,6 @@ def sharded_replay_grid(mesh: Mesh, axis: str = "data",
                 raise ValueError("no scenario blocks") from None
             B = _round_up(block_size or head.n_scenarios, n_shards)
             raw = itertools.chain([head], raw)
-            S_real = None                # discovered while streaming
-
-        plan_blk = (plan_P * (B // n_shards)
-                    if plan_P is not None else None)
-        n_reals: list = []
 
         def ingest():
             # numpy ONLY in this thread: jax dispatch (the jitted
@@ -407,65 +406,20 @@ def sharded_replay_grid(mesh: Mesh, axis: str = "data",
                 if n > B:
                     raise ValueError(
                         f"scenario block of {n} > block size {B}")
-                n_reals.append(n)
-                yield pad_scenarios(blk, B)
+                yield pad_scenarios(blk, B), n
 
         stream = ingest()
         if prefetch_depth > 0:
             stream = prefetch(stream, depth=prefetch_depth)
-
-        res_blocks, met_blocks = [], []
-        for padded in stream:
-            res, metrics = _replay_block_sharded(
-                eng, mesh, axis, plan_blk,
-                *replay_inputs(padded, pool))
-            if prefetch_depth <= 0:
-                jax.block_until_ready((res, metrics))
-            n_keep = n_reals[len(res_blocks)] * Psz
-            if n_keep != B * Psz:        # only partial blocks pay a trim
-                trim = lambda x: x[:n_keep]
-                res = res._replace(
-                    state=jax.tree.map(trim, res.state),
-                    events=trim(res.events),
-                    deadlocked=trim(res.deadlocked))
-                metrics = jax.tree.map(trim, metrics)
-            res_blocks.append(res)
-            met_blocks.append(metrics)
-        if not res_blocks:
-            raise ValueError("no scenario blocks")
-        S_out = sum(n_reals)
-
-        cat = (lambda *xs: xs[0] if len(xs) == 1
-               else jnp.concatenate(xs, axis=0))
-        res = ReplayResult(
-            state=jax.tree.map(cat, *[r.state for r in res_blocks]),
-            events=cat(*[r.events for r in res_blocks]),
-            iters=sum(r.iters.sum() for r in res_blocks),
-            deadlocked=cat(*[r.deadlocked for r in res_blocks]),
-            pass_invocations=sum(r.pass_invocations.sum()
-                                 for r in res_blocks))
-        metrics = jax.tree.map(cat, *met_blocks)
+        res, metrics = _stream_blocks(
+            eng, mesh, axis, pool, B,
+            ((replay_inputs(padded, pool), n) for padded, n in stream),
+            sync=prefetch_depth <= 0)
         costs, best = grid_select_jit(goal, metrics, res.deadlocked, Psz)
+        S_out = res.deadlocked.shape[0] // Psz
         return _shape_outcome(res, metrics, (S_out, Psz), costs, best)
 
     return wrapper
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("spec", "P", "B", "S"))
-def _fan_block_inputs(submit, nodes, est, true_rt, valid, totals, pool,
-                      spec, P, B, S, lo):
-    """One fixed-shape fan block, expanded ON DEVICE: pseudo-scenarios
-    ``g = lo .. lo+B`` (``g = s·F + φ``; ids past S·F are inert
-    padding) perturbed from the shared base arrays and assembled into
-    donatable (B·P)-fork replay inputs.  ``lo`` is a dynamic operand —
-    every block reuses ONE compiled expansion."""
-    from repro.core.engine import _assemble_replay_inputs
-    from repro.core.fan import perturb_block
-    g = lo + jnp.arange(B)
-    rows = perturb_block(submit, nodes, est, true_rt, valid, totals,
-                         spec, g, S)
-    return _assemble_replay_inputs(*rows, pool, P)
 
 
 def sharded_fan_grid(mesh: Mesh, axis: str = "data",
@@ -478,87 +432,37 @@ def sharded_fan_grid(mesh: Mesh, axis: str = "data",
     rows) sharded over ``axis`` of ``mesh`` and streamed in fixed-size
     blocks, exactly like ``sharded_replay_grid`` streams scenarios.
 
-    The fan stacks on the PR-6 block machinery unchanged because fan
-    members ARE pseudo-scenarios: hoist plans stay P-periodic
-    (``plan_P · (B / n_shards)`` per shard), padding rows are inert,
-    and ``_replay_block_sharded`` is reused as is.  What changes is
-    ingestion: there is NO host ingest thread to overlap — each block
-    is expanded on device from the one uploaded base (H2D stays O(1)
-    in F), so blocks dispatch back-to-back and jax's async dispatch
-    pipelines them.  Fan member draws are keyed per (s, φ)
-    independently of the block cut, so any ``block_size`` is
-    bit-identical to the one-shot ``fan_grid``.
+    The fan is one full-pool member window ``[0, F)`` through the one
+    block streamer (``_stream_window``), then ``fan_select_jit``: fan
+    members ARE pseudo-scenarios, so hoist plans stay P-periodic and
+    padding rows are inert.  There is NO host ingest thread to
+    overlap — each block is expanded on device from the one uploaded
+    base (H2D stays O(1) in F), so blocks dispatch back-to-back and
+    jax's async dispatch pipelines them.  Fan member draws are keyed
+    per (s, φ) independently of the block cut, so any ``block_size``
+    is bit-identical to the one-shot ``fan_grid``.
 
     ``fan`` is a ``FanSpec`` (or bare int F); ``block_size`` counts
     pseudo-scenarios per device step (i.e. ``block_size // F`` base
     scenarios), rounded up to the axis size.  Returns a function
     ``(scenarios, pool) -> FanOutcome``.
     """
-    from repro.core.des import ReplayResult
-    from repro.core.engine import (FanOutcome, _scenario_arrays, as_pool,
-                                   fan_select_jit, pool_size)
+    from repro.core.engine import (_fan_outcome, as_pool, fan_select_jit,
+                                   pool_size)
     from repro.core.fan import normalize_fan
 
     eng = engine or DEFAULT_ENGINE
     goal = resolve_goal(objective)
     spec = normalize_fan(fan if fan is not None else 1)
-    n_shards = mesh.shape[axis]
 
-    def wrapper(scenarios, pool: PoolArg) -> "FanOutcome":
+    def wrapper(scenarios, pool: PoolArg):
         pool = as_pool(_engine_pool(pool))
         Psz = pool_size(pool)
         S = int(scenarios.total_nodes.shape[0])
-        G = S * spec.n
-        B = _round_up(block_size or G, n_shards)
-        plan_P = eng.plan(pool)
-        plan_blk = (plan_P * (B // n_shards)
-                    if plan_P is not None else None)
-        base = _scenario_arrays(scenarios)
-
-        res_blocks, met_blocks = [], []
-        for lo in range(0, G, B):
-            inputs = _fan_block_inputs(*base, pool, spec, Psz, B, S,
-                                       jnp.int32(lo))
-            res, metrics = _replay_block_sharded(
-                eng, mesh, axis, plan_blk, *inputs)
-            n_keep = (min(lo + B, G) - lo) * Psz
-            if n_keep != B * Psz:        # only the tail block pays a trim
-                trim = lambda x: x[:n_keep]
-                res = res._replace(
-                    state=jax.tree.map(trim, res.state),
-                    events=trim(res.events),
-                    deadlocked=trim(res.deadlocked))
-                metrics = jax.tree.map(trim, metrics)
-            res_blocks.append(res)
-            met_blocks.append(metrics)
-
-        cat = (lambda *xs: xs[0] if len(xs) == 1
-               else jnp.concatenate(xs, axis=0))
-        res = ReplayResult(
-            state=jax.tree.map(cat, *[r.state for r in res_blocks]),
-            events=cat(*[r.events for r in res_blocks]),
-            iters=sum(r.iters.sum() for r in res_blocks),
-            deadlocked=cat(*[r.deadlocked for r in res_blocks]),
-            pass_invocations=sum(r.pass_invocations.sum()
-                                 for r in res_blocks))
-        metrics = jax.tree.map(cat, *met_blocks)
-        member, costs, best, ci, width = fan_select_jit(
-            goal, metrics, res.deadlocked, spec.n, Psz)
-        shape = (S, spec.n, Psz)
-        rs = lambda x: x.reshape(shape + x.shape[1:])
-        return FanOutcome(
-            start_t=rs(res.state.jobs.start_t),
-            end_t=rs(res.state.jobs.end_t),
-            metrics=jax.tree.map(rs, metrics),
-            deadlocked=rs(res.deadlocked),
-            events=rs(res.events),
-            result=res,
-            member_costs=member,
-            costs=costs,
-            best=best,
-            cost_ci=ci,
-            fan_width=width,
-        )
+        res, metrics = _stream_window(eng, mesh, axis, scenarios, pool,
+                                      spec, 0, spec.n, block_size)
+        return _fan_outcome(res, metrics, (S, spec.n, Psz), fan_select_jit(
+            goal, metrics, res.deadlocked, spec.n, Psz))
 
     return wrapper
 
@@ -586,24 +490,6 @@ def sharded_generation_costs(mesh: Mesh, axis: str = "data",
     return lambda scenarios, pool: run(scenarios, pool).costs
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("spec", "P", "B", "S", "lo", "width"))
-def _race_block_inputs(submit, nodes, est, true_rt, valid, totals, pool,
-                       spec, P, B, S, lo, width, blo):
-    """One fixed-shape RACING-WINDOW block, expanded on device: window
-    rows ``r = blo .. blo+B`` of the rung's ``S·width`` rectangle
-    (``r = s·width + w`` ⇒ member ``φ = lo + w``; ids past S·width are
-    inert padding).  ``lo``/``width`` are STATIC — the rung schedule is
-    fixed, so each rung compiles once — while ``blo`` is a dynamic
-    operand: all blocks within a rung share one compiled expansion."""
-    from repro.core.engine import _assemble_replay_inputs
-    from repro.core.fan import perturb_window
-    r = blo + jnp.arange(B)
-    rows = perturb_window(submit, nodes, est, true_rt, valid, totals,
-                          spec, r, lo, width, S)
-    return _assemble_replay_inputs(*rows, pool, P)
-
-
 def sharded_race_grid(mesh: Mesh, axis: str = "data",
                       engine: Optional[DrainEngine] = None,
                       objective: ObjectiveLike = None, *,
@@ -611,8 +497,8 @@ def sharded_race_grid(mesh: Mesh, axis: str = "data",
                       block_size: Optional[int] = None):
     """Fleet-scale adaptive racing (DESIGN.md §§9–11): each rung of the
     successive-halving race streams its ``S·width`` member-window rows
-    through the PR-6 block machinery (``_replay_block_sharded``
-    unchanged — window rows are pseudo-scenarios like any other), and
+    through the one block streamer (``_stream_window`` — window rows
+    are pseudo-scenarios like any other), and
     the controller (``race.run_race``) eliminates/terminates between
     rungs exactly as the local ``race_grid`` does.
 
@@ -624,61 +510,28 @@ def sharded_race_grid(mesh: Mesh, axis: str = "data",
     window rows per device step, rounded up to the axis size.  Returns
     a function ``(scenarios, pool) -> race.RaceOutcome``.
     """
-    from repro.core.des import ReplayResult
-    from repro.core.engine import (_index_pool, _scenario_arrays, as_pool,
-                                   fan_select_jit, pool_size)
-    from repro.core.race import normalize_race, run_race
+    from repro.core.engine import as_pool, fan_select_jit, pool_size
+    from repro.core.race import normalize_race, run_race, sub_pools
 
     eng = engine or DEFAULT_ENGINE
     goal = resolve_goal(objective)
     spec = normalize_race(race if race is not None else 1)
-    n_shards = mesh.shape[axis]
 
     def wrapper(scenarios, pool: PoolArg):
         pool_full = as_pool(_engine_pool(pool))
         Psz = pool_size(pool_full)
         S = int(scenarios.total_nodes.shape[0])
-        base = _scenario_arrays(scenarios)
-        sub_pools = {}
+        sub_pool = sub_pools(pool_full)
         passes = [0]
 
         def eval_window(active, lo, hi):
-            key = tuple(int(i) for i in active)
-            sub = sub_pools.get(key)
-            if sub is None:
-                sub = (pool_full if len(active) == Psz
-                       else _index_pool(pool_full, jnp.asarray(active)))
-                sub_pools[key] = sub
-            Pa = pool_size(sub)
-            width = hi - lo
-            R = S * width                      # window rows this rung
-            B = _round_up(block_size or R, n_shards)
-            plan_P = eng.plan(sub)
-            plan_blk = (plan_P * (B // n_shards)
-                        if plan_P is not None else None)
-            met_blocks, dead_blocks = [], []
-            for blo in range(0, R, B):
-                inputs = _race_block_inputs(*base, sub, spec.fan, Pa,
-                                            B, S, lo, width,
-                                            jnp.int32(blo))
-                res, metrics = _replay_block_sharded(
-                    eng, mesh, axis, plan_blk, *inputs)
-                passes[0] += int(res.pass_invocations.sum())
-                n_keep = (min(blo + B, R) - blo) * Pa
-                if n_keep != B * Pa:     # only the tail block trims
-                    trim = lambda x: x[:n_keep]
-                    metrics = jax.tree.map(trim, metrics)
-                    dead_blocks.append(trim(res.deadlocked))
-                else:
-                    dead_blocks.append(res.deadlocked)
-                met_blocks.append(metrics)
-            cat = (lambda *xs: xs[0] if len(xs) == 1
-                   else jnp.concatenate(xs, axis=0))
-            metrics = jax.tree.map(cat, *met_blocks)
-            dead = cat(*dead_blocks)
-            member, _, _, _, _ = fan_select_jit(
-                goal, metrics, dead, width, Pa)
-            return member
+            sub = sub_pool(active)
+            res, metrics = _stream_window(eng, mesh, axis, scenarios, sub,
+                                          spec.fan, lo, hi - lo,
+                                          block_size)
+            passes[0] += int(res.pass_invocations)
+            return fan_select_jit(goal, metrics, res.deadlocked, hi - lo,
+                                  pool_size(sub))[0]
 
         out = run_race(spec, S, Psz, goal, eval_window)
         return out._replace(passes=passes[0])

@@ -192,3 +192,157 @@ def test_pool_array_preserves_caller_order():
     arr = np.asarray(whatif.pool_array(ids))
     assert list(arr) == ids
     assert arr.dtype == np.int32
+
+
+# ----------------------------------------------------------------------
+# Every producer's outputs, bit for bit: SHA-256 digests of their bytes
+# on one fixed CPU state and scenario set (J = 64, pool ``paper``, goal
+# ``score``, reference backend), recorded before the decision and replay
+# programs were folded into one member program and one block streamer.
+# ----------------------------------------------------------------------
+
+DIGEST_J = 64
+DIGEST_POOL = "paper"
+DIGEST_FAN = dict(n=4, runtime_noise=0.3, burst_amplitude=0.3,
+                  burst_period=600.0, failure_prob=0.4, failure_domains=4,
+                  seed=7)
+DIGEST_RACE_FAN = dict(n=8, runtime_noise=0.3, failure_prob=0.4,
+                       failure_domains=4, seed=7)
+
+PRODUCER_DIGESTS = {
+    "decide":
+        "dbaf54d1188305204240deca23247f7a0b62567271b2bb8e7e0b872240e1c970",
+    # recorded with member 0 exact, after the ensemble's fix (its
+    # estimates used to be scaled by exp(-noise**2 / 2))
+    "decide_ensemble":
+        "71312b3dd196bc0be8cfff49082e9c9ffe082579a94c57ffbfcbd02896d533a7",
+    "decide_fan":
+        "3f9816da1285f143b125e7e717ffffc55e0943b1b97eb0b438691285a9999c0f",
+    "decide_race":
+        "5eeaedd125ee6685731e6698d3b8870cdb43ff6b4a2da3d067d2ad2da90447f7",
+    "sharded_whatif":
+        "dbaf54d1188305204240deca23247f7a0b62567271b2bb8e7e0b872240e1c970",
+    "replay_grid":
+        "d2495ad7ffb8ea97a30777a3d86c896ed5233ea50dbf0df371ce1de460087b53",
+    "fan_grid":
+        "c126765bfd3385ade8ae78ebf9edcfb035770f78c80d5519758c121cfae2989f",
+    "fan_window_grid":
+        "6ab0b018f8c0ee49f5c5211759e8de20c7901df1eb102b20b5f459ba006c809c",
+    "sharded_replay_grid":
+        "e0b53d30f675d4218b8a3c0b75d519b78231b0f83b40c471464f2835341cabff",
+    "sharded_fan_grid":
+        "76a1482f8663654f76b7a409ea72fbafc3a153705a209ae7ad1505265610170c",
+    "sharded_race_grid":
+        "a832ad04628f0f1e97670012c87d659d1eb6f2f4f67f0f0639ea9d55b4e71ced",
+}
+
+
+def _digest(*arrays) -> str:
+    import hashlib
+    h = hashlib.sha256()
+    for a in jax.tree.leaves(arrays):
+        a = np.asarray(a)
+        h.update(f"{a.dtype}{a.shape}".encode())
+        h.update(np.ascontiguousarray(a).tobytes())
+    return h.hexdigest()
+
+
+def _produce(name: str) -> str:
+    from repro.cluster.workload import (bursty_trace, poisson_trace,
+                                        stack_scenarios)
+    from repro.core.fan import FanSpec
+    from repro.core.policies import parse_pool
+    from repro.core.race import RaceSpec
+    from repro.launch.mesh import make_mesh
+
+    pool = parse_pool(DIGEST_POOL).spec
+    spec = FanSpec(**DIGEST_FAN)
+    race = RaceSpec(fan=FanSpec(**DIGEST_RACE_FAN), f0=2)
+    mesh = make_mesh((1, 1), ("data", "model"))
+    state = make_cluster_state(max_jobs=DIGEST_J, total_nodes=32,
+                               n_queued=14, n_running=4, seed=3)
+
+    def scenarios(S):
+        return stack_scenarios(
+            [(bursty_trace if i % 2 else poisson_trace)(
+                24, 16, 6.0 + i, (1, 12), (30.0, 400.0), seed=40 + i)
+             for i in range(S)], 16, max_jobs=DIGEST_J)
+
+    def grid(o):
+        return _digest(o.start_t, o.end_t, o.metrics, o.deadlocked,
+                       o.events, o.costs, o.best)
+
+    def fan(o):
+        return _digest(o.start_t, o.end_t, o.metrics, o.deadlocked,
+                       o.events, o.member_costs, o.costs, o.best,
+                       o.cost_ci, o.fan_width)
+
+    if name == "decide":
+        return _digest(REF.decide(state, pool, "score").words)
+    if name == "decide_ensemble":
+        return _digest(REF.decide_ensemble(
+            state, pool, jax.random.PRNGKey(5), n_ens=4, noise=0.3,
+            objective="score").words)
+    if name == "decide_fan":
+        return _digest(REF.decide_fan(state, pool, spec, "score").words)
+    if name == "decide_race":
+        d, r = REF.decide_race(state, pool, race, "score")
+        return _digest(d.words, r.member_costs, r.costs, r.best, r.cost_ci,
+                       r.fan_width, r.keep)
+    if name == "sharded_whatif":
+        return _digest(whatif.sharded_whatif(
+            mesh, engine=REF, objective="score")(state, pool).words)
+    if name == "replay_grid":
+        return grid(REF.replay_grid(scenarios(3), pool, "score"))
+    if name == "fan_grid":
+        return fan(REF.fan_grid(scenarios(3), pool, spec, "score"))
+    if name == "fan_window_grid":
+        return fan(REF.fan_window_grid(scenarios(3), pool, spec, "score",
+                                       lo=1, width=2))
+    if name == "sharded_replay_grid":      # blocks of 2, 2 and a tail of 1
+        o = whatif.sharded_replay_grid(mesh, engine=REF, objective="score",
+                                       block_size=2)(scenarios(5), pool)
+        return _digest(o.start_t, o.end_t, o.costs, o.best)
+    if name == "sharded_fan_grid":         # 12 fan rows in blocks of 5
+        o = whatif.sharded_fan_grid(mesh, engine=REF, objective="score",
+                                    fan=spec, block_size=5)(scenarios(3),
+                                                            pool)
+        return _digest(o.start_t, o.end_t, o.member_costs, o.costs,
+                       o.best)
+    assert name == "sharded_race_grid"
+    r = whatif.sharded_race_grid(mesh, engine=REF, objective="score",
+                                 race=race, block_size=3)(scenarios(3), pool)
+    return _digest(r.member_costs, r.costs, r.best, r.cost_ci, r.fan_width,
+                   r.keep, r.members, r.fan_size, r.separation)
+
+
+@pytest.mark.parametrize("name", sorted(PRODUCER_DIGESTS))
+def test_producer_outputs_match_recorded_bits(name):
+    assert _produce(name) == PRODUCER_DIGESTS[name], name
+
+
+def test_ensemble_member0_is_the_nominal_pass():
+    """The ensemble's qrun set comes from member 0, which must be the
+    mirror's own estimates.  Here EASY backfill hinges on an estimate:
+    job 2 (4 nodes, estimate 52 s at t=50) may start only if it ends
+    by the head's shadow time, 100 s; any shrink of its estimate by
+    more than 4 % (``exp(-noise**2 / 2)`` is 0.956 at noise 0.3) would
+    backfill it.  So the run mask must be the chosen policy's nominal
+    first pass, which starts nothing."""
+    from repro.core.policies import parse_pool
+    from repro.core.state import add_job, empty_state, start_job
+    state = empty_state(8, 10)
+    state = add_job(state, 0, 0.0, 6, 100.0)
+    state = start_job(state, 0, 0.0)             # ends at t=100
+    state = add_job(state, 1, 1.0, 8, 50.0)      # the head: waits to t=100
+    state = add_job(state, 2, 2.0, 4, 52.0)
+    state = state._replace(now=jnp.float32(50.0))
+    pool = parse_pool("paper").spec
+    for eng in (REF, PAL):
+        d = eng.decide_ensemble(state, pool, jax.random.PRNGKey(0),
+                                n_ens=4, noise=0.3)
+        chosen = jax.tree.map(lambda x: x[int(d.policy_index)], pool)
+        nominal = eng.schedule_pass_starts(state, chosen)
+        np.testing.assert_array_equal(np.asarray(d.run_mask),
+                                      np.asarray(nominal), err_msg=eng.backend)
+        assert not np.asarray(nominal).any()
