@@ -38,7 +38,11 @@ sources: none (``decide``, the nominal program the twin runs), a fan
 window (``decide_fan`` over ``[0, F)``, ``race.decide_race``'s rungs
 over later windows) and the ensemble's estimate noise
 (``decide_ensemble``); ``whatif.sharded_whatif`` is the nominal decide
-with a placement.  ``SchedTwin`` gets its engine at construction, and
+with a placement.  A decision's snapshot is a ``SimState`` or the
+twin's mirror as one uploaded buffer (``PackedState``), which the
+decision's program unpacks first (``state.as_state``); a ``SimState``
+passes through ``as_state`` unchanged and adds nothing to the program.
+``SchedTwin`` gets its engine at construction, and
 the cluster emulator's static mode runs a k=1 engine, so baselines
 stay bit-identical to the twin's simulator.
 
@@ -79,7 +83,7 @@ from repro.core.des import (DrainMetrics, DrainResult, ReplayResult,
                             simulate_to_drain_batched, state_metrics)
 from repro.core.policies import PolicySpec, time_invariant_mask
 from repro.core.state import (QUEUED, RUNNING, TIME_NONE, JobTable,
-                              SimState)
+                              PackedState, SimState, as_state)
 from repro.kernels import policy_eval as _pe
 from repro.launch.mesh import shard_map
 
@@ -104,6 +108,11 @@ def _quiet_donation(jitted):
 #: a leading fork axis (the post-tentpole representation) or a legacy
 #: i32 id vector (kept as the bit-exact pre-parametric oracle path).
 EnginePool = Union[PolicySpec, jax.Array]
+
+#: What a decision accepts as its snapshot: a ``SimState``, or the
+#: twin's mirror uploaded as one buffer (``PackedState``), which the
+#: decision's own program unpacks (``state.as_state``).
+Mirror = Union[SimState, PackedState]
 
 
 def pool_size(pool: EnginePool) -> int:
@@ -589,7 +598,7 @@ class DrainEngine:
                       pool, self.plan(pool))
 
     # -- decision cycles ----------------------------------------------
-    def decide(self, state: SimState, pool: EnginePool,
+    def decide(self, state: Mirror, pool: EnginePool,
                objective: ObjectiveLike = None, *,
                weights: Optional[scoring.ScoreWeights] = None) -> Decision:
         """One decision cycle under ``objective`` (an ``Objective``, a
@@ -598,7 +607,7 @@ class DrainEngine:
         goal = resolve_goal(objective, weights)
         return _decide(self, state, pool, goal, self.plan(pool))
 
-    def decide_ensemble(self, state: SimState, pool: EnginePool,
+    def decide_ensemble(self, state: Mirror, pool: EnginePool,
                         key: jax.Array, n_ens: int = 8, noise: float = 0.3,
                         objective: ObjectiveLike = None, *,
                         weights: Optional[scoring.ScoreWeights] = None,
@@ -608,7 +617,7 @@ class DrainEngine:
                              goal, self.plan(pool))
         return d._replace(fan_size=n_ens)
 
-    def decide_fan(self, state: SimState, pool: EnginePool, fan,
+    def decide_fan(self, state: Mirror, pool: EnginePool, fan,
                    objective: ObjectiveLike = None, *,
                    weights: Optional[scoring.ScoreWeights] = None
                    ) -> Decision:
@@ -757,7 +766,7 @@ class DrainEngine:
         from repro.core.race import race_grid as _race_grid
         return _race_grid(scenarios, pool, race, objective, engine=self)
 
-    def decide_race(self, state: SimState, pool: EnginePool, race,
+    def decide_race(self, state: Mirror, pool: EnginePool, race,
                     objective: ObjectiveLike = None):
         """One raced decision cycle: the ``decide_fan`` fan grown rung
         by rung with CI elimination and anytime budgets.  Returns
@@ -910,17 +919,18 @@ def _decision(objective: Objective, m: Members,
 @functools.partial(jax.jit,
                    static_argnames=("engine", "objective", "plan",
                                     "placement"))
-def _decide(engine: DrainEngine, state: SimState, pool: EnginePool,
+def _decide(engine: DrainEngine, state: Mirror, pool: EnginePool,
             objective: Objective = DEFAULT_OBJECTIVE,
             plan: HoistPlan = None, placement=None) -> Decision:
-    return _decision(objective, _members(engine, state, pool, objective,
-                                         plan, placement=placement))
+    return _decision(objective, _members(engine, as_state(state), pool,
+                                         objective, plan,
+                                         placement=placement))
 
 
 @functools.partial(jax.jit,
                    static_argnames=("engine", "n_ens", "noise", "objective",
                                     "plan"))
-def _decide_ensemble(engine: DrainEngine, state: SimState, pool: EnginePool,
+def _decide_ensemble(engine: DrainEngine, state: Mirror, pool: EnginePool,
                      key: jax.Array, n_ens: int, noise: float,
                      objective: Objective = DEFAULT_OBJECTIVE,
                      plan: HoistPlan = None) -> Decision:
@@ -928,6 +938,7 @@ def _decide_ensemble(engine: DrainEngine, state: SimState, pool: EnginePool,
     lognormal noise from ``key``'s normals, member 0 exact (so the
     qrun set is the mirror's); the policy cost is the cost of the
     members' mean metrics."""
+    state = as_state(state)
     eps = jax.random.normal(key, (n_ens, state.jobs.capacity))
     est = lognormal_noise(state.jobs.est_runtime[None, :], eps, noise,
                           jnp.arange(n_ens) == 0)
@@ -938,7 +949,7 @@ def _decide_ensemble(engine: DrainEngine, state: SimState, pool: EnginePool,
 @functools.partial(jax.jit,
                    static_argnames=("engine", "spec", "objective", "plan",
                                     "lo", "width"))
-def _fan_members(engine: DrainEngine, state: SimState, pool: EnginePool,
+def _fan_members(engine: DrainEngine, state: Mirror, pool: EnginePool,
                  spec: FanSpec, objective: Objective, plan: HoistPlan,
                  lo: int, width: int) -> Members:
     """Members ``φ ∈ [lo, lo+width)`` of a decision's fan (fork
@@ -946,18 +957,20 @@ def _fan_members(engine: DrainEngine, state: SimState, pool: EnginePool,
     (``race.decide_race``), or with ``[0, F)`` the whole fan.  The
     per-member costs are the goal's inner costs; reduction happens in
     the caller."""
+    state = as_state(state)
     est, down = _fan_draws(state, spec, lo, width)
     return _members(engine, state, pool, objective, plan, width, est, down)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("engine", "spec", "objective", "plan"))
-def _decide_fan(engine: DrainEngine, state: SimState, pool: EnginePool,
+def _decide_fan(engine: DrainEngine, state: Mirror, pool: EnginePool,
                 spec: FanSpec = FanSpec(),
                 objective: Objective = DEFAULT_OBJECTIVE,
                 plan: HoistPlan = None) -> Decision:
-    return _decision(objective, _fan_members(engine, state, pool, spec,
-                                             objective, plan, 0, spec.n))
+    return _decision(objective, _fan_members(engine, as_state(state), pool,
+                                             spec, objective, plan, 0,
+                                             spec.n))
 
 
 # ----------------------------------------------------------------------

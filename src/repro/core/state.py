@@ -3,7 +3,8 @@
 Fixed-capacity arrays (``max_jobs`` slots) so every simulation has a
 static shape.  The same structures are used by (a) the twin's mirror of
 the physical system (numpy leaves on the host, uploaded once per
-decision; a window of slots recycled as jobs end, ``sync.JobSlots``),
+decision as one ``PackedState`` buffer; a window of slots recycled as
+jobs end, ``sync.JobSlots``),
 (b) each what-if simulation fork, and (c) the cluster emulator's
 ground-truth state (which additionally knows true runtimes; there slot
 ``i`` is job ``i`` for the lifetime of a trace).  Slot order is
@@ -11,7 +12,7 @@ submission order wherever a pass reads the table.
 """
 from __future__ import annotations
 
-from typing import NamedTuple
+from typing import NamedTuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -56,6 +57,49 @@ class SimState(NamedTuple):
     free_nodes: jax.Array   # i32 scalar
     total_nodes: jax.Array  # i32 scalar (changes on NODEFAIL/NODEUP)
     now: jax.Array          # f32 scalar
+
+
+class PackedState(NamedTuple):
+    """A ``SimState`` as one flat int32 buffer of ``6·J + 3`` words, so
+    the twin's mirror reaches the device in one transfer (``sync.pack``
+    writes it on the host, ``as_state`` reads it inside the decide's
+    program).  Layout: the six job columns in ``JobTable`` field order,
+    ``J`` words each, then ``free_nodes``, ``total_nodes`` and ``now``.
+    Float32 values are stored by their bits (``packed_fields``)."""
+
+    words: jax.Array        # i32 (6·J + 3,)
+
+
+#: The dtype of each ``SimState`` field as a ``PackedState`` holds it:
+#: each job column's, then the three scalars'.
+_PACKED_JOBS = JobTable(np.float32, np.int32, np.float32, np.float32,
+                        np.float32, np.int32)
+_PACKED_SCALARS = (("free_nodes", np.int32), ("total_nodes", np.int32),
+                   ("now", np.float32))
+
+
+def packed_fields(words) -> SimState:
+    """The ``SimState`` fields of a ``PackedState`` buffer of ``6·J +
+    3`` int32 words, each a static slice of ``words`` read as its dtype
+    (a bitcast): on a numpy buffer views to write the fields through, on
+    a device one the unpacked state."""
+    J = (words.shape[0] - 3) // 6
+
+    def field(start, size, dtype):
+        return words[start:start + size].view(dtype)
+
+    jobs = JobTable(*(field(i * J, J, dt)
+                      for i, dt in enumerate(_PACKED_JOBS)))
+    scalars = {name: field(6 * J + i, 1, dt).reshape(())
+               for i, (name, dt) in enumerate(_PACKED_SCALARS)}
+    return SimState(jobs=jobs, **scalars)
+
+
+def as_state(x: Union[SimState, PackedState]) -> SimState:
+    """``x`` as a ``SimState``: a ``SimState`` unchanged, a
+    ``PackedState`` unpacked (``packed_fields``).  Every decision program
+    that takes the twin's state calls it first."""
+    return packed_fields(x.words) if isinstance(x, PackedState) else x
 
 
 def empty_jobs(max_jobs: int, xp=jnp) -> JobTable:

@@ -46,7 +46,7 @@ for the life of the cluster, the mirror holds a fixed number of slots
 for the live ones.  The handlers here work on slots; the twin names
 each event's job by its slot before applying it, gives the slot back
 (``release``) when the job ends, and ``pack``s the live slots in
-submission order before each upload.
+submission order into one int32 buffer before each upload.
 """
 from __future__ import annotations
 
@@ -56,7 +56,7 @@ import numpy as np
 
 from repro.core.events import Event, EventKind
 from repro.core.state import (DONE, INVALID, QUEUED, RUNNING, TIME_NONE,
-                              JobTable, SimState)
+                              JobTable, SimState, packed_fields)
 
 
 #: An empty row of the job table, column by column.
@@ -268,15 +268,23 @@ def release(state: SimState, slot: int) -> SimState:
     return state._replace(jobs=_set(state.jobs, slot, **_EMPTY))
 
 
-def pack(state: SimState, slots: JobSlots) -> Tuple[SimState, np.ndarray]:
-    """The mirror with its live slots gathered to the front in
-    submission order (the rest empty), and the job id of each packed
-    row: what the device decides over, and how its run mask maps back
-    to jobs."""
+def pack(state: SimState, slots: JobSlots) -> Tuple[np.ndarray, np.ndarray]:
+    """The mirror as one fresh ``PackedState`` buffer (int32, ``6·J +
+    3`` words) with its live slots gathered to the front of each column
+    in submission order (the rest empty), and the job id of each packed
+    row: what the device decides over, in one transfer, and how its run
+    mask maps back to jobs.  Float32 words are written by their bits."""
     order = slots.order()
-    return (state._replace(jobs=_rows(state.jobs, {
-        name: getattr(state.jobs, name)[order] for name in _EMPTY})),
-        slots.ids[order])
+    n = len(order)
+    words = np.empty(6 * state.jobs.capacity + 3, np.int32)
+    out = packed_fields(words)
+    for name, fill in _EMPTY.items():
+        col = getattr(out.jobs, name)
+        col[:n] = getattr(state.jobs, name)[order]
+        col[n:] = fill
+    for name in ("free_nodes", "total_nodes", "now"):
+        getattr(out, name)[()] = getattr(state, name)
+    return words, slots.ids[order]
 
 
 def _rows(jobs: JobTable, rows: Dict[str, np.ndarray]) -> JobTable:

@@ -10,9 +10,9 @@ so the totals of a pump never count a second twice.  Beside the stage
 totals the meter counts the pump's events, the job-table slots it
 released (``recycled``) and the events it refused for a full table
 (``window_full``), its blocking device-to-host reads (``fetch``), its
-host-to-device uploads of the mirror (``upload``) and its backend
-compiles; a pump that records a decision cycle stamps all of them on
-that ``CycleRecord``.
+host-to-device uploads of the mirror (``upload``) and the device
+buffers they carried, and its backend compiles; a pump that records a
+decision cycle stamps all of them on that ``CycleRecord``.
 """
 from __future__ import annotations
 
@@ -43,12 +43,13 @@ class _ProcessCounts:
     """Monotone counts over the whole process; a meter reads deltas."""
     host_reads = 0
     uploads = 0
+    upload_buffers = 0
     compiles = 0
     listening = False
 
 
 #: The counts a ``StageMeter`` stamps on its pump's ``CycleRecord``.
-_COUNTS = ("host_reads", "uploads", "compiles")
+_COUNTS = ("host_reads", "uploads", "upload_buffers", "compiles")
 
 
 def _counts() -> Dict[str, int]:
@@ -77,8 +78,10 @@ def fetch(x) -> np.ndarray:
 
 def upload(tree):
     """``tree`` (host arrays) on the device in one ``jax.device_put``,
-    counted for the pump's ``uploads``."""
+    counted for the pump's ``uploads``, and its leaves, one device
+    buffer each, for ``upload_buffers``."""
     _ProcessCounts.uploads += 1
+    _ProcessCounts.upload_buffers += len(jax.tree.leaves(tree))
     return jax.device_put(tree)
 
 
@@ -136,12 +139,14 @@ class CycleRecord:
     # the pump that ran this cycle (``StageMeter``): host seconds per
     # stage (every name in ``STAGES``, 0.0 where it did not run), its
     # blocking device-to-host reads, its uploads of the mirror to the
-    # device, the events it consumed and the backend compiles inside it.
+    # device and the device buffers they carried, the events it consumed
+    # and the backend compiles inside it.
     stages: Dict[str, float] = dataclasses.field(default_factory=dict)
     host_reads: int = 0
     events: int = 0
     compiles: int = 0
     uploads: int = 0
+    upload_buffers: int = 0
     # the job table (``sync.JobSlots``): live jobs in the window at this
     # decision, slots the pump released, and events it refused because
     # every slot was live (each also a dead letter).
@@ -304,15 +309,17 @@ class Telemetry:
     def cycle_latency_stats(self) -> Dict[str, object]:
         """The decision span (``wall_seconds``) by nearest rank, the
         median of each host stage, the mean blocking reads, uploads,
-        events, live jobs and released slots per cycle, the run's live
-        jobs refused for a full job table (``IngestStats``: a pump that
-        refuses its only event records no cycle), and ``twin.attach``'s
-        seconds (its one cycle's, or 0.0)."""
+        uploaded buffers, events, live jobs and released slots per
+        cycle, the run's live jobs refused for a full job table
+        (``IngestStats``: a pump that refuses its only event records no
+        cycle), and ``twin.attach``'s seconds (its one cycle's, or
+        0.0)."""
         n = len(self.cycles)
         if not n:
             return {"n": 0, "mean_s": 0.0, "p50_s": 0.0, "p95_s": 0.0,
                     "max_s": 0.0, "host_reads": 0.0, "uploads": 0.0,
-                    "events": 0.0, "live_jobs": 0.0, "recycled": 0.0,
+                    "upload_buffers": 0.0, "events": 0.0,
+                    "live_jobs": 0.0, "recycled": 0.0,
                     "window_full": self.ingest.window_full,
                     "attach_s": 0.0,
                     "stage_p50_s": dict.fromkeys(STAGES, 0.0)}
@@ -329,6 +336,7 @@ class Telemetry:
             "max_s": max(ws),
             "host_reads": mean("host_reads"),
             "uploads": mean("uploads"),
+            "upload_buffers": mean("upload_buffers"),
             "events": mean("events"),
             "live_jobs": mean("live_jobs"),
             "recycled": mean("recycled"),

@@ -52,7 +52,8 @@ from repro.core.policies import (PAPER_POOL, PolicyPool, PolicySpec,
                                  PoolLike, normalize_pool)
 from repro.core.race import RaceSpec, normalize_race
 from repro.core.scoring import ScoreWeights
-from repro.core.state import DONE, INVALID, QUEUED, SimState, empty_state
+from repro.core.state import (DONE, INVALID, QUEUED, PackedState, SimState,
+                              empty_state)
 from repro.core.telemetry import fetch
 
 
@@ -407,8 +408,9 @@ class SchedTwin:
         self.telemetry.record_residual(est, ev.time - start)
 
     # ------------------------------------------------------------------
-    def _decide_at_level(self, level: int, state: SimState):
-        """One decision on ``state`` (the mirror, on the device) at the
+    def _decide_at_level(self, level: int, state: PackedState):
+        """One decision on ``state`` (the mirror, on the device as one
+        buffer, which every decision program unpacks itself) at the
         given ladder level (DESIGN.md §12).  Returns ``(decision,
         race_out, names, source)`` where ``names`` label the decision's
         forks and ``source`` ∈ {'pool', 'fallback'} says which pool the
@@ -491,9 +493,9 @@ class SchedTwin:
                     self.state, self.free_nodes_probe())
 
         with span("twin.slots"):
-            packed, ids = sync.pack(self.state, self.slots)
+            mirror, ids = sync.pack(self.state, self.slots)
         with span("twin.upload"):
-            state = telemetry.upload(packed)
+            state = telemetry.upload(PackedState(mirror))
 
         level = self.guard.plan() if self.guard is not None else 0
         with telemetry.StopWatch(self._clock) as sw:

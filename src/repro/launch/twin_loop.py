@@ -681,7 +681,8 @@ def main() -> None:
         f"{name.split('.')[-1]} {s * 1e3:.3f}"
         for name, s in lat["stage_p50_s"].items())
         + f"; host reads {lat['host_reads']:.1f}, uploads "
-        f"{lat['uploads']:.1f}, events {lat['events']:.2f}, live_jobs "
+        f"{lat['uploads']:.1f}, upload_buffers {lat['upload_buffers']:.1f}, "
+        f"events {lat['events']:.2f}, live_jobs "
         f"{lat['live_jobs']:.1f}, recycled {lat['recycled']:.2f} per "
         f"cycle; window_full {lat['window_full']}; attach "
         f"{lat['attach_s'] * 1e3:.3f} ms")

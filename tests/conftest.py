@@ -2,7 +2,7 @@ import jax
 import numpy as np
 import pytest
 
-from repro.core.state import add_job, empty_state, start_job
+from repro.core.state import JobTable, add_job, empty_state, start_job
 from repro.distributed.sharding import make_rules
 from repro.launch.mesh import make_mesh
 
@@ -45,3 +45,21 @@ def make_cluster_state(max_jobs=64, total_nodes=32, n_queued=12,
         jid += 1
     import jax.numpy as jnp
     return st._replace(now=jnp.float32(now))
+
+
+def gathered_mirror(state, slots):
+    """What ``sync.pack`` uploads, as a host ``SimState`` and the job id
+    of each row, built row by row: the live slots' rows first in job-id
+    (submission) order, empty rows after them, the scalars as they are."""
+    live = sorted((int(j), s) for s, j in enumerate(slots.ids) if j >= 0)
+    capacity = state.jobs.capacity
+    out = empty_state(capacity, int(state.total_nodes), xp=np).jobs
+    cols = {name: np.array(getattr(out, name)) for name in JobTable._fields}
+    for row, (_, s) in enumerate(live):
+        for name in JobTable._fields:
+            cols[name][row] = getattr(state.jobs, name)[s]
+    return (state._replace(jobs=JobTable(**cols),
+                           free_nodes=np.int32(state.free_nodes),
+                           total_nodes=np.int32(state.total_nodes),
+                           now=np.float32(state.now)),
+            np.array([j for j, _ in live], np.int64))

@@ -104,6 +104,7 @@ def test_every_cycle_has_all_stages_and_exact_counts():
         assert rec.events == events, name
         assert rec.host_reads == reads + DECISION_READS, name
         assert rec.uploads == 1, name
+        assert rec.upload_buffers == 1, name
         assert rec.n_started == started, name
         assert (rec.stages["twin.qrun"] > 0.0) == bool(started), name
         # every pump runs each stage but qrun (only when it starts jobs)
@@ -127,10 +128,13 @@ def test_one_upload_per_decision_and_no_read_before_it(n_events):
     s = ScriptedTwin()
     for j in range(n_events):
         s.publish(_queue(j, float(j), nodes=4))
-    before = telemetry._ProcessCounts.uploads
+    before = telemetry._counts()
     _, first = s.pump()
     assert first.events == n_events and first.n_started >= 1
-    assert telemetry._ProcessCounts.uploads - before == 1
+    after = telemetry._counts()
+    assert after["uploads"] - before["uploads"] == 1
+    # the mirror crosses as one buffer (``PackedState``)
+    assert after["upload_buffers"] - before["upload_buffers"] == 1
     assert first.host_reads == HOIST_PLAN_READS + DECISION_READS
     # the next pump carries the RUNJOBs the first one's qrun published
     s.publish(_queue(n_events, 100.0, nodes=1))
@@ -138,9 +142,10 @@ def test_one_upload_per_decision_and_no_read_before_it(n_events):
     assert second.events == first.n_started + 1
     assert second.host_reads == DECISION_READS
     for rec in (first, second):
-        assert rec.uploads == 1
+        assert rec.uploads == 1 and rec.upload_buffers == 1
         assert rec.stages["twin.upload"] > 0.0
-    assert s.twin.telemetry.cycle_latency_stats()["uploads"] == 1.0
+    stats = s.twin.telemetry.cycle_latency_stats()
+    assert stats["uploads"] == 1.0 and stats["upload_buffers"] == 1.0
 
 
 def test_a_nested_entry_point_meters_its_own_cycle():
@@ -309,7 +314,7 @@ def test_twin_loop_prints_every_stage(monkeypatch, capsys):
     for name in STAGES:
         assert f" {name.split('.')[-1]} " in line, name
     assert "host reads" in line and "events" in line
-    assert "uploads 1.0" in line
+    assert "uploads 1.0" in line and "upload_buffers 1.0" in line
 
 
 def test_twin_loop_attaches_to_a_lublin_stream(monkeypatch, capsys):

@@ -1,6 +1,7 @@
 """End-to-end system tests: the twin in the loop with the emulator."""
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -9,7 +10,10 @@ from repro.cluster.emulator import ClusterEmulator, FailureSpec
 from repro.cluster.workload import JobSpec, paper_synthetic_trace
 from repro.core.events import EventBus
 from repro.core.policies import FCFS, PAPER_POOL, SJF, WFP
+from repro.core.state import PackedState
 from repro.core.twin import SchedTwin
+
+from conftest import gathered_mirror
 
 
 def tiny_trace(n=24, seed=0):
@@ -291,3 +295,51 @@ def test_every_cycle_record_is_its_decision_read_field_by_field(trace,
         assert _same_bits(c.cost_ci, want["cost_ci"])
         assert _same_bits(c.fan_width, want["fan_width"])
         assert bool(c.cost_ci) == (mode != "plain")
+
+
+# ----------------------------------------------------------------------
+# the mirror reaches the device in one buffer (``PackedState``)
+# ----------------------------------------------------------------------
+
+class StateParityTwin(SchedTwin):
+    """A twin that also decides each cycle from its mirror as a
+    ``SimState`` gathered in the test, beside its own decision from the
+    one buffer it uploaded."""
+
+    def __init__(self, *args, **kw):
+        super().__init__(*args, **kw)
+        self.from_state = []
+
+    def _decide_at_level(self, level, state):
+        assert isinstance(state, PackedState) and state.words.ndim == 1
+        mirror, ids = gathered_mirror(self.state, self.slots)
+        d = self.engine.decide(jax.device_put(mirror), self.pool.spec,
+                               self.objective)
+        self.from_state.append((d.unpack(np.asarray(d.words)), ids))
+        return super()._decide_at_level(level, state)
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_a_packed_upload_decides_as_the_mirror_state(seed):
+    """Paper traces, 32 nodes, a 256-slot table: every cycle's record,
+    decided from the one uploaded buffer, is the decision of the same
+    mirror passed as a ``SimState``, bit for bit."""
+    bus = EventBus()
+    em = ClusterEmulator(paper_synthetic_trace(seed=seed), 32, bus=bus,
+                         check_invariants=True)
+    twin = StateParityTwin(bus=bus, qrun=em.qrun, total_nodes=32,
+                           max_jobs=256,
+                           free_nodes_probe=lambda: em.free_nodes)
+    em.run(on_event=twin.pump)
+    cycles = twin.telemetry.cycles
+    assert len(cycles) == len(twin.from_state) >= 290
+    names = list(twin.pool.names)
+    for c, (view, ids) in zip(cycles, twin.from_state):
+        assert c.upload_buffers == 1
+        assert c.policy == names[view.policy_index]
+        assert c.started_jobs == ids[np.nonzero(view.run_mask)[0]].tolist()
+        assert _same_bits(c.costs, dict(zip(names, view.costs)))
+        assert c.term_costs.keys() == set(names)
+        for i, name in enumerate(names):
+            assert _same_bits(c.term_costs[name], {
+                t: v[i] for t, v in view.cost_terms.items()})
